@@ -24,9 +24,9 @@ BENCH_JSON = os.path.join(_ROOT, "BENCH_collectives.json")
 _CHILD = r"""
 import os, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.core import collectives as coll
 from repro.core.engine import FlareConfig, GradReducer
 
@@ -43,14 +43,15 @@ def timeit(fn, *args, iters=5):
 
 
 # --- raw collective wall-clock (seed benchmark, kept) ----------------------
-mesh = compat.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 Z = 1 << 22
 x = jnp.ones((8, Z), jnp.float32)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     xd = jax.device_put(x, NamedSharding(mesh, P(("pod", "data"), None)))
     for alg in ["ring", "rhd", "fixed_tree", "two_level",
                 "psum"]:
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v, a=alg: coll.allreduce(v[0], ("pod", "data"),
                                             algorithm=a),
             in_specs=(P(("pod", "data"), None),), out_specs=P(None),
@@ -74,8 +75,8 @@ for i in range(192):
 total = sum(int(np.prod(g.shape)) for g in grads.values())
 in_specs = {k: P() for k in grads}
 
-mesh8 = compat.make_mesh((8,), ("data",))
-with compat.set_mesh(mesh8):
+mesh8 = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+with jax.set_mesh(mesh8):
     gd = {k: jax.device_put(v, NamedSharding(mesh8, P()))
           for k, v in grads.items()}
     times = {}
@@ -85,7 +86,7 @@ with compat.set_mesh(mesh8):
                               ("arena_auto", True, "auto")]:
         red = GradReducer(FlareConfig(axes=("data",), algorithm=alg,
                                       bucket_bytes=64 << 10, arena=arena))
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda g, red=red: red(g)[0], in_specs=(in_specs,),
             out_specs=in_specs, axis_names={"data"}, check_vma=False))
         times[label] = timeit(fn, gd, iters=7)
@@ -109,7 +110,7 @@ from repro.core import transports
 B, S = 16, 1 << 11
 arena = jnp.asarray(rng.normal(size=(B, S)).astype(np.float32))
 exts = (S,) * B
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     for name, kw in [("sparse", dict(sparse_k_frac=0.01)),
                      ("int8", dict(compression="int8"))]:
@@ -117,7 +118,7 @@ with compat.set_mesh(mesh8):
         for mode, batched in [("scan", False), ("batched", True)]:
             cfg = FlareConfig(axes=("data",), **kw)
             t = transports.from_config(cfg, jnp.float32, batched=batched)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, t=t: t(a, jnp.zeros_like(a),
                                  jnp.zeros((B,), jnp.int32), exts)[0],
                 in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -145,7 +146,7 @@ HIER_CASES = [
     ("sparse", 8, 1 << 20, dict(sparse_k_frac=0.0005),
      dict(sparse_k_frac=0.0005)),
 ]
-with compat.set_mesh(mesh24):
+with jax.set_mesh(mesh24):
     for name, b, s, flat_kw, hier_kw in HIER_CASES:
         arena = jnp.asarray(rng.normal(size=(b, s)).astype(np.float32))
         ad = jax.device_put(arena, NamedSharding(mesh24, P()))
@@ -155,7 +156,7 @@ with compat.set_mesh(mesh24):
                                ("hier", hier_kw, True)]:
             cfg = FlareConfig(axes=("pod", "data"), hierarchical=hier, **kw)
             t = transports.from_config(cfg, jnp.float32, batched=True)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, t=t, b=b: t(a, jnp.zeros_like(a),
                                       jnp.zeros((b,), jnp.int32),
                                       (a.shape[1],) * b)[0],
@@ -179,7 +180,7 @@ with compat.set_mesh(mesh24):
 B, S = 4, 1 << 14
 arena = jnp.asarray(rng.normal(size=(B, S)).astype(np.float32))
 exts = (S,) * B
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     for name, kw in [("dense", dict()),
                      ("sparse", dict(sparse_k_frac=0.01)),
@@ -191,7 +192,7 @@ with compat.set_mesh(mesh8):
                 ("slotloop", dict(transport="innetwork"), False)]:
             cfg = FlareConfig(axes=("data",), **kw, **extra)
             t = transports.from_config(cfg, jnp.float32, batched=batched)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, t=t: t(a, jnp.zeros_like(a),
                                  jnp.zeros((B,), jnp.int32), exts)[0],
                 in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -216,7 +217,7 @@ from repro.runtime import SessionManager
 B, S = 4, 1 << 14
 arena = jnp.asarray(rng.normal(size=(B, S)).astype(np.float32))
 exts = (S,) * B
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     fns = {}
     for nten in (1, 2, 4):
@@ -229,7 +230,7 @@ with compat.set_mesh(mesh8):
                           reproducible=True)
         t = transports.from_config(cfg, jnp.float32, manager=mgr,
                                    tenant="t0")
-        fns[nten] = jax.jit(compat.shard_map(
+        fns[nten] = jax.jit(jax.shard_map(
             lambda a, t=t: t(a, None, jnp.zeros((B,), jnp.int32), exts)[0],
             in_specs=(P(),), out_specs=P(), axis_names={"data"},
             check_vma=False))
@@ -259,7 +260,7 @@ from repro.switch.packets import FaultPlan
 B, S = 4, 1 << 14
 arena = jnp.asarray(rng.normal(size=(B, S)).astype(np.float32))
 exts = (S,) * B
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     ts = {}
     for name, plan in [("baseline", None),
@@ -268,7 +269,7 @@ with compat.set_mesh(mesh8):
         cfg = FlareConfig(axes=("data",), transport="innetwork",
                           fault_plan=plan)
         t = transports.from_config(cfg, jnp.float32, batched=True)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda a, t=t: t(a, jnp.zeros_like(a),
                              jnp.zeros((B,), jnp.int32), exts)[0],
             in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -317,9 +318,9 @@ print(f"transports.canary.contention_x,{c_dynamic/c_static:.2f},"
 _QUICK_CHILD = r"""
 import os, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.core import transports
 from repro.core.engine import FlareConfig
 
@@ -338,8 +339,8 @@ B, S = 4, 2048
 rng = np.random.default_rng(0)
 arena = jnp.asarray(rng.normal(size=(B, S)).astype(np.float32))
 exts = (S,) * B
-mesh8 = compat.make_mesh((8,), ("data",))
-with compat.set_mesh(mesh8):
+mesh8 = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     for name, kw in [("dense", dict(algorithm="ring")),
                      ("sparse", dict(sparse_k_frac=0.01)),
@@ -348,7 +349,7 @@ with compat.set_mesh(mesh8):
         for mode, batched in [("scan", False), ("batched", True)]:
             cfg = FlareConfig(axes=("data",), **kw)
             t = transports.from_config(cfg, jnp.float32, batched=batched)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, t=t: t(a, jnp.zeros_like(a),
                                  jnp.zeros((B,), jnp.int32), exts)[0],
                 in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -365,7 +366,7 @@ if os.environ.get("REPRO_QUICK_INJECT_FAIL"):
     raise RuntimeError("injected failure (REPRO_QUICK_INJECT_FAIL)")
 from repro.launch import mesh as launch_mesh
 mesh24 = launch_mesh.make_fake_mesh(launch_mesh.FAKE_2D)
-with compat.set_mesh(mesh24):
+with jax.set_mesh(mesh24):
     ad = jax.device_put(arena, NamedSharding(mesh24, P()))
     for name, kw in [("dense", dict()),
                      ("sparse", dict(sparse_k_frac=0.01)),
@@ -374,7 +375,7 @@ with compat.set_mesh(mesh24):
         for mode, hier in [("flat", False), ("hier", True)]:
             cfg = FlareConfig(axes=("pod", "data"), hierarchical=hier, **kw)
             t = transports.from_config(cfg, jnp.float32, batched=True)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, t=t: t(a, jnp.zeros_like(a),
                                  jnp.zeros((B,), jnp.int32), exts)[0],
                 in_specs=(P(),), out_specs=P(), axis_names={"pod", "data"},
@@ -389,7 +390,7 @@ with compat.set_mesh(mesh24):
 # shapes — keeps FlareConfig(transport="innetwork") + the repro/switch
 # packet/handler plumbing under the tier-1 smoke gate for every handler
 # type, in both the batched plane and the slot-loop oracle schedule
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     for name, kw in [("dense", dict()),
                      ("sparse", dict(sparse_k_frac=0.01)),
@@ -401,7 +402,7 @@ with compat.set_mesh(mesh8):
                 ("slotloop", dict(transport="innetwork"), False)]:
             cfg = FlareConfig(axes=("data",), **kw, **extra)
             t = transports.from_config(cfg, jnp.float32, batched=batched)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, t=t: t(a, jnp.zeros_like(a),
                                  jnp.zeros((B,), jnp.int32), exts)[0],
                 in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -421,7 +422,7 @@ with compat.set_mesh(mesh8):
 # SessionManager → transports → dataplane plumbing under the tier-1
 # smoke gate.
 from repro.runtime import SessionManager
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     ts = {}
     for nten in (1, 2, 4):
@@ -434,7 +435,7 @@ with compat.set_mesh(mesh8):
                           reproducible=True)
         t = transports.from_config(cfg, jnp.float32, manager=mgr,
                                    tenant="t0")
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda a, t=t: t(a, None, jnp.zeros((B,), jnp.int32),
                              exts)[0],
             in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -454,7 +455,7 @@ with compat.set_mesh(mesh8):
 # traced plane accumulates (they are asserted equal in tests).
 from repro.switch import dataplane as sw_dp
 from repro.switch.packets import FaultPlan
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     ts = {}
     for name, plan in [("baseline", None),
@@ -463,7 +464,7 @@ with compat.set_mesh(mesh8):
         cfg = FlareConfig(axes=("data",), transport="innetwork",
                           fault_plan=plan)
         t = transports.from_config(cfg, jnp.float32, batched=True)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda a, t=t: t(a, jnp.zeros_like(a),
                              jnp.zeros((B,), jnp.int32), exts)[0],
             in_specs=(P(),), out_specs=P(), axis_names={"data"},
@@ -517,34 +518,41 @@ obs_tm = Telemetry.create()
 # (quick.health.poll.us_per_call) — it stays outside the timed window
 # so the gate keeps measuring the step, not the detector sweep
 obs_hm = HealthMonitor(obs_tm)
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     fns = {}
     for label, tm in [("bare", None), ("telemetry", obs_tm)]:
         cfg = FlareConfig(axes=("data",), transport="innetwork",
                           telemetry=tm)
         t = transports.from_config(cfg, jnp.float32, batched=True)
-        fns[label] = jax.jit(compat.shard_map(
+        fns[label] = jax.jit(jax.shard_map(
             lambda a, t=t: t(a, jnp.zeros_like(a),
                              jnp.zeros((B,), jnp.int32), exts)[0],
             in_specs=(P(),), out_specs=P(), axis_names={"data"},
             check_vma=False))
         jax.block_until_ready(fns[label](ad))   # compile + warm both
     ts = {label: float("inf") for label in fns}
-    # more rounds than the other sections: the gate is a tight ratio
-    # (1.05x), so the min needs room to converge on a noisy shared CPU
-    for _round in range(12):
-        for label, fn in fns.items():
+    # the gate is a tight ratio (1.05x) on a noisy shared CPU, where the
+    # two mins can land in different load bursts: each round times both
+    # variants back to back, in alternating order, and the gate reads
+    # the median of the per-round ratios
+    ratios = []
+    for rnd in range(100):
+        dt = {}
+        for label in (("bare", "telemetry") if rnd % 2 == 0
+                      else ("telemetry", "bare")):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(ad))
-            ts[label] = min(ts[label], time.perf_counter() - t0)
+            jax.block_until_ready(fns[label](ad))
+            dt[label] = time.perf_counter() - t0
+            ts[label] = min(ts[label], dt[label])
             if label == "telemetry":
                 obs_hm.poll()
+        ratios.append(dt["telemetry"] / dt["bare"])
     for label in ("bare", "telemetry"):
         print(f"quick.obs.{label}.us_per_call,{ts[label]*1e6:.0f},"
               f"8dev_cpu_B{B}xS{S}_dense_innetwork")
-    print(f"quick.obs.overhead_x,{ts['telemetry']/ts['bare']:.2f},"
-          f"telemetry/bare_dense_innetwork")
+    print(f"quick.obs.overhead_x,{np.median(ratios):.2f},"
+          f"telemetry/bare_dense_innetwork_paired_median")
     t0 = time.perf_counter()
     for _ in range(100):
         obs_hm.poll()
@@ -559,14 +567,14 @@ with compat.set_mesh(mesh8):
 import json as _json, tempfile
 tm2 = Telemetry.create(clock=counting_clock())
 mgr2 = SessionManager(("data",), (8,), seed=0, telemetry=tm2)
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     ad = jax.device_put(arena, NamedSharding(mesh8, P()))
     for tenant, kw in [("a", dict()), ("b", dict(compression="int8"))]:
         cfg = FlareConfig(axes=("data",), transport="innetwork",
                           telemetry=tm2, **kw)
         t = transports.from_config(cfg, jnp.float32, manager=mgr2,
                                    tenant=tenant)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda a, t=t: t(a, jnp.zeros_like(a),
                              jnp.zeros((B,), jnp.int32), exts)[0],
             in_specs=(P(),), out_specs=P(), axis_names={"data"},

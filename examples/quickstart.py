@@ -5,16 +5,17 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import collectives as coll, compression, reproducible, sparse
 
-mesh = compat.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 Z = 1 << 16
 rng = np.random.default_rng(0)
 contrib = jnp.asarray(rng.normal(size=(8, Z)).astype(np.float32))
@@ -22,11 +23,11 @@ oracle = np.asarray(contrib).sum(0)
 
 
 def run(fn):
-    g = jax.jit(compat.shard_map(fn, in_specs=(P(("pod", "data"), None),),
-                                 out_specs=P(None),
-                                 axis_names={"pod", "data"},
-                                 check_vma=False))
-    with compat.set_mesh(mesh):
+    g = jax.jit(jax.shard_map(fn, in_specs=(P(("pod", "data"), None),),
+                              out_specs=P(None),
+                              axis_names={"pod", "data"},
+                              check_vma=False))
+    with jax.set_mesh(mesh):
         x = jax.device_put(contrib,
                            NamedSharding(mesh, P(("pod", "data"), None)))
         return np.asarray(g(x))

@@ -6,12 +6,13 @@ Run:  PYTHONPATH=src python examples/sparse_allreduce_demo.py
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, configs
+from repro import configs
 from repro.core.engine import FlareConfig
 from repro.core.sparse import expected_sparse_wire_bytes
 from repro.core import collectives as coll
@@ -21,7 +22,8 @@ from repro.train import trainer
 
 cfg = configs.load("tinyllama-1.1b").SMOKE.scaled(dtype=jnp.float32)
 model = get_model(cfg)
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 mcfg = rules.MeshCfg(("data", "model"), (4, 2))
 key = jax.random.PRNGKey(0)
 batch = {"tokens": jax.random.randint(key, (8, 32), 0, cfg.vocab),
@@ -40,7 +42,7 @@ MODES = {
 print(f"{'mode':<14}{'final loss':>12}{'grad wire bytes/rank':>24}")
 for name, fc in MODES.items():
     tcfg = trainer.TrainConfig(lr=5e-3, flare=fc)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, param_sh, opt_sh, batch_sh, init_opt = trainer.jit_train_step(
             model, mesh, mcfg, tcfg, jax.eval_shape(model.init, key),
             batch_shapes, donate=False)

@@ -3,21 +3,22 @@ steps, full Flare stack (FSDP gather/reduce-scatter + GradReducer +
 AdamW + checkpointing) on 4 fake devices.
 
 Run:  PYTHONPATH=src python examples/train_e2e.py [--steps 200]
-Scale up with --d-model/--layers/--steps (the same driver trains the
-~100M-class config with --d-model 768 --layers 12 on real hardware).
+Scale up with --d-model/--layers/--steps.  This example always runs on
+the CPU; on a TPU use ``python -m repro.launch.train`` or
+``chip_smoke.py``.
 """
 import argparse
 import os
 
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=4")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import time
 
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.engine import FlareConfig
 from repro.data import pipeline
 from repro.ft import CheckpointManager
@@ -47,7 +48,8 @@ def main():
         vocab=args.vocab, dtype=jnp.float32)
     model = get_model(cfg)
 
-    mesh = compat.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     mcfg = rules.MeshCfg(("data", "model"), (2, 2))
     tcfg = trainer.TrainConfig(
         lr=args.lr,
@@ -59,7 +61,7 @@ def main():
     batch_shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch0)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, param_sh, opt_sh, batch_sh, init_opt = trainer.jit_train_step(
             model, mesh, mcfg, tcfg, jax.eval_shape(model.init, key),
             batch_shapes)

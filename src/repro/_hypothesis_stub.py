@@ -105,6 +105,8 @@ def given(*arg_strats, **kw_strats):
         def run(*args, **kwargs):
             n = min(getattr(run, "_hyp_max_examples", MAX_EXAMPLES),
                     MAX_EXAMPLES)
+            for ex in getattr(run, "_hyp_examples", ()):
+                fn(*args, **kwargs, **ex)
             rng = random.Random(0xF1A2E)
             for _ in range(n):
                 vals = [s.example(rng) for s in arg_strats]
@@ -122,6 +124,14 @@ def given(*arg_strats, **kw_strats):
 def settings(max_examples: int = MAX_EXAMPLES, deadline=None, **_kw):
     def deco(fn):
         fn._hyp_max_examples = max_examples
+        return fn
+    return deco
+
+
+def example(**kw):
+    """Run ``kw`` as an explicit example before the drawn ones."""
+    def deco(fn):
+        fn._hyp_examples = [kw, *getattr(fn, "_hyp_examples", ())]
         return fn
     return deco
 
@@ -149,6 +159,7 @@ def install() -> None:
         setattr(strategies, name, globals()[name])
     mod.given = given
     mod.settings = settings
+    mod.example = example
     mod.assume = assume
     mod.HealthCheck = HealthCheck
     mod.strategies = strategies

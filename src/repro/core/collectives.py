@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size as _axis_size
 from repro.core import topology
 
 Op = Callable[[jax.Array, jax.Array], jax.Array]
@@ -107,7 +106,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
     their traffic never contends for the same chunk/link at the same step.
     ``x.shape[0]`` must be divisible by the axis size.
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     r = lax.axis_index(axis)
     if x.shape[0] % p:
         raise ValueError(f"ring_reduce_scatter: len {x.shape[0]} % {p} != 0")
@@ -127,7 +126,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
 
 def ring_all_gather(chunk: jax.Array, axis: str, *, stagger: int = 0) -> jax.Array:
     """Inverse of ``ring_reduce_scatter``: gather P chunks back to a vector."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     r = lax.axis_index(axis)
     perm = _ring_perm(p)
     out0 = jnp.zeros((p,) + chunk.shape, chunk.dtype)
@@ -146,7 +145,7 @@ def ring_all_gather(chunk: jax.Array, axis: str, *, stagger: int = 0) -> jax.Arr
 def allreduce_ring(x: jax.Array, axis: str, *, op: Op = jnp.add,
                    stagger: int = 0) -> jax.Array:
     """Rabenseifner ring allreduce: ~2Z(P-1)/P bytes per rank on the wire."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     xp, n = pad_to_multiple(x, p)
     chunk = ring_reduce_scatter(xp, axis, op=op, stagger=stagger)
     full = ring_all_gather(chunk, axis, stagger=stagger)
@@ -187,7 +186,7 @@ def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
     bitwise-equal to the per-bucket loop.
     """
     b, size = arena.shape
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if p == 1:
         return arena
     if size % p:
@@ -213,7 +212,7 @@ def rhd_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Arra
     Rank ``r`` ends with the segment at bit-reversed position; use
     ``rhd_all_gather`` to invert.
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if not _is_pow2(p):
         raise ValueError(f"rhd requires power-of-two axis size, got {p}")
     r = lax.axis_index(axis)
@@ -235,7 +234,7 @@ def rhd_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Arra
 
 def rhd_all_gather(seg: jax.Array, axis: str) -> jax.Array:
     """Distance-halving all-gather inverting ``rhd_reduce_scatter``."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     r = lax.axis_index(axis)
     steps = p.bit_length() - 1
     for k in reversed(range(steps)):
@@ -251,7 +250,7 @@ def rhd_all_gather(seg: jax.Array, axis: str) -> jax.Array:
 
 def allreduce_rhd(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Array:
     """Recursive halving-doubling allreduce (multi-buffer design analogue)."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     xp, n = pad_to_multiple(x, p)
     seg = rhd_reduce_scatter(xp, axis, op=op)
     full = rhd_all_gather(seg, axis)
@@ -274,7 +273,7 @@ def allreduce_fixed_tree(x: jax.Array, axis: str, *, op: Op = jnp.add,
     paper pays the same structural price — tree aggregation keeps
     (P-1)/log(P) buffers alive instead of 1).
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if not _is_pow2(p):
         raise ValueError(f"fixed_tree requires power-of-two axis size, got {p}")
     orig_dtype = x.dtype
@@ -315,7 +314,7 @@ def allreduce_two_level(x: jax.Array, inner_axis: str, outer_axis: str, *,
     over all P ranks — the paper's 2x in-network traffic reduction shows up
     exactly here) plus Z/P_in * f(P_out) on the scarce inter-pod links.
     """
-    p_in = _axis_size(inner_axis)
+    p_in = lax.axis_size(inner_axis)
     xp, n = pad_to_multiple(x, p_in)
     if inner == "ring":
         seg = ring_reduce_scatter(xp, inner_axis, op=op, stagger=stagger)
@@ -374,7 +373,7 @@ def hierarchical_allreduce(x: jax.Array, axes: tuple[str, ...], *,
     Per-level wire algorithms otherwise come from the level fan-in:
     power-of-two fan-ins take the log-depth rhd path, others the ring.
     """
-    sizes = tuple(_axis_size(a) for a in axes)
+    sizes = tuple(lax.axis_size(a) for a in axes)
     levels = topology.mesh_levels(axes, sizes)
     if len(levels) == 1 and levels[0].fanin == 1:       # 1-host mesh
         return x
@@ -547,7 +546,7 @@ def reduce_scatter(x: jax.Array, axes: tuple[str, ...], *,
     matched reduce-scatter/all-gather pairs don't care.
     """
     *outers, inner = axes
-    p = _axis_size(inner)
+    p = lax.axis_size(inner)
     if x.shape[0] % p:
         raise ValueError(f"reduce_scatter: len {x.shape[0]} % {p} != 0")
     if algorithm == "ring":
@@ -577,7 +576,7 @@ def all_gather(seg: jax.Array, axes: tuple[str, ...], *,
                                stagger=-1 if ordered else stagger)
     if algorithm in ("rhd", "fixed_tree"):
         if ordered:
-            seg = lax.ppermute(seg, inner, _bitrev_perm(_axis_size(inner)))
+            seg = lax.ppermute(seg, inner, _bitrev_perm(lax.axis_size(inner)))
         return rhd_all_gather(seg, inner)
     if algorithm == "psum":
         return lax.all_gather(seg, inner, tiled=True)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size as _axis_size, axis_tuple as _axis_tuple
+from repro.compat import axis_tuple as _axis_tuple
 
 INT8_MAX = 127.0
 
@@ -71,7 +71,7 @@ def quantized_reduce_scatter(x: jax.Array, axis: str, *, block: int = 256,
     unpadded input length, which :func:`quantized_all_gather` needs to
     invert the pad.
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     # pad so each of the P chunks is a multiple of `block`
     xp, n = _pad_last(x, p * block)
     chunk_len = xp.shape[0] // p
@@ -122,7 +122,7 @@ def quantized_allreduce(x: jax.Array, axis: str, *, block: int = 256,
     """
     red, n = quantized_reduce_scatter(x, axis, block=block)
     if mean:
-        red = red / _axis_size(axis)
+        red = red / lax.axis_size(axis)
     return quantized_all_gather(red, axis, block=block, dtype=x.dtype, n=n)
 
 
@@ -142,10 +142,10 @@ def quantized_allreduce_hier(x: jax.Array, inner_axis: str, outer_axes,
     innermost first.
     """
     red, n = quantized_reduce_scatter(x, inner_axis, block=block)
-    world = _axis_size(inner_axis)
+    world = lax.axis_size(inner_axis)
     for ax in _axis_tuple(outer_axes):
         red = quantized_allreduce(red, ax, block=block)
-        world *= _axis_size(ax)
+        world *= lax.axis_size(ax)
     if mean:
         red = red / world
     return quantized_all_gather(red, inner_axis, block=block, dtype=x.dtype,
@@ -157,7 +157,7 @@ def quantized_reduce_scatter_batched(x: jax.Array, axis: str, *,
                                      ) -> tuple[jax.Array, int]:
     """Reduce-scatter leg for a whole ``(B, Z)`` arena: ONE ``all_to_all``
     (plus one for scales) carries every bucket's int8 chunks."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     b = x.shape[0]
     xp, n = _pad_last(x, p * block)
     chunk = xp.shape[-1] // p
@@ -201,7 +201,7 @@ def quantized_allreduce_batched(x: jax.Array, axis: str, *, block: int = 256,
     """
     red, n = quantized_reduce_scatter_batched(x, axis, block=block)
     if mean:
-        red = red / _axis_size(axis)
+        red = red / lax.axis_size(axis)
     return quantized_all_gather_batched(red, axis, block=block, dtype=x.dtype,
                                         n=n)
 
@@ -217,10 +217,10 @@ def quantized_allreduce_hier_batched(x: jax.Array, inner_axis: str,
     carrying all B buckets.
     """
     red, n = quantized_reduce_scatter_batched(x, inner_axis, block=block)
-    world = _axis_size(inner_axis)
+    world = lax.axis_size(inner_axis)
     for ax in _axis_tuple(outer_axes):
         red = quantized_allreduce_batched(red, ax, block=block)
-        world *= _axis_size(ax)
+        world *= lax.axis_size(ax)
     if mean:
         red = red / world
     return quantized_all_gather_batched(red, inner_axis, block=block,

@@ -42,6 +42,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro import compat
 from repro.core import arena as arena_mod
@@ -192,7 +193,7 @@ class GradReducer:
         return self._reduce_legacy(grads, state)
 
     def _world(self) -> int:
-        return compat.world_size(self.config.axes)
+        return lax.axis_size(tuple(self.config.axes))
 
     def _transport(self, dtype, *, batched: bool):
         """Group transport; dtype-suffixed tenant names under a manager

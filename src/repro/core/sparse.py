@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size as _axis_size, axis_tuple as _axis_tuple
+from repro.compat import axis_tuple as _axis_tuple
 from repro.core import collectives as coll
 
 #: Sentinel index marking an empty slot; sorts after every valid index.
@@ -151,7 +151,7 @@ def _merge_over_axis(idx, val, dense, cap: int, axis: str, size: int,
     crossover (the paper's hash-at-the-leaves / array-at-the-root split,
     now spanning tree levels).  Returns the updated state.
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if not (p > 0 and (p & (p - 1)) == 0):
         raise ValueError(f"sparse merge requires power-of-two P, got {p}")
     steps = p.bit_length() - 1
@@ -195,7 +195,7 @@ def sparse_allreduce(x: jax.Array, axis: str, k: int, *,
     hash-at-the-leaves / array-at-the-root split, with the crossover depth
     chosen statically from (k, Z, threshold).
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if not (p > 0 and (p & (p - 1)) == 0):
         raise ValueError(f"sparse_allreduce requires power-of-two P, got {p}")
     size = x.shape[0]
@@ -252,7 +252,7 @@ def sparse_allreduce_batched(x: jax.Array, axis: str,
     extent); the static list capacity is ``max(ks)`` and smaller buckets
     mask their tails with sentinels.
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if not (p > 0 and (p & (p - 1)) == 0):
         raise ValueError(f"sparse_allreduce requires power-of-two P, got {p}")
     b, size = x.shape
@@ -284,7 +284,7 @@ def _dense_outer(v: jax.Array, axis: str) -> jax.Array:
     ring otherwise — the dense exchange must work for *any* pod count
     (it is also the fallback for meshes the sparse hierarchical merge
     cannot cross)."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if p & (p - 1):
         return coll.allreduce_ring(v, axis)
     return coll.allreduce_rhd(v, axis)
@@ -307,7 +307,7 @@ def sparse_allreduce_two_level(x: jax.Array, inner_axis: str, outer_axis: str,
                                      k_eff=k_eff)
     reduced = _dense_outer(reduced, outer_axis)
     if mean:
-        total = _axis_size(inner_axis) * _axis_size(outer_axis)
+        total = lax.axis_size(inner_axis) * lax.axis_size(outer_axis)
         reduced = reduced / total
     return reduced, mine
 
@@ -328,7 +328,7 @@ def sparse_allreduce_two_level_batched(x: jax.Array, inner_axis: str,
         x, inner_axis, ks, density_threshold=density_threshold)
     reduced = jax.vmap(lambda v: _dense_outer(v, outer_axis))(reduced)
     if mean:
-        total = _axis_size(inner_axis) * _axis_size(outer_axis)
+        total = lax.axis_size(inner_axis) * lax.axis_size(outer_axis)
         reduced = reduced / total
     return reduced, mine
 
@@ -361,7 +361,7 @@ def sparse_allreduce_hier(x: jax.Array, inner_axis: str, outer_axes,
     cap = k
     world = 1
     for axis in (inner_axis, *_axis_tuple(outer_axes)):
-        world *= _axis_size(axis)
+        world *= lax.axis_size(axis)
         idx, val, dense, cap = _merge_over_axis(
             idx, val, dense, cap, axis, size, density_threshold, scatter32,
             _exchange_flat)
@@ -403,7 +403,7 @@ def sparse_allreduce_hier_batched(x: jax.Array, inner_axis: str,
     cap = k_max
     world = 1
     for axis in (inner_axis, *_axis_tuple(outer_axes)):
-        world *= _axis_size(axis)
+        world *= lax.axis_size(axis)
         idx, val, dense, cap = _merge_over_axis(
             idx, val, dense, cap, axis, size, density_threshold, scatter32,
             _exchange_lists)

@@ -55,7 +55,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core import collectives as coll, compression, sparse, topology
 
 #: Quantization block of the int8 transport; ``GradReducer`` folds
@@ -101,7 +100,7 @@ class Transport:
         return False
 
     def _world(self) -> int:
-        return compat.world_size(self.axes)
+        return lax.axis_size(tuple(self.axes))
 
     def _use_hierarchy(self) -> bool:
         """Resolve flat vs hierarchical at trace time, tree as arbiter."""
@@ -109,7 +108,7 @@ class Transport:
             return False
         if self.hierarchical is not None:
             return self.hierarchical
-        sizes = tuple(compat.axis_size(a) for a in self.axes)
+        sizes = tuple(lax.axis_size(a) for a in self.axes)
         tree = topology.build_mesh_tree(sizes)
         return topology.transport_schedule(tree) == "hierarchical"
 
@@ -234,7 +233,7 @@ class SparseTransport(Transport):
         if ef is None:
             ef = jnp.zeros_like(buf)
         *outer_axes, inner = self.axes
-        p = compat.axis_size(inner)
+        p = lax.axis_size(inner)
         if p & (p - 1):
             raise ValueError(
                 f"sparse transport requires a power-of-two inner axis; "
@@ -251,7 +250,7 @@ class SparseTransport(Transport):
             # for any outer size — the pre-hierarchy behavior); an
             # explicit hierarchical=True is a config error.
             bad = [a for a in outer_axes
-                   if compat.axis_size(a) & (compat.axis_size(a) - 1)]
+                   if lax.axis_size(a) & (lax.axis_size(a) - 1)]
             if bad and self.hierarchical:
                 raise ValueError(
                     f"hierarchical sparse transport requires power-of-two "
@@ -400,7 +399,7 @@ class SwitchTransport(Transport):
             wire_dtype, elems = jnp.int8, s + (-s) % self.block
         else:
             wire_dtype, elems = jnp.int32, 2 * max(ks)
-        sizes = tuple(compat.axis_size(a) for a in self.axes)
+        sizes = tuple(lax.axis_size(a) for a in self.axes)
         self.telemetry.record_switch_counters(
             tenant, dataplane.plan_counters(
                 self.axes, sizes, b, elems, wire_dtype,

@@ -1,8 +1,16 @@
 """Public jit'd wrappers over the Pallas kernels.
 
 These handle padding/reshaping and interpret-mode dispatch (kernels run
-``interpret=True`` off-TPU so CPU tests execute the same kernel bodies),
-and fall back to the jnp oracle for shapes the kernels don't tile.
+``interpret=True`` off-TPU so CPU tests execute the same kernel bodies).
+The four switch-handler wrappers (``tree_reduce``, ``tree_reduce_slots``,
+``dequant_accum_slots``, ``sparse_accum_slots``) pad any shape up to
+their tiling and slice the result, so on a TPU they always run the
+compiled kernel; only off-TPU may the slot folds and the densify step
+route to their ``kernels/ref.py`` oracle.
+
+XLA cannot partition a Mosaic kernel: on a TPU these calls must sit
+where every mesh axis is manual (``train.trainer`` makes its size-1
+axes manual for this).
 """
 from __future__ import annotations
 
@@ -18,11 +26,30 @@ from repro.kernels import topk_compact as _tk
 from repro.kernels import tree_reduce as _tr
 
 
-def _pad_axis0(x, m):
-    rem = (-x.shape[0]) % m
-    if rem:
-        x = jnp.concatenate([x, jnp.zeros((rem,) + x.shape[1:], x.dtype)])
-    return x
+def _on_tpu() -> bool:
+    """Kernels compile for a TPU backend and are interpreted elsewhere."""
+    return jax.default_backend() == "tpu"
+
+
+def _pad_to(x, axis: int, m: int, value=0):
+    """Pad ``axis`` of ``x`` up to a multiple of ``m`` with ``value``."""
+    rem = (-x.shape[axis]) % m
+    if not rem:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, rem)
+    return jnp.pad(x, widths, constant_values=value)
+
+
+def _tile(n: int, tiles) -> int:
+    """The largest of ``tiles`` that divides ``n``."""
+    return next(t for t in tiles if n % t == 0)
+
+
+#: The TPU tiles the last two dims of a block by (8 sublanes, 128 lanes).
+_SUBLANES, _LANES = 8, 128
+#: Slot-axis block heights, largest first: S pads to a multiple of 8 only.
+_SLOT_TILES = (64, 32, 16, 8)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "accum_dtype"))
@@ -41,10 +68,12 @@ def tree_reduce(x: jax.Array, tile_n: int = 2048,
     pp = 1 << max(0, (p - 1).bit_length())
     if pp != p:
         x = jnp.concatenate([x, jnp.zeros((pp - p, n), x.dtype)])
-    tile = min(tile_n, n)
-    if n % tile:
-        return _ref.tree_reduce(x, accum_dtype=accum_dtype)
-    return _tr.tree_reduce(x, tile_n=tile, accum_dtype=accum_dtype)
+    # the fold is elementwise over N, so zero columns past N never touch
+    # the bits of the first N; a 1-D bf16 block needs >= 2 x 128 lanes
+    tile = min(tile_n, n + (-n) % (2 * _LANES))
+    out = _tr.tree_reduce(_pad_to(x, 1, tile), tile_n=tile,
+                          accum_dtype=accum_dtype, interpret=not _on_tpu())
+    return out[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_e", "accum_dtype"))
@@ -67,18 +96,22 @@ def tree_reduce_slots(x: jax.Array, tile_e: int | None = None,
     pp = 1 << max(0, (p - 1).bit_length())
     if pp != p:
         x = jnp.concatenate([x, jnp.zeros((pp - p, s, e), x.dtype)])
-    if jax.default_backend() != "tpu":
+    if not _on_tpu():
         return _ref.tree_reduce(x, accum_dtype=accum_dtype)
-    tile_s = 64 if s % 64 == 0 else (8 if s % 8 == 0 else 1)
-    return _tr.tree_reduce_slots(x, tile_s=tile_s, tile_e=tile_e,
-                                 accum_dtype=accum_dtype)
+    # S pads to the (8, 128) sublane tiling; the fold is elementwise per
+    # slot, so the padded slots never change the bits of the real ones
+    x = _pad_to(x, 1, _SUBLANES)
+    out = _tr.tree_reduce_slots(x, tile_s=_tile(x.shape[1], _SLOT_TILES),
+                                tile_e=tile_e, accum_dtype=accum_dtype,
+                                interpret=False)
+    return out[:s]
 
 
 @functools.partial(jax.jit, static_argnames=("qblock",))
 def quantize(x: jax.Array, qblock: int = 256):
     n = x.shape[0]
     if n % qblock:
-        return _ref.quantize(_pad_axis0(x, qblock), qblock)
+        return _ref.quantize(_pad_to(x, 0, qblock), qblock)
     nb = n // qblock
     tile_b = 64 if nb % 64 == 0 else (8 if nb % 8 == 0 else 1)
     return _quant.quantize(x, qblock=qblock, tile_b=tile_b)
@@ -125,8 +158,12 @@ def dequant_accum_slots(q: jax.Array, scales: jax.Array,
         # same contract as dequant_accum: the caller owns the per-slot
         # scales layout, so a ragged E means the scales shape is wrong
         raise ValueError(f"dequant_accum_slots: E={e} % qblock={qblock} != 0")
-    tile_s = 64 if s % 64 == 0 else (8 if s % 8 == 0 else 1)
-    return _quant.dequant_accum_slots(q, scales, qblock=qblock, tile_s=tile_s)
+    q = _pad_to(q, 1, _SUBLANES)
+    scales = _pad_to(scales, 1, _SUBLANES)
+    out = _quant.dequant_accum_slots(q, scales, qblock=qblock,
+                                     tile_s=_tile(q.shape[1], _SLOT_TILES),
+                                     interpret=not _on_tpu())
+    return out[:s]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block"))
@@ -134,7 +171,7 @@ def topk_compact(x: jax.Array, k: int, block: int = 512):
     """Per-block magnitude top-k → (values, local indices), -1 padded."""
     n = x.shape[0]
     if n % block:
-        x = _pad_axis0(x, block)
+        x = _pad_to(x, 0, block)
         n = x.shape[0]
     nb = n // block
     tile_b = 8 if nb % 8 == 0 else 1
@@ -167,16 +204,22 @@ def sparse_accum_slots(idx: jax.Array, val: jax.Array, size: int,
     only where indirect writes are expensive (TPU).  Off-TPU the
     interpreted grid loops a tiny matmul thousands of times while the
     backend has a perfectly good native scatter, so dispatch follows the
-    backend, not just the tiling.
+    backend.  On a TPU any shape pads to the kernel's tiling: buckets to
+    8 and entries to 128 with dropped (-1) entries, the dense size to
+    128 with columns sliced off after.
     """
     b, e = idx.shape
-    tile_z = 2048 if size % 2048 == 0 else (256 if size % 256 == 0 else 0)
-    tile_e = 512 if e % 512 == 0 else (64 if e % 64 == 0 else (8 if e % 8 == 0
-                                                               else 0))
-    if jax.default_backend() != "tpu" or not tile_z or not tile_e:
+    if not _on_tpu():
         return _ref.sparse_accum_slots(idx, val, size, out_dtype)
-    return _sa.sparse_accum_slots(idx, val, size, tile_z=tile_z,
-                                  tile_e=tile_e, out_dtype=out_dtype)
+    idx = _pad_to(_pad_to(idx, 0, _SUBLANES, -1), 1, _LANES, -1)
+    val = _pad_to(_pad_to(val, 0, _SUBLANES), 1, _LANES)
+    size_p = size + (-size) % _LANES
+    out = _sa.sparse_accum_slots(
+        idx, val, size_p, tile_b=_SUBLANES,
+        tile_z=_tile(size_p, (2048, 1024, 512, 256, 128)),
+        tile_e=_tile(idx.shape[1], (512, 256, 128)),
+        out_dtype=out_dtype, interpret=False)
+    return out[:b, :size]
 
 
 def blockwise_sparsify(x: jax.Array, k: int, block: int = 512):

@@ -79,24 +79,27 @@ def sparse_accum(idx: jax.Array, val: jax.Array, size: int, *,
 def _sparse_accum_slots_kernel(idx_ref, val_ref, o_ref, *, tile_z):
     zt = pl.program_id(1)
     et = pl.program_id(2)
-    idx = idx_ref[...][0]                         # (TILE_E,) int32, bucket-local
-    val = val_ref[...][0].astype(jnp.float32)     # (TILE_E,)
-    z_lo = zt * tile_z
-    local = idx - z_lo
-    e = idx.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (e, tile_z), 1)
-    onehot = (cols == local[:, None]).astype(jnp.float32)   # OOB rows all-zero
-    contrib = val[None, :] @ onehot               # (1, TILE_Z) on the MXU
+    idx = idx_ref[...]                            # (TILE_B, TILE_E) bucket-local
+    val = val_ref[...].astype(jnp.float32)        # (TILE_B, TILE_E)
+    local = idx - zt * tile_z
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_z, idx.shape[1]), 0)
 
     @pl.when(et == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += contrib.astype(o_ref.dtype)
+    for r in range(idx.shape[0]):                 # static unroll over buckets
+        # transposed one-hot (TILE_Z, TILE_E): OOB/sentinel columns all-zero
+        onehot_t = (rows == local[r:r + 1, :]).astype(jnp.float32)
+        contrib = jax.lax.dot_general(            # (1, TILE_Z) on the MXU
+            val[r:r + 1, :], onehot_t, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        o_ref[r:r + 1, :] += contrib.astype(o_ref.dtype)
 
 
 def sparse_accum_slots(idx: jax.Array, val: jax.Array, size: int, *,
-                       tile_z: int = 2048, tile_e: int = 512,
+                       tile_b: int = 8, tile_z: int = 2048, tile_e: int = 512,
                        out_dtype=jnp.float32,
                        interpret: bool | None = None) -> jax.Array:
     """Batched ``sparse_accum``: (B, E) coordinate lists → (B, size) buffers.
@@ -104,12 +107,17 @@ def sparse_accum_slots(idx: jax.Array, val: jax.Array, size: int, *,
     The batched switch root densifies every bucket's merged coordinate
     list in one call instead of one scatter per bucket.  Indices are
     bucket-local (``0 ≤ idx < size``; out-of-range/sentinel entries drop).
-    Grid is (buckets × dense tiles × entry tiles) with the entry axis
-    innermost, so each (bucket, dense-tile) output block accumulates its
-    entry tiles in order — the same entry-major order as the per-bucket
-    kernel, hence identical bits per bucket.
+    Grid is (bucket tiles × dense tiles × entry tiles) with the entry
+    axis innermost, so each (buckets, dense-tile) output block
+    accumulates its entry tiles in order.  A block holds ``tile_b``
+    buckets: the TPU tiles the last two block dims by (8, 128), so a
+    one-bucket block only compiles when ``B == 1``.
     """
     b, e = idx.shape
+    tile_b = min(tile_b, b)
+    if b % tile_b:
+        raise ValueError(
+            f"sparse_accum_slots: buckets={b} % tile_b={tile_b} != 0")
     if size % tile_z:
         raise ValueError(
             f"sparse_accum_slots: size={size} % tile_z={tile_z} != 0")
@@ -122,10 +130,10 @@ def sparse_accum_slots(idx: jax.Array, val: jax.Array, size: int, *,
     kernel = functools.partial(_sparse_accum_slots_kernel, tile_z=tile_z)
     return pl.pallas_call(
         kernel,
-        grid=(b, size // tile_z, e // tile_e),
-        in_specs=[pl.BlockSpec((1, tile_e), lambda i, z, t: (i, t)),
-                  pl.BlockSpec((1, tile_e), lambda i, z, t: (i, t))],
-        out_specs=pl.BlockSpec((1, tile_z), lambda i, z, t: (i, z)),
+        grid=(b // tile_b, size // tile_z, e // tile_e),
+        in_specs=[pl.BlockSpec((tile_b, tile_e), lambda i, z, t: (i, t)),
+                  pl.BlockSpec((tile_b, tile_e), lambda i, z, t: (i, t))],
+        out_specs=pl.BlockSpec((tile_b, tile_z), lambda i, z, t: (i, z)),
         out_shape=jax.ShapeDtypeStruct((b, size), out_dtype),
         interpret=interpret,
     )(idx, val)

@@ -1,9 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST stay the first statements of this module (jax
+The three lines above MUST stay the first statements of this module (jax
 locks the device count at first init).  Run as::
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \
@@ -30,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat, configs
+from repro import configs
 from repro.core.engine import FlareConfig
 from repro.data import pipeline
 from repro.launch import analytic, hlo_analysis, mesh as mesh_mod
@@ -107,7 +108,7 @@ def run_cell(arch: str, cell, *, multi_pod: bool, out_dir: str,
     label = f"{arch}.{cell.name}.{mesh_name}" + (f".{tag}" if tag else "")
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             lowered = _train_lowered(model, mesh, mcfg, cell,
                                      flare_algorithm, gather_algorithm)

@@ -7,8 +7,8 @@ the first device query, and tests must see 1 CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro import compat
 from repro.sharding.rules import MeshCfg
 
 SINGLE_POD = (16, 16)                 # 256 chips: (data, model)
@@ -43,7 +43,8 @@ def make_fake_mesh(shape=FAKE_2D, axes: tuple[str, ...] | None = None):
             f"{len(jax.devices())} — set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
             "any jax import")
-    return compat.make_mesh(tuple(shape), tuple(axes), devices=devices)
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -59,7 +60,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             "— the dry-run must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    return compat.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def mesh_cfg(*, multi_pod: bool = False) -> MeshCfg:

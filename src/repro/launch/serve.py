@@ -19,6 +19,7 @@ def main():
     if args.fake_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.fake_devices}")
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
     import jax.numpy as jnp

@@ -3,13 +3,17 @@
 Runs the Flare train step (shard_map + FSDP-gather + GradReducer) on
 whatever devices exist (real TPUs, or ``--fake-devices N`` CPU devices
 for local bring-up), with checkpointing and failure-recovery wiring.
+:func:`main` parses the command line and picks the model config;
+:func:`run` trains a given ``ModelConfig`` under parsed arguments, so
+other entry points (``chip_smoke.py``) reuse the same path with a cut
+model.
 """
 import argparse
 import os
 import sys
 
 
-def _parse():
+def _parse(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true",
@@ -87,7 +91,7 @@ def _parse():
                          "JSON (needs --health-policy; gate in CI with "
                          "`python -m repro.obs.report --incidents PATH "
                          "--fail-on critical`)")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
 def _fault_plan(args):
@@ -182,7 +186,6 @@ def _run_tenants(args, mesh, mcfg, cfg, model, batch_shapes):
 
     import jax
 
-    from repro import compat
     from repro.core.engine import FlareConfig
     from repro.data import pipeline
     from repro.runtime import SessionManager
@@ -214,7 +217,7 @@ def _run_tenants(args, mesh, mcfg, cfg, model, batch_shapes):
             donate=False, reduce_manager=manager, tenant=f"job{k}")
 
     jobs = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # phase 1 — registration traces: sessions open at *trace* time,
         # and jit is lazy, so without this pass tenant 0 would compile
         # seeing an empty switch (no contention) and earlier tenants
@@ -228,9 +231,9 @@ def _run_tenants(args, mesh, mcfg, cfg, model, batch_shapes):
         # phase 2 — the real builds: fresh traces now see the full mix
         for k in range(args.tenants):
             kw, (fn, param_sh, opt_sh, batch_sh, init_opt) = build(k)
-            params = jax.device_put(model.init(jax.random.PRNGKey(k)),
-                                    param_sh)
-            opt = jax.device_put(init_opt(params), opt_sh)
+            params = jax.jit(model.init, out_shardings=param_sh)(
+                jax.random.PRNGKey(k))
+            opt = jax.jit(init_opt, out_shardings=opt_sh)(params)
             stream = pipeline.synthetic_batches(cfg, args.batch, args.seq,
                                                 shardings=batch_sh,
                                                 seed=100 + k)
@@ -272,17 +275,39 @@ def _run_tenants(args, mesh, mcfg, cfg, model, batch_shapes):
     _export(args, telemetry, manager)
 
 
-def main():
-    args = _parse()
+def main(argv=None):
+    args = _parse(argv)
     if args.fake_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.fake_devices}")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+
+    import jax.numpy as jnp
+
+    from repro import configs
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+    mod = configs.load(args.arch)
+    cfg = (mod.SMOKE if args.smoke else mod.CONFIG)
+    if args.smoke:
+        cfg = cfg.scaled(dtype=jnp.float32)
+    run(args, cfg)
+
+
+def run(args, cfg) -> dict | None:
+    """Train ``cfg`` as the parsed ``args`` say, on ``jax.devices()``.
+
+    Returns ``{"step", "compile_s", "losses", "step_s"}`` for a single
+    job (the compiled step program and its compile time, then one loss
+    and one host-clock step time per step); ``None`` for
+    ``--tenants > 1``.
+    """
+    import time
 
     import jax
-    import jax.numpy as jnp
-    import numpy as np
+    from jax.sharding import AxisType
 
-    from repro import compat, configs
     from repro.core.engine import FlareConfig
     from repro.data import pipeline
     from repro.ft import CheckpointManager
@@ -297,13 +322,9 @@ def main():
         axes, shape = ("pod", "data", "model"), tuple(dims)
     else:
         sys.exit("--mesh must be DxM or PxDxM")
-    mesh = compat.make_mesh(shape, axes)
+    mesh = jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
     mcfg = rules.MeshCfg(axes, shape)
-
-    mod = configs.load(args.arch)
-    cfg = (mod.SMOKE if args.smoke else mod.CONFIG)
-    if args.smoke:
-        cfg = cfg.scaled(dtype=jnp.float32)
     model = get_model(cfg)
 
     key = jax.random.PRNGKey(0)
@@ -329,7 +350,8 @@ def main():
         # builds its own innetwork configs (a --fault-rate without
         # --transport innetwork is valid there and would fail the
         # single-job validation below)
-        return _run_tenants(args, mesh, mcfg, cfg, model, batch_shapes)
+        _run_tenants(args, mesh, mcfg, cfg, model, batch_shapes)
+        return None
 
     telemetry = _telemetry(args)
     tcfg = trainer.TrainConfig(
@@ -344,12 +366,15 @@ def main():
                           fault_plan=_fault_plan(args),
                           telemetry=telemetry))
 
-    with compat.set_mesh(mesh):
+    result = {"losses": [], "step_s": []}
+    with jax.set_mesh(mesh):
         fn, param_sh, opt_sh, batch_sh, init_opt = trainer.jit_train_step(
             model, mesh, mcfg, tcfg, params_shapes, batch_shapes,
             donate=True)
-        params = jax.device_put(model.init(key), param_sh)
-        opt = jax.device_put(init_opt(params), opt_sh)
+        # initialise in place, already sharded: an eager init would
+        # materialise the whole model on the first device
+        params = jax.jit(model.init, out_shardings=param_sh)(key)
+        opt = jax.jit(init_opt, out_shardings=opt_sh)(params)
 
         start = 0
         cm = None
@@ -362,24 +387,37 @@ def main():
                 params, opt = state["p"], state["o"]
                 print(f"resumed from step {start}")
 
+        t0 = time.perf_counter()
+        batch_in = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            batch_shapes, batch_sh)
+        step_fn = fn.lower(params, opt, batch_in).compile()
+        result["step"] = step_fn
+        result["compile_s"] = time.perf_counter() - t0
+        print(f"compiled train step in {result['compile_s']:.1f}s",
+              flush=True)
+
         stream = pipeline.synthetic_batches(cfg, args.batch, args.seq,
                                             shardings=batch_sh, seed=1)
-        import time
         for step in range(start, args.steps):
-            t0 = time.time()
+            t0 = time.perf_counter()
             batch = next(stream)
             with _step_span(telemetry, step):
-                params, opt, metrics = fn(params, opt, batch)
+                params, opt, metrics = step_fn(params, opt, batch)
                 loss = float(metrics["loss"])
+            dt = time.perf_counter() - t0
+            result["losses"].append(loss)
+            result["step_s"].append(dt)
             print(f"step {step:5d} loss {loss:8.4f} "
                   f"gnorm {float(metrics['grad_norm']):8.3f} "
-                  f"dt {time.time() - t0:6.3f}s", flush=True)
+                  f"dt {dt:6.3f}s", flush=True)
             if cm and args.ckpt_every and (step + 1) % args.ckpt_every == 0:
                 cm.save(step + 1, {"p": params, "o": opt})
         if cm:
             cm.wait()
     _health(args, telemetry)
     _export(args, telemetry)
+    return result
 
 
 if __name__ == "__main__":

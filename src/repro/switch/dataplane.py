@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from repro.core import collectives as coll
 from repro.core import compression, sparse, topology
 from repro.kernels import ops
@@ -70,7 +69,7 @@ def resolve_design(data_bytes: int, design: str = "auto",
 
 
 def _levels(axes: Sequence[str]) -> tuple[topology.MeshLevel, ...]:
-    sizes = tuple(compat.axis_size(a) for a in axes)
+    sizes = tuple(lax.axis_size(a) for a in axes)
     return topology.mesh_levels(tuple(axes), sizes)
 
 
@@ -121,7 +120,7 @@ def _gather_children(tree: Any, axis: str) -> Any:
     ``stagger=-1`` pins slot order to child rank so the stack arrives in
     canonical order before any arrival permutation is applied.
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
 
     def g(leaf):
         flat = coll.ring_all_gather(leaf, axis, stagger=-1)
@@ -138,7 +137,7 @@ def _multicast(tree: Any, axis: str, switch_rank: int = 0) -> Any:
     tree).  Otherwise a ring broadcast (P−1 hops).  Non-switch ranks'
     payloads are masked zeros and are simply overwritten.
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     if p == 1:
         return tree
     r = lax.axis_index(axis)
@@ -588,7 +587,7 @@ def switch_allreduce_dense(arena: jax.Array, axes: Sequence[str], *,
             for lvl in reversed(levels):
                 cur = _multicast_arena(cur, lvl, fmt)
     if mean:
-        cur = cur / compat.world_size(axes)
+        cur = cur / lax.axis_size(tuple(axes))
     return (cur, fstats) if with_fault_stats else cur
 
 
@@ -710,7 +709,7 @@ def switch_allreduce_int8(arena: jax.Array, axes: Sequence[str], *,
     out = compression.dequantize_int8(q, scales, block, dtype=arena.dtype)
     out = out[:, :s0]
     if mean:
-        out = out / compat.world_size(axes)
+        out = out / lax.axis_size(tuple(axes))
     return (out, fstats) if with_fault_stats else out
 
 
@@ -782,7 +781,7 @@ def switch_allreduce_sparse(arena: jax.Array, axes: Sequence[str],
     if len(levels) == 1 and levels[0].fanin == 1:
         out = mine.astype(jnp.float32)
         if mean:
-            out = out / compat.world_size(axes)
+            out = out / lax.axis_size(tuple(axes))
         ret = [out.astype(arena.dtype), mine]
         if with_stats:
             ret.append({"collisions": jnp.zeros((), jnp.int32),
@@ -886,7 +885,7 @@ def switch_allreduce_sparse(arena: jax.Array, axes: Sequence[str],
             for lvl in reversed(levels):
                 dense_acc = _multicast_arena(dense_acc, lvl, fmt)
     if mean:
-        dense_acc = dense_acc / compat.world_size(axes)
+        dense_acc = dense_acc / lax.axis_size(tuple(axes))
     red = dense_acc.astype(arena.dtype)
     ret = [red, mine]
     if with_stats:

@@ -94,11 +94,7 @@ def packetize(arena: jax.Array, fmt: PacketFormat,
     b, s = arena.shape
     e = fmt.payload_elems(arena.dtype)
     npkt = fmt.packets_per_block(s, arena.dtype)
-    pad = npkt * e - s
-    if pad:
-        arena = jnp.concatenate(
-            [arena, jnp.zeros((b, pad), arena.dtype)], axis=1)
-    payload = arena.reshape(b * npkt, e)
+    payload = frame_bits(arena, npkt * e - s, (b * npkt, e))
 
     block = jnp.repeat(jnp.arange(b, dtype=jnp.int32), npkt)
     seq = jnp.tile(jnp.arange(npkt, dtype=jnp.int32), b)
@@ -125,9 +121,33 @@ def depacketize(stream: PacketStream, fmt: PacketFormat,
         raise ValueError(f"stream has {stream.num_packets} packets, plan "
                          f"wants {n} ({num_buckets} blocks x {npkt})")
     slot = stream.headers[:, HDR_BLOCK] * npkt + stream.headers[:, HDR_SEQ]
-    flat = jnp.zeros((n, e), stream.payload.dtype).at[slot].set(
-        stream.payload, mode="drop")
-    return flat.reshape(num_buckets, npkt * e)[:, :bucket_elems]
+    dtype = stream.payload.dtype
+    bits = lax.bitcast_convert_type(stream.payload, _uint_type(dtype))
+    flat = jnp.zeros((n, e), bits.dtype).at[slot].set(bits, mode="drop")
+    return unframe_bits(flat, dtype, (num_buckets, npkt * e), bucket_elems)
+
+
+def frame_bits(x: jax.Array, pad: int, shape) -> jax.Array:
+    """Zero-pad the last axis of ``x`` by ``pad`` and reshape to ``shape``.
+
+    Works on the raw bits (the same-width uint image), so float payloads
+    keep every bit: a float concatenate may quiet a signalling NaN.  The
+    one framing step shared by :func:`packetize` and
+    :meth:`FramePlan.pack`.
+    """
+    u = lax.bitcast_convert_type(x, _uint_type(x.dtype))
+    if pad:
+        u = jnp.concatenate(
+            [u, jnp.zeros((*u.shape[:-1], pad), u.dtype)], axis=-1)
+    return lax.bitcast_convert_type(u.reshape(shape), x.dtype)
+
+
+def unframe_bits(payload: jax.Array, dtype, shape, keep: int) -> jax.Array:
+    """Inverse of :func:`frame_bits`: reshape ``payload`` (any dtype of
+    ``dtype``'s width) to ``shape``, keep the first ``keep`` elements of
+    the last axis and return them as ``dtype``, bit for bit."""
+    u = lax.bitcast_convert_type(payload, _uint_type(dtype))
+    return lax.bitcast_convert_type(u.reshape(shape)[..., :keep], dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -185,11 +205,8 @@ class FramePlan:
         if (b, s) != (self.num_buckets, self.bucket_elems):
             raise ValueError(f"pack: arena {arena.shape[-2:]} != plan "
                              f"({self.num_buckets}, {self.bucket_elems})")
-        if self.pad:
-            arena = jnp.concatenate(
-                [arena, jnp.zeros((*lead, b, self.pad), arena.dtype)],
-                axis=-1)
-        return arena.reshape(*lead, self.num_packets, self.payload_elems)
+        return frame_bits(arena, self.pad,
+                          (*lead, self.num_packets, self.payload_elems))
 
     def unpack(self, payload: jax.Array) -> jax.Array:
         """``(..., n, E)`` canonical-order payload → ``(..., B, S)`` arena."""
@@ -197,9 +214,10 @@ class FramePlan:
         if (n, e) != (self.num_packets, self.payload_elems):
             raise ValueError(f"unpack: payload {payload.shape[-2:]} != plan "
                              f"({self.num_packets}, {self.payload_elems})")
-        flat = payload.reshape(*lead, self.num_buckets,
-                               self.packets_per_block * e)
-        return flat[..., :self.bucket_elems]
+        return unframe_bits(
+            payload, payload.dtype,
+            (*lead, self.num_buckets, self.packets_per_block * e),
+            self.bucket_elems)
 
     def headers(self, child_rank: int = 0) -> np.ndarray:
         """Static ``(n, HEADER_FIELDS)`` int32 headers for the canonical

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.engine import FlareConfig, GradReducer
 from repro.sharding import rules
 from repro.train import optim
@@ -115,9 +114,14 @@ def make_train_step(model, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
                      _opt_specs(manual_specs), bspec))
         out_specs = (manual_specs, _opt_specs(manual_specs),
                      {"loss": P(), "grad_norm": P()})
-        return compat.shard_map(
+        # a size-1 axis partitions nothing, so it is manual too: a
+        # compiled Pallas kernel (the in-network switch handlers) cannot
+        # sit in a region that leaves any mesh axis auto
+        manual = set(reduce_axes) | {
+            a for a, n in zip(mesh_cfg.axes, mesh_cfg.shape) if n == 1}
+        return jax.shard_map(
             step_body, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(reduce_axes), check_vma=False)
+            axis_names=manual, check_vma=False)
 
     def _opt_specs(mspecs):
         d = {"m": mspecs, "v": mspecs, "step": P()}

@@ -24,13 +24,13 @@ import sys
 if __name__ == "__main__":
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax                                                     # noqa: E402
 import jax.numpy as jnp                                        # noqa: E402
 import numpy as np                                             # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P     # noqa: E402
 
-from repro import compat                                       # noqa: E402
 from repro.core import collectives as coll                     # noqa: E402
 from repro.core import compression, fsdp, reproducible, sparse  # noqa: E402
 from repro.core import transports                              # noqa: E402
@@ -39,7 +39,8 @@ from repro.launch import mesh as launch_mesh                   # noqa: E402
 
 
 def _mesh():
-    return compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 
 def _mesh_shape() -> tuple[int, int]:
@@ -54,10 +55,10 @@ def _mesh_shape() -> tuple[int, int]:
 
 
 def _run(fn, xs, mesh, out_spec=P(None)):
-    g = jax.jit(compat.shard_map(fn, in_specs=(P(("pod", "data"), None),),
-                                 out_specs=out_spec,
-                                 axis_names={"pod", "data"}, check_vma=False))
-    with compat.set_mesh(mesh):
+    g = jax.jit(jax.shard_map(fn, in_specs=(P(("pod", "data"), None),),
+                              out_specs=out_spec,
+                              axis_names={"pod", "data"}, check_vma=False))
+    with jax.set_mesh(mesh):
         x = jax.device_put(xs, NamedSharding(mesh, P(("pod", "data"), None)))
         return np.asarray(g(x))
 
@@ -261,12 +262,12 @@ def check_transports():
 
     # HLO collective counts: independent of B for the batched transports
     def count_collectives(cfg, batched, b):
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             transport_fn(cfg, batched, b=b, ext=(S,) * b),
             in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
         x = jax.ShapeDtypeStruct((4, b * S), jnp.float32)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             txt = fn.lower(x).compile().as_text()
         return {op: len(re.findall(op + r"(?:-start)?\(", txt))
                 for op in ("collective-permute", "all-to-all", "all-gather")}
@@ -304,7 +305,7 @@ def check_transports():
 
     # construction-time sparse validation: non-power-of-two inner axis
     mesh6 = Mesh(np.array(jax.devices()[:6]), ("data",))
-    with compat.set_mesh(mesh6):
+    with jax.set_mesh(mesh6):
         try:
             GradReducer(FlareConfig(axes=("data",), sparse_k_frac=0.01))
         except ValueError as e:
@@ -327,11 +328,11 @@ def check_fsdp_engine():
                 w = fsdp.gather_params(ws, ("pod", "data"), alg)
                 return jnp.sum((x_local @ w) ** 2) / 64.0
             return jax.grad(loss)(w_shard)
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             step, in_specs=(P("data", None), P(("pod", "data"), None, None)),
             out_specs=P("data", None), axis_names={"pod", "data"},
             check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             ws = jax.device_put(W, NamedSharding(mesh, P("data", None)))
             xs = jax.device_put(X, NamedSharding(
                 mesh, P(("pod", "data"), None, None)))
@@ -376,7 +377,7 @@ def check_trainer():
     batch = {"tokens": jax.random.randint(key, (8, 16), 0, cfg.vocab),
              "labels": jax.random.randint(key, (8, 16), 0, cfg.vocab)}
     tcfg = trainer.TrainConfig(lr=1e-2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, param_sh, opt_sh, batch_sh, init_opt = trainer.jit_train_step(
             m, mesh, mcfg, tcfg, jax.eval_shape(m.init, key), batch,
             donate=False)
@@ -433,10 +434,10 @@ def check_hierarchy():
     expect = np.asarray(xs).sum(0)
 
     def run(fn, xs=xs):
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             fn, in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(xs, NamedSharding(mesh,
                                                  P(("pod", "data"), None)))
             return np.asarray(g(x))
@@ -544,10 +545,10 @@ def check_switch():
     expect = np.asarray(xs).sum(0)
 
     def run(fn, xs=xs):
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             fn, in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(xs, NamedSharding(mesh,
                                                  P(("pod", "data"), None)))
             return np.asarray(g(x))
@@ -784,10 +785,10 @@ def check_runtime():
     rng = np.random.default_rng(51)
 
     def run(fn, xs):
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             fn, in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(xs, NamedSharding(mesh,
                                                  P(("pod", "data"), None)))
             return np.asarray(g(x))
@@ -917,10 +918,10 @@ def check_sparse_densify():
                      .astype(np.float32))
 
     def run(fn):
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             fn, in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(xs, NamedSharding(mesh,
                                                  P(("pod", "data"), None)))
             return np.asarray(g(x))
@@ -1000,10 +1001,10 @@ def check_chaos():
     rng = np.random.default_rng(71)
 
     def run(fn, xs):
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             fn, in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(xs, NamedSharding(mesh,
                                                  P(("pod", "data"), None)))
             return np.asarray(g(x))
@@ -1172,10 +1173,10 @@ def check_canary():
     rng = np.random.default_rng(83)
 
     def run(fn, xs):
-        g = jax.jit(compat.shard_map(
+        g = jax.jit(jax.shard_map(
             fn, in_specs=(P(("pod", "data"), None),), out_specs=P(None),
             axis_names={"pod", "data"}, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(xs, NamedSharding(mesh,
                                                  P(("pod", "data"), None)))
             return np.asarray(g(x))
@@ -1337,11 +1338,11 @@ def check_obs():
                 red, _ = t(arena, ef, jnp.zeros((B,), jnp.int32), (S,) * B)
                 return red
 
-            g = jax.jit(compat.shard_map(
+            g = jax.jit(jax.shard_map(
                 fn, in_specs=(P(("pod", "data"), None),),
                 out_specs=P(None), axis_names={"pod", "data"},
                 check_vma=False))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 x = jax.device_put(xs, NamedSharding(
                     mesh, P(("pod", "data"), None)))
                 outs[tenant] = np.asarray(g(x))
@@ -1501,11 +1502,11 @@ def check_health():
                 red, _ = t(arena, ef, jnp.zeros((B,), jnp.int32), (S,) * B)
                 return red
 
-            g = jax.jit(compat.shard_map(
+            g = jax.jit(jax.shard_map(
                 fn, in_specs=(P(("pod", "data"), None),),
                 out_specs=P(None), axis_names={"pod", "data"},
                 check_vma=False))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 x = jax.device_put(xs, NamedSharding(
                     mesh, P(("pod", "data"), None)))
                 outs[tenant] = np.asarray(g(x))

@@ -15,6 +15,7 @@ _SCRIPT = os.path.join(os.path.dirname(__file__), "multidevice_checks.py")
 def _run_group(group: str, mesh_shape: str | None = None):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])

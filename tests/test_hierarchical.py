@@ -31,6 +31,7 @@ if __name__ == "__main__":
                     and sys.argv[1] == "sparse_nonpow2_fallback") else 8
     os.environ.setdefault(
         "XLA_FLAGS", f"--xla_force_host_platform_device_count={_N_DEV}")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 try:                                                           # noqa: E402
     import hypothesis  # noqa: F401  (conftest installs the stub in pytest)
@@ -122,11 +123,10 @@ def _run_on_mesh(mesh, fn, xs):
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
-    g = jax.jit(compat.shard_map(fn, in_specs=(P(("pod", "data"), None),),
-                                 out_specs=P(None),
-                                 axis_names={"pod", "data"}, check_vma=False))
-    with compat.set_mesh(mesh):
+    g = jax.jit(jax.shard_map(fn, in_specs=(P(("pod", "data"), None),),
+                              out_specs=P(None),
+                              axis_names={"pod", "data"}, check_vma=False))
+    with jax.set_mesh(mesh):
         x = jax.device_put(xs, NamedSharding(mesh, P(("pod", "data"), None)))
         return np.asarray(g(x))
 
@@ -278,6 +278,7 @@ def test_hierarchical_multidevice(check):
     env = dict(os.environ)
     n = CHILD_CHECKS[check][1]
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])

@@ -116,3 +116,47 @@ def test_batched_server_end_to_end():
     for r in reqs:
         assert r.done and len(r.out) >= 1
         assert all(0 <= t < cfg.vocab for t in r.out)
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.cache import enable_compile_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+print(enable_compile_cache())
+jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()
+"""
+
+
+def _cache_probe(env_dir):
+    """Run the cache helper in a child (the setting is process-wide) and
+    return the directory it reports."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_follows_env(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing and the
+    compiled program lands there."""
+    assert _cache_probe(tmp_path) == str(tmp_path)
+    assert any(tmp_path.iterdir()), "no cache entry written"
+
+
+def test_compile_cache_default_is_checkout_path():
+    """Without the variable the cache is the fixed <checkout>/.jax_cache."""
+    import pathlib
+    from repro.launch import cache
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert cache.DEFAULT_DIR == root / ".jax_cache"
+    assert _cache_probe(None) == str(root / ".jax_cache")
