@@ -1,0 +1,7 @@
+"""Share of the traced window in which no op ran on the device (mean
+over the chips), in a reduction cell."""
+from bench import trace
+
+
+def read(ctx):
+    return trace.idle_percent(ctx["trace"])
