@@ -96,6 +96,7 @@ def pad_to_multiple(x: jax.Array, m: int) -> tuple[jax.Array, int]:
 # Ring (Rabenseifner) — the paper's host-based baseline.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("flare.ring.reduce_scatter")
 def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
                         stagger: int = 0) -> jax.Array:
     """Reduce-scatter a flat vector over ``axis`` with a ppermute ring.
@@ -124,6 +125,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
     return acc
 
 
+@jax.named_scope("flare.ring.all_gather")
 def ring_all_gather(chunk: jax.Array, axis: str, *, stagger: int = 0) -> jax.Array:
     """Inverse of ``ring_reduce_scatter``: gather P chunks back to a vector."""
     p = lax.axis_size(axis)
@@ -202,6 +204,7 @@ def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
 # Recursive halving-doubling — bandwidth-optimal, log P steps.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("flare.rhd")
 def rhd_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Array:
     """Vector-halving distance-doubling reduce-scatter (power-of-two P).
 
@@ -232,6 +235,7 @@ def rhd_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Arra
     return x
 
 
+@jax.named_scope("flare.rhd")
 def rhd_all_gather(seg: jax.Array, axis: str) -> jax.Array:
     """Distance-halving all-gather inverting ``rhd_reduce_scatter``."""
     p = lax.axis_size(axis)
@@ -261,6 +265,7 @@ def allreduce_rhd(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Array:
 # Fixed-tree (tree aggregation §6.3) — reproducible, latency-optimal.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("flare.fixed_tree")
 def allreduce_fixed_tree(x: jax.Array, axis: str, *, op: Op = jnp.add,
                          accum_dtype: jnp.dtype | None = None) -> jax.Array:
     """Recursive-doubling allreduce over a fixed aligned binary tree.
@@ -295,6 +300,7 @@ def allreduce_fixed_tree(x: jax.Array, axis: str, *, op: Op = jnp.add,
 # Two-level hierarchical — the in-network reduction tree (§1, §4).
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("flare.two_level")
 def allreduce_two_level(x: jax.Array, inner_axis: str, outer_axis: str, *,
                         op: Op = jnp.add,
                         inner: str = "ring",
@@ -345,6 +351,7 @@ def allreduce_two_level(x: jax.Array, inner_axis: str, outer_axis: str, *,
 # Tree-driven hierarchical schedule — the ReductionTree as source of truth.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("flare.hierarchical")
 def hierarchical_allreduce(x: jax.Array, axes: tuple[str, ...], *,
                            op: Op = jnp.add,
                            stagger: int = 0,
@@ -440,6 +447,7 @@ def hierarchical_allreduce_bucketed(arena: jax.Array, axes: tuple[str, ...],
 # Vendor baseline.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("flare.psum")
 def allreduce_psum(x: jax.Array, axes: str | tuple[str, ...]) -> jax.Array:
     """XLA's native psum — the SHARP/fixed-function analogue."""
     return lax.psum(x, axes)

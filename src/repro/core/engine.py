@@ -81,7 +81,7 @@ class FlareConfig:
     fault_plan: Any = None
     #: ``repro.obs.Telemetry`` flight recorder (DESIGN.md §16): the
     #: transports register their static wire/reliability counters and
-    #: emit trace-time phase spans into it.  ``compare=False`` — the
+    #: the data plane's retry instants into it.  ``compare=False`` — the
     #: handle never participates in equality/hashing, so attaching
     #: telemetry cannot perturb jit cache keys or session specs.
     telemetry: Any = dataclasses.field(default=None, compare=False,
@@ -230,20 +230,24 @@ class GradReducer:
         ef_out_groups: list[jax.Array | None] = []
         red_groups: list[jax.Array] = []
         for g in plan.groups:
-            buf = g.pack(leaves)
-            ef_buf = g.pack(ef_leaves) if ef_leaves is not None else None
+            with jax.named_scope("flare.pack"):
+                buf = g.pack(leaves)
+                ef_buf = g.pack(ef_leaves) if ef_leaves is not None else None
             transport = self._transport(g.dtype, batched=True)
             red, ef_red = transport(buf, ef_buf, g.staggers(c.stagger),
                                     g.valid_extents)
             red_groups.append(red)
             ef_out_groups.append(ef_red)
-        out_leaves = plan.unpack(red_groups)
+        with jax.named_scope("flare.unpack"):
+            out_leaves = plan.unpack(red_groups)
 
         out = jax.tree.unflatten(treedef, out_leaves)
         if not self.needs_state:
             return out, None
-        ef_flat = plan.unpack([e if e is not None else jnp.zeros_like(r)
-                               for e, r in zip(ef_out_groups, red_groups)])
+        with jax.named_scope("flare.unpack"):
+            ef_flat = plan.unpack(
+                [e if e is not None else jnp.zeros_like(r)
+                 for e, r in zip(ef_out_groups, red_groups)])
         return out, jax.tree.unflatten(treedef, ef_flat)
 
     # -- per-bucket loop (benchmark baseline) --------------------------------
@@ -259,19 +263,22 @@ class GradReducer:
         new_ef: list[jax.Array | None] = [None] * len(leaves)
 
         for b in buckets:
-            flat = bucketing.pack_bucket(leaves, b)
-            ef_flat = (bucketing.pack_bucket(ef_leaves, b)
-                       if self.needs_state else None)
+            with jax.named_scope("flare.pack"):
+                flat = bucketing.pack_bucket(leaves, b)
+                ef_flat = (bucketing.pack_bucket(ef_leaves, b)
+                           if self.needs_state else None)
             transport = self._transport(flat.dtype, batched=False)
             stagger = b.stagger if c.stagger else 0
             red, ef_out = transport(
                 flat[None], ef_flat[None] if ef_flat is not None else None,
                 jnp.full((1,), stagger, jnp.int32), (b.num_elements,))
-            for i, piece in bucketing.unpack_bucket(red[0], leaves, b):
-                out_leaves[i] = piece
-            if ef_out is not None:
-                for i, piece in bucketing.unpack_bucket(ef_out[0], leaves, b):
-                    new_ef[i] = piece
+            with jax.named_scope("flare.unpack"):
+                for i, piece in bucketing.unpack_bucket(red[0], leaves, b):
+                    out_leaves[i] = piece
+                if ef_out is not None:
+                    for i, piece in bucketing.unpack_bucket(ef_out[0], leaves,
+                                                            b):
+                        new_ef[i] = piece
 
         out = jax.tree.unflatten(treedef, out_leaves)
         state_out = (jax.tree.unflatten(treedef, new_ef)

@@ -41,6 +41,7 @@ def _alg(algorithm: str) -> str:
             else algorithm)
 
 
+@jax.named_scope("flare.fsdp.gather")
 def _gather_impl(shard, axes, algorithm, axis):
     *_, inner = axes
     x = jnp.moveaxis(shard, axis, 0) if axis else shard
@@ -53,6 +54,7 @@ def _gather_fwd(shard, axes, algorithm, axis):
     return _gather_impl(shard, axes, algorithm, axis), None
 
 
+@jax.named_scope("flare.fsdp.reduce_scatter")
 def _gather_bwd(axes, algorithm, axis, _res, g):
     x = jnp.moveaxis(g, axis, 0) if axis else g
     gs = coll.reduce_scatter(x, axes, algorithm=_alg(algorithm),

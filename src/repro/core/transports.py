@@ -90,7 +90,7 @@ class Transport:
     #: ``compare=False`` — attaching telemetry never changes a
     #: transport's identity, so jit cache keys and session specs are
     #: untouched.  The switch transport records its static counters and
-    #: trace-time phase spans into it; the wire transports carry it for
+    #: retry instants into it; the wire transports carry it for
     #: callers but add nothing themselves.
     telemetry: Any = dataclasses.field(default=None, compare=False,
                                        repr=False)
@@ -168,6 +168,7 @@ class Int8Transport(Transport):
     def needs_state(self) -> bool:
         return True
 
+    @jax.named_scope("flare.int8")
     def __call__(self, buf, ef, staggers, extents):
         if ef is None:
             ef = jnp.zeros_like(buf)
@@ -229,6 +230,7 @@ class SparseTransport(Transport):
     def _ks(self, extents: Sequence[int]) -> tuple[int, ...]:
         return tuple(sparse.sparse_k(self.k_frac, e) for e in extents)
 
+    @jax.named_scope("flare.sparse")
     def __call__(self, buf, ef, staggers, extents):
         if ef is None:
             ef = jnp.zeros_like(buf)

@@ -68,7 +68,7 @@ def _parse(argv=None):
                     help="export a Chrome-trace/Perfetto JSON timeline of "
                          "the run (flight recorder, DESIGN.md §16): "
                          "measured step spans, session lifecycle events, "
-                         "trace-time data-plane phases and the modeled "
+                         "the data plane's retry instants and the modeled "
                          "scheduler/perfmodel tracks, with the metric "
                          "snapshot embedded.  Summarize with "
                          "`python -m repro.obs.report`")

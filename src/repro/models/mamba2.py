@@ -102,22 +102,43 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
 def mamba_block(cfg: ModelConfig, p: dict, x: jax.Array, *,
                 cache: dict | None = None):
     """One Mamba-2 mixer.  cache = {"conv_x","conv_b","conv_c","ssm"}."""
-    b, s, d = x.shape
-    di, n, h, pd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
+    b, s, di = x.shape[0], x.shape[1], cfg.d_inner
 
-    z = x @ p["wz"]                                      # (B,S,di)
-    xin = x @ p["wx"]                                    # (B,S,di)
-    bb = x @ p["wb"]                                     # (B,S,N)
-    cc = x @ p["wc"]                                     # (B,S,N)
-    dt = x @ p["wdt"]                                    # (B,S,H)
+    with jax.named_scope("mamba2.in_proj"):
+        z = x @ p["wz"]                                  # (B,S,di)
+        xin = x @ p["wx"]                                # (B,S,di)
+        bb = x @ p["wb"]                                 # (B,S,N)
+        cc = x @ p["wc"]                                 # (B,S,N)
+        dt = x @ p["wdt"]                                # (B,S,H)
 
     cx = cache["conv_x"] if cache is not None else None
     cb = cache["conv_b"] if cache is not None else None
     ccv = cache["conv_c"] if cache is not None else None
-    xin, ncx = _causal_conv(xin, p["conv_xw"], p["conv_xb"], cx)
-    bb, ncb = _causal_conv(bb, p["conv_bw"], p["conv_bb"], cb)
-    cc, ncc = _causal_conv(cc, p["conv_cw"], p["conv_cb"], ccv)
+    with jax.named_scope("mamba2.conv"):
+        xin, ncx = _causal_conv(xin, p["conv_xw"], p["conv_xb"], cx)
+        bb, ncb = _causal_conv(bb, p["conv_bw"], p["conv_bb"], cb)
+        cc, ncc = _causal_conv(cc, p["conv_cw"], p["conv_cb"], ccv)
 
+    y, h_final = _ssd(cfg, p, xin, bb, cc, dt, cache)
+    with jax.named_scope("mamba2.out"):
+        y = y.reshape(b, s, di).astype(cfg.dtype if x.dtype != jnp.float32
+                                       else jnp.float32)
+        y = base.rmsnorm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
+        out = y @ p["out_proj"]
+    new_cache = None
+    if cache is not None:
+        new_cache = {"conv_x": ncx, "conv_b": ncb, "conv_c": ncc,
+                     "ssm": h_final}
+    return out, new_cache
+
+
+@jax.named_scope("mamba2.ssd")
+def _ssd(cfg: ModelConfig, p: dict, xin, bb, cc, dt, cache: dict | None):
+    """The SSM over the conv outputs: the chunked SSD scan (or the
+    recurrence for decode and odd lengths) and the D skip; returns
+    (y: (B,S,H,P) float32, final state)."""
+    b, s, _ = xin.shape
+    n, h, pd = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))    # (B,S,H)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))                # (H,)
@@ -145,15 +166,7 @@ def mamba_block(cfg: ModelConfig, p: dict, x: jax.Array, *,
         y = ys.transpose(1, 0, 2, 3)             # (B,S,H,P)
 
     y = y + xh.astype(jnp.float32) * p["D"][None, None, :, None]
-    y = y.reshape(b, s, di).astype(cfg.dtype if x.dtype != jnp.float32
-                                   else jnp.float32)
-    y = base.rmsnorm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
-    out = y @ p["out_proj"]
-    new_cache = None
-    if cache is not None:
-        new_cache = {"conv_x": ncx, "conv_b": ncb, "conv_c": ncc,
-                     "ssm": h_final}
-    return out, new_cache
+    return y, h_final
 
 
 def _layer_params(cfg: ModelConfig, key):
@@ -227,7 +240,8 @@ def _run(cfg: ModelConfig, params, x, *, mode: str, cache=None,
         body = base.remat(cfg, body)
     xs_cache = cache["layers"] if mode == "decode" \
         else jnp.zeros((cfg.n_layers, 0))
-    x, ys = jax.lax.scan(body, x, (params["layers"], xs_cache))
+    with jax.named_scope("mamba2.layer"):
+        x, ys = jax.lax.scan(body, x, (params["layers"], xs_cache))
     return x, ({"layers": ys} if want_cache else None)
 
 
@@ -235,11 +249,13 @@ def loss_fn(cfg: ModelConfig, params, batch, *, gather: Gather = None,
             loss_chunk: int = 2048):
     from repro.models import transformer as tf
     tokens, labels = batch["tokens"], batch["labels"]
-    x, emb = tf._embed(cfg, params, tokens, gather)
+    with jax.named_scope("lm.embed"):
+        x, emb = tf._embed(cfg, params, tokens, gather)
     x, _ = _run(cfg, params, x, mode="train", gather=gather)
-    x = base.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = tf._head(cfg, params, emb, gather)
-    return tf.chunked_ce(cfg, x, head, labels, loss_chunk)
+    with jax.named_scope("lm.head_loss"):
+        x = base.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = tf._head(cfg, params, emb, gather)
+        return tf.chunked_ce(cfg, x, head, labels, loss_chunk)
 
 
 def prefill(cfg: ModelConfig, params, batch, *, gather: Gather = None):

@@ -5,9 +5,10 @@ simulation (``scheduler.simulate_shared``), the analytic shared-switch
 model (``switch_model.model_shared``), and the lossy-fabric expectation
 (``switch_model.model_lossy``).  Each renders here into Chrome-trace
 complete events on the ``"modeled"`` process, laid alongside the
-measured (``"measured"``) and trace-time (``"trace"``) spans in the
-same export — so modeled-vs-measured drift is visible per phase in the
-Perfetto timeline, not collapsed into one scalar ratio.
+measured (``"measured"``) spans and the data plane's retry instants
+(``"trace"``) in the same export — so modeled-vs-measured drift is
+visible per phase in the Perfetto timeline, not collapsed into one
+scalar ratio.
 
 Timebase: the simulator and the model speak switch cycles; events land
 in trace microseconds via ``SwitchParams.clock_hz`` (1 GHz → 1 cycle =

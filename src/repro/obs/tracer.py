@@ -6,7 +6,7 @@ renderer lays down analytic tracks, ``repro.obs.timeline``) land in one
 event list, grouped two-deep for the trace viewer:
 
 * ``process`` — the comparison axis: ``"measured"`` (host wall-clock
-  spans), ``"trace"`` (trace-time data-plane phases), ``"modeled"``
+  spans), ``"trace"`` (the data plane's retry instants), ``"modeled"``
   (scheduler/perfmodel predictions).  Perfetto renders each as its own
   process lane, so modeled-vs-measured drift is visible per phase.
 * ``track`` — the thread lane within a process (one per tenant/session).
@@ -16,6 +16,11 @@ recording method takes ``now=``, and the tracer itself takes a
 ``clock=`` callable — ``time.perf_counter`` by default,
 :func:`counting_clock` for byte-identical exports (the determinism
 anchor: same workload + same injected clock ⇒ identical JSON).
+
+Every span and instant on ``"measured"`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name (an instant, one of
+no length), so it sits on the host plane of any JAX profiler trace,
+on the clock of the device's ops.
 
 ``ring=N`` turns the tracer into a flight recorder: a bounded deque
 keeps the **last** N events, so an always-on tracer in a long run costs
@@ -27,6 +32,8 @@ import collections
 import contextlib
 import json
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 def counting_clock(start: int = 0, tick: int = 1):
@@ -52,7 +59,9 @@ class Tracer:
         self.clock = time.perf_counter if clock is None else clock
         self.ring = ring
         self._events = collections.deque(maxlen=ring)
-        self._open: list[dict] = []      # begin() stack, matched by end()
+        # begin() stack, matched by end(): each event with the profiler
+        # annotation it opened (None off the "measured" process)
+        self._open: list[tuple[dict, TraceAnnotation | None]] = []
 
     def now(self) -> float:
         return self.clock()
@@ -77,6 +86,9 @@ class Tracer:
               "process": process, "track": str(track)}
         if args:
             ev["args"] = dict(args)
+        if process == "measured":
+            with TraceAnnotation(str(name)):
+                pass
         return self._emit(ev)
 
     def begin(self, name: str, *, track: str = "host",
@@ -87,13 +99,19 @@ class Tracer:
               "process": process, "track": str(track)}
         if args:
             ev["args"] = dict(args)
-        self._open.append(ev)
+        annotation = None
+        if process == "measured":
+            annotation = TraceAnnotation(str(name))
+            annotation.__enter__()
+        self._open.append((ev, annotation))
         return ev
 
     def end(self, *, args: dict | None = None, now=None) -> dict:
         if not self._open:
             raise RuntimeError("end() without a matching begin()")
-        ev = self._open.pop()
+        ev, annotation = self._open.pop()
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         ts = self.now() if now is None else now
         ev["dur"] = max(0.0, float(ts) - ev["ts"])
         if args:

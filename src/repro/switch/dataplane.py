@@ -32,7 +32,6 @@ consumes, cross-checked in ``tests/test_switch.py``.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Any, Sequence
 
@@ -74,24 +73,20 @@ def _levels(axes: Sequence[str]) -> tuple[topology.MeshLevel, ...]:
 
 
 class _PlaneObs:
-    """Trace-time phase spans of one data-plane build (DESIGN.md §16).
+    """The data plane's retry instants (DESIGN.md §16).
 
-    Spans land on the ``"trace"`` process, track ``plane/<tenant>`` —
-    they wrap *tracing*, never add ops to the traced program, so the
-    compiled computation is byte-identical with or without telemetry
-    (the observability overhead contract).  ``telemetry=None`` degrades
-    every phase to a ``nullcontext``.
+    One ``plane.retry.l<k>`` instant per faulted level lands on the
+    ``"trace"`` process, track ``plane/<tenant>``, mirroring the static
+    ``FaultSchedule``; ``telemetry=None`` records nothing.  The plane's
+    phases are ``jax.named_scope``s (``plane.l<k>``,
+    ``plane.multicast``) in the traced program itself, so the device's
+    profiler trace times them; scopes are metadata, so the compiled
+    program is the same with or without telemetry.
     """
 
     def __init__(self, telemetry, tenant):
         self._tracer = None if telemetry is None else telemetry.tracer
         self._track = f"plane/{tenant}" if tenant else "plane/solo"
-
-    def __call__(self, name, **args):
-        if self._tracer is None:
-            return contextlib.nullcontext()
-        return self._tracer.span(name, track=self._track, process="trace",
-                                 args=args or None)
 
     def instant(self, name, **args):
         if self._tracer is not None:
@@ -563,27 +558,26 @@ def switch_allreduce_dense(arena: jax.Array, axes: Sequence[str], *,
         return (arena, fstats) if with_fault_stats else arena
     faults = fault_schedules(fault_plan, level_packet_counts(
         [l.fanin for l in levels], b, s, arena.dtype, mode="dense", fmt=fmt))
-    obs = _PlaneObs(telemetry, tenant)
-    obs.retries(faults)
+    _PlaneObs(telemetry, tenant).retries(faults)
     cur = arena
     if batched:
         plan = pk.FramePlan(b, s, arena.dtype, fmt)
         for i, lvl in enumerate(levels):
             arrival = arrival_perms[i] if arrival_perms is not None else None
-            with obs(f"plane.l{i + 1}", mode="dense", fanin=lvl.fanin):
+            with jax.named_scope(f"plane.l{i + 1}"):
                 cur = _dense_level_batched(cur, lvl, handler, design, n_bufs,
                                            plan, arrival, fault=faults[i],
                                            fault_stats=fstats)
-        with obs("plane.multicast", mode="dense"):
+        with jax.named_scope("plane.multicast"):
             cur = _multicast_root(cur, levels)
     else:
         for i, lvl in enumerate(levels):
             arrival = arrival_perms[i] if arrival_perms is not None else None
-            with obs(f"plane.l{i + 1}", mode="dense", fanin=lvl.fanin):
+            with jax.named_scope(f"plane.l{i + 1}"):
                 cur = _dense_level(cur, lvl, handler, design, n_bufs, fmt,
                                    arrival, fault=faults[i],
                                    fault_stats=fstats)
-        with obs("plane.multicast", mode="dense"):
+        with jax.named_scope("plane.multicast"):
             for lvl in reversed(levels):
                 cur = _multicast_arena(cur, lvl, fmt)
     if mean:
@@ -648,8 +642,7 @@ def switch_allreduce_int8(arena: jax.Array, axes: Sequence[str], *,
     faults = fault_schedules(fault_plan, level_packet_counts(
         [l.fanin for l in levels], b, s0, arena.dtype, mode="int8", fmt=fmt,
         block=block))
-    obs = _PlaneObs(telemetry, tenant)
-    obs.retries(faults)
+    _PlaneObs(telemetry, tenant).retries(faults)
 
     acc = xp.astype(jnp.float32)
     e = fmt.payload_elems(jnp.int8)
@@ -657,7 +650,7 @@ def switch_allreduce_int8(arena: jax.Array, axes: Sequence[str], *,
     qplan = pk.FramePlan(b, s, jnp.int8, fmt)
     splan = pk.FramePlan(b, s // block, jnp.float32, sfmt)
     for i, lvl in enumerate(levels):
-        with obs(f"plane.l{i + 1}", mode="int8", fanin=lvl.fanin):
+        with jax.named_scope(f"plane.l{i + 1}"):
             q, scales = compression.quantize_int8(acc, block)
             if batched:
                 # two collectives per level (payload + scales sideband);
@@ -695,7 +688,7 @@ def switch_allreduce_int8(arena: jax.Array, axes: Sequence[str], *,
             acc = _mask_to_switch(acc, lvl.axis, lvl.switch_rank)
 
     # root multicast: requantize once, stream int8 + scales back down
-    with obs("plane.multicast", mode="int8"):
+    with jax.named_scope("plane.multicast"):
         q, scales = compression.quantize_int8(acc, block)
         if batched:
             q, scales = _multicast_root((q, scales), levels)
@@ -796,12 +789,11 @@ def switch_allreduce_sparse(arena: jax.Array, axes: Sequence[str],
     faults = fault_schedules(fault_plan, level_packet_counts(
         [l.fanin for l in levels], b, s, arena.dtype, mode="sparse", fmt=fmt,
         k_max=k_max, density_threshold=density_threshold))
-    obs = _PlaneObs(telemetry, tenant)
-    obs.retries(faults)
+    _PlaneObs(telemetry, tenant).retries(faults)
 
     dplan = pk.FramePlan(b, s, jnp.float32, fmt)
     for i, lvl in enumerate(levels):
-        with obs(f"plane.l{i + 1}", mode="sparse", fanin=lvl.fanin):
+        with jax.named_scope(f"plane.l{i + 1}"):
             arrival = arrival_perms[i] if arrival_perms is not None else None
             if dense_acc is None and sparse.densify_step(
                     cap * lvl.fanin, s, density_threshold):
@@ -878,7 +870,7 @@ def switch_allreduce_sparse(arena: jax.Array, axes: Sequence[str],
         dense_acc = _mask_to_switch(dense_acc, levels[-1].axis,
                                     levels[-1].switch_rank)
 
-    with obs("plane.multicast", mode="sparse"):
+    with jax.named_scope("plane.multicast"):
         if batched:
             dense_acc = _multicast_root(dense_acc, levels)
         else:

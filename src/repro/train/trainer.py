@@ -88,20 +88,23 @@ def make_train_step(model, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
         grads = jax.tree.unflatten(treedef, g_leaves)
 
         # --- global grad-norm clipping -----------------------------------
-        fsdp_ss = sum(jnp.sum(g_leaves[i].astype(jnp.float32) ** 2)
-                      for i in fsdp_idx) if fsdp_idx else jnp.float32(0)
-        rep_ss = sum(jnp.sum(g_leaves[i].astype(jnp.float32) ** 2)
-                     for i in rep_idx) if rep_idx else jnp.float32(0)
-        for ax in reduce_axes:
-            fsdp_ss = jax.lax.psum(fsdp_ss, ax) if ax == "data" else fsdp_ss
-        gnorm = jnp.sqrt(fsdp_ss + rep_ss)
-        scale = jnp.minimum(1.0, tcfg.clip_norm / (gnorm + 1e-9))
-        grads = jax.tree.map(lambda g: g * scale, grads)
+        with jax.named_scope("train.clip"):
+            fsdp_ss = sum(jnp.sum(g_leaves[i].astype(jnp.float32) ** 2)
+                          for i in fsdp_idx) if fsdp_idx else jnp.float32(0)
+            rep_ss = sum(jnp.sum(g_leaves[i].astype(jnp.float32) ** 2)
+                         for i in rep_idx) if rep_idx else jnp.float32(0)
+            for ax in reduce_axes:
+                fsdp_ss = (jax.lax.psum(fsdp_ss, ax) if ax == "data"
+                           else fsdp_ss)
+            gnorm = jnp.sqrt(fsdp_ss + rep_ss)
+            scale = jnp.minimum(1.0, tcfg.clip_norm / (gnorm + 1e-9))
+            grads = jax.tree.map(lambda g: g * scale, grads)
 
         # --- ZeRO update on local shards ----------------------------------
-        new_params, new_opt = optim.adamw_update(
-            params, grads, opt_state, lr=tcfg.lr,
-            weight_decay=tcfg.weight_decay)
+        with jax.named_scope("train.adamw"):
+            new_params, new_opt = optim.adamw_update(
+                params, grads, opt_state, lr=tcfg.lr,
+                weight_decay=tcfg.weight_decay)
         if ef is not None:
             new_opt["ef"] = ef
         loss = jax.lax.psum(loss, reduce_axes)   # undo /data_world: global mean

@@ -7,7 +7,7 @@ Invoked by tests/test_collectives.py as::
 
 Groups: collectives | arena_pipeline | sparse_quant | fsdp_engine |
         trainer | repro | transports | hierarchy | switch | runtime |
-        sparse_densify | chaos | canary | obs | health
+        sparse_densify | chaos | canary | obs | health | scopes
 Exits non-zero on any failure (assertion output on stderr).
 
 The ``hierarchy``, ``switch``, ``runtime``, ``sparse_densify``,
@@ -1274,16 +1274,19 @@ def check_obs():
         telemetry, fresh jit closures → fresh traces) export
         **byte-identical** trace JSON and metrics JSON;
       * neutrality: both tenants' reductions are bitwise identical with
-        and without the telemetry handle attached (the §16 overhead
-        contract — telemetry never touches the traced program);
+        and without the telemetry handle attached, and compile to the
+        same HLO (the §16 overhead contract — telemetry never touches
+        the traced program), which carries the plane's ``plane.l<k>``
+        and ``plane.multicast`` scopes either way;
       * the exported ``switch.*`` counters are integer-equal to an
         independent ``dataplane.tree_counters`` recomputation, the
         ``tenant.*`` reliability counters to the plan's static
         ``FaultSchedule`` sums, and the traced ``plane.retry.*``
         instants carry the same retransmit total;
       * the trace carries the measured/trace/modeled processes, one
-        plane track and one modeled (fcfs + model) lane per tenant, the
-        lossy session's retry lane, and both admission instants.
+        modeled (fcfs + model) lane per tenant, the lossy session's
+        plane retry instants and modeled retry lane, and both admission
+        instants.
     """
     import json as _json
 
@@ -1320,6 +1323,8 @@ def check_obs():
     TENANTS = [("det", dict(reproducible=True)),
                ("lossy", dict(fault_plan=plan))]
 
+    texts = {}      # each tenant's compiled reduction, last run
+
     def one_run(with_telemetry=True):
         tm = (Telemetry.create(clock=counting_clock())
               if with_telemetry else None)
@@ -1346,6 +1351,7 @@ def check_obs():
                 x = jax.device_put(xs, NamedSharding(
                     mesh, P(("pod", "data"), None)))
                 outs[tenant] = np.asarray(g(x))
+                texts[tenant] = g.lower(x).compile().as_text()
         if tm is not None:
             mgr.schedule()                     # publish schedule gauges
             timeline.manager_tracks(tm.tracer, mgr)
@@ -1363,10 +1369,17 @@ def check_obs():
         assert out1[t].tobytes() == out2[t].tobytes(), f"{t}: run bits"
 
     # neutrality: the telemetry handle never changes the math
+    instrumented = dict(texts)
     _, _, bare = one_run(with_telemetry=False)
     for t in out1:
         assert out1[t].tobytes() == bare[t].tobytes(), \
             f"{t}: telemetry changed reduction bits"
+        # the plane's phases are device scopes, telemetry or not
+        assert _without_source_tables(texts[t]) == \
+            _without_source_tables(instrumented[t]), \
+            f"{t}: telemetry changed the HLO"
+        levels = {f"plane.l{i + 1}" for i in range(len(fanins))}
+        assert levels | {"plane.multicast"} <= _op_scopes(texts[t]), t
 
     # switch.* counters ≡ an independent tree_counters recomputation
     reg = tm1.registry
@@ -1408,11 +1421,12 @@ def check_obs():
     tracks = {e["args"]["name"] for e in evs
               if e.get("ph") == "M" and e["name"] == "thread_name"}
     for tenant, _kw in TENANTS:
-        assert f"plane/{tenant}" in tracks, tracks
         assert f"fcfs/{tenant}" in tracks, tracks
         assert f"model/{tenant}" in tracks, tracks
-    assert "lossy/lossy" in tracks, tracks
-    assert any(e["name"] == "plane.l1" for e in evs if e.get("ph") == "X")
+    assert "plane/lossy" in tracks and "lossy/lossy" in tracks, tracks
+    # plane phases are scopes in the program, no longer trace-time spans
+    assert not any(e["name"].startswith("plane.l") for e in evs
+                   if e.get("ph") == "X")
     admits = [e for e in evs if e.get("ph") == "i"
               and e["name"] == "session.admit"]
     assert len(admits) == len(TENANTS), admits
@@ -1619,6 +1633,106 @@ def check_health():
     print(f"health OK ({pod}x{data})")
 
 
+def _op_scopes(text: str) -> set[str]:
+    """Every scope component of the ``op_name`` paths in compiled HLO,
+    with JAX's transform wrappers (``jvp(…)``, ``transpose(…)``) off."""
+    import re
+    out = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        for c in re.split(r"[/;]", path):
+            while (m := re.fullmatch(r"[\w.\-]+\((.*)\)", c)):
+                c = m.group(1)
+            out.add(c)
+    return out
+
+
+def _without_source_tables(text: str) -> str:
+    """Compiled HLO less its source-location tables (``FileNames`` …
+    ``StackFrames``), which differ with the caller's line."""
+    import re
+    return re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n.*?\n\n", "\n", text, flags=re.S)
+
+
+def _collective_scopes(text: str) -> list[str]:
+    """The ``op_name`` of each collective instruction in compiled HLO."""
+    import re
+    return re.findall(
+        r"= [^=]* (?:collective-permute|all-reduce|all-gather|all-to-all)"
+        r'(?:-start)?\(.*op_name="([^"]*)"', text)
+
+
+def check_scopes():
+    """The reducer's, the transports' and FSDP's ``jax.named_scope``s
+    reach the compiled HLO, and every collective op sits under one.
+
+    Four fake devices: mesh ``data=4`` for the flat schedules, ``2x2``
+    (pod, data) for the two-level and hierarchical ones."""
+    flat = jax.make_mesh((4,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    two = jax.make_mesh((2, 2), ("pod", "data"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    grads = {"a": jnp.ones((4, 40, 8)), "b": jnp.ones((4, 100)),
+             "c": jnp.ones((4, 12))}
+
+    def compiled(mesh, **kw):
+        axes = mesh.axis_names
+        r = GradReducer(FlareConfig(axes=axes, **kw))
+
+        def body(g):
+            g = jax.tree.map(lambda x: x[0], g)
+            red, _ = r(g, r.init_state(g))
+            return jax.tree.map(lambda x: x[None], red)
+        spec = P(axes)
+        fn = jax.jit(jax.shard_map(body, in_specs=(spec,), out_specs=spec,
+                                   axis_names=set(axes), check_vma=False))
+        with jax.set_mesh(mesh):
+            return fn.lower(grads).compile().as_text()
+
+    cases = [
+        (flat, dict(algorithm="ring"), {"flare.pack", "flare.unpack",
+                                        "flare.ring.reduce_scatter",
+                                        "flare.ring.all_gather"}),
+        (flat, dict(algorithm="ring", arena=False),
+         {"flare.pack", "flare.unpack", "flare.ring.reduce_scatter",
+          "flare.ring.all_gather"}),
+        (flat, dict(algorithm="rhd"), {"flare.rhd"}),
+        (flat, dict(algorithm="fixed_tree"), {"flare.fixed_tree"}),
+        (flat, dict(algorithm="psum"), {"flare.psum"}),
+        (two, dict(algorithm="two_level"), {"flare.two_level"}),
+        (two, dict(algorithm="hierarchical"), {"flare.hierarchical"}),
+        (flat, dict(compression="int8"), {"flare.int8"}),
+        (flat, dict(sparse_k_frac=0.25), {"flare.sparse"}),
+    ]
+    for mesh, kw, want in cases:
+        text = compiled(mesh, **kw)
+        assert want <= _op_scopes(text), (kw, want - _op_scopes(text))
+        coll_names = _collective_scopes(text)
+        assert coll_names, kw
+        for name in coll_names:
+            assert "flare." in name, (kw, name)
+
+    # FSDP: the gather forward and the reduce-scatter backward
+    def step(w_shard, x_local):
+        def loss(ws):
+            w = fsdp.gather_params(ws, ("data",), "ring")
+            return jnp.sum((x_local @ w) ** 2)
+        return jax.grad(loss)(w_shard)
+    g = jax.jit(jax.shard_map(
+        step, in_specs=(P("data", None), P("data", None, None)),
+        out_specs=P("data", None), axis_names={"data"}, check_vma=False))
+    with jax.set_mesh(flat):
+        text = g.lower(jnp.ones((16, 4)), jnp.ones((4, 3, 16))) \
+            .compile().as_text()
+    found = _op_scopes(text)
+    assert {"flare.fsdp.gather", "flare.fsdp.reduce_scatter",
+            "flare.ring.all_gather", "flare.ring.reduce_scatter"} <= found, \
+        found
+    for name in _collective_scopes(text):
+        assert "flare.fsdp." in name, name
+    print("scopes OK")
+
+
 GROUPS = {
     "collectives": check_collectives,
     "arena_pipeline": check_arena_pipeline,
@@ -1635,6 +1749,7 @@ GROUPS = {
     "canary": check_canary,
     "obs": check_obs,
     "health": check_health,
+    "scopes": check_scopes,
 }
 
 if __name__ == "__main__":

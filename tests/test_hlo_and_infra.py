@@ -123,7 +123,11 @@ import jax, jax.numpy as jnp
 from repro.launch.cache import enable_compile_cache
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 print(enable_compile_cache())
-jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()
+for scope in ("a.first", "b.second"):     # the same ops, scoped apart
+    def f(x, scope=scope):
+        with jax.named_scope(scope):
+            return x * 2 + 1
+    jax.jit(f)(jnp.ones(8)).block_until_ready()
 """
 
 
@@ -151,6 +155,16 @@ def test_compile_cache_follows_env(tmp_path):
     compiled program lands there."""
     assert _cache_probe(tmp_path) == str(tmp_path)
     assert any(tmp_path.iterdir()), "no cache entry written"
+
+
+def test_compile_cache_keys_hold_the_scopes(tmp_path):
+    """Two modules that differ only in their ``named_scope`` are two
+    entries: a profile reads an op's scope from the executable."""
+    _cache_probe(tmp_path)
+    entries = [p for p in tmp_path.iterdir()
+               if p.name.startswith("jit_f-")
+               and not p.name.endswith("-atime")]
+    assert len(entries) == 2, entries
 
 
 def test_compile_cache_default_is_checkout_path():

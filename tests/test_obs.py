@@ -132,6 +132,40 @@ def test_tracer_chrome_export_byte_stable():
     assert x["args"] == {"fanin": 4} and x["dur"] > 0
 
 
+def test_measured_spans_reach_the_profiler_trace(tmp_path):
+    """Spans and instants on "measured" also sit on the host plane of a
+    JAX profiler trace, on the device ops' clock; other processes do
+    not."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(clock=counting_clock())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs.profiled_step", track="steps"):
+            tr.instant("obs.profiled_instant")
+            jax.block_until_ready(jax.numpy.ones(4) * 2)
+        tr.begin("obs.trace_only", process="trace")
+        tr.end()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    events = {e.name: e for p in ProfileData.from_file(path).planes
+              if p.name.startswith("/host:") for line in p.lines
+              for e in line.events}
+    assert {"obs.profiled_step", "obs.profiled_instant"} <= set(events)
+    assert "obs.trace_only" not in events
+    step, instant = events["obs.profiled_step"], events["obs.profiled_instant"]
+    assert step.start_ns <= instant.start_ns <= step.start_ns + \
+        step.duration_ns
+    # the export is the tracer's own, untouched by the profiler
+    assert [e["name"] for e in tr.events] == [
+        "obs.profiled_instant", "obs.profiled_step", "obs.trace_only"]
+
+
 def test_tracer_ring_keeps_last_events():
     tr = Tracer(clock=counting_clock(), ring=2)
     for i in range(5):
