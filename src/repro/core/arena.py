@@ -79,12 +79,13 @@ class DtypeArena:
             max(0, min(self.bucket_elems, used - b * self.bucket_elems))
             for b in range(self.num_buckets))
 
-    def staggers(self, enabled: bool = True) -> jax.Array:
-        """Per-bucket ring-phase offsets (staggered sending, §5)."""
+    def staggers(self, enabled: bool = True) -> np.ndarray:
+        """Per-bucket ring-phase offsets (staggered sending, §5), static:
+        the dense ring groups buckets by them at trace time."""
         if not enabled:
-            return jnp.zeros((self.num_buckets,), jnp.int32)
-        return self.stagger_base + jnp.arange(self.num_buckets,
-                                              dtype=jnp.int32)
+            return np.zeros((self.num_buckets,), np.int32)
+        return self.stagger_base + np.arange(self.num_buckets,
+                                             dtype=np.int32)
 
     def pack(self, leaves: Sequence[jax.Array]) -> jax.Array:
         """Gather this dtype's leaves into the (B, S) arena buffer.
@@ -139,8 +140,9 @@ def _leaf_key(leaf) -> tuple:
 
 
 @functools.lru_cache(maxsize=256)
-def _build_cached(keys: tuple, bucket_bytes: int,
-                  pad_multiple: int) -> FlatArena:
+def _build_cached(keys: tuple, bucket_bytes: int, pad_multiple: int,
+                  group_pads: tuple = ()) -> FlatArena:
+    pads = dict(group_pads)
     by_dtype: dict[str, list[int]] = {}
     for i, (_, dtype_name) in enumerate(keys):
         by_dtype.setdefault(dtype_name, []).append(i)
@@ -161,7 +163,8 @@ def _build_cached(keys: tuple, bucket_bytes: int,
         total_bytes = total * dtype.itemsize
         b = max(1, math.ceil(total_bytes / bucket_bytes))
         s = math.ceil(total / b)
-        s = max(pad_multiple, math.ceil(s / pad_multiple) * pad_multiple)
+        pad = pads.get(dtype_name, pad_multiple)
+        s = max(pad, math.ceil(s / pad) * pad)
         # shrink B if padding made later buckets entirely empty
         b = max(1, math.ceil(total / s))
         groups.append(DtypeArena(dtype, b, s, stagger_base, tuple(slots)))
@@ -171,13 +174,17 @@ def _build_cached(keys: tuple, bucket_bytes: int,
 
 def build_plan(leaves: Sequence[jax.Array | jax.ShapeDtypeStruct],
                bucket_bytes: int = 4 << 20, *,
-               pad_multiple: int = 1) -> FlatArena:
+               pad_multiple: int = 1,
+               group_pads: Sequence[tuple[str, int]] = ()) -> FlatArena:
     """Compute (or fetch) the arena plan for a sequence of leaves.
 
     ``pad_multiple`` folds the collectives' divisibility requirement into
     the plan: with ``pad_multiple = 2 * world`` every bucket length
     satisfies ring (P), pipelined ring (2P), rhd (P) and two-level
-    (P_in * P_out) chunking with zero runtime padding.
+    (P_in * P_out) chunking with zero runtime padding.  ``group_pads``
+    gives some dtype groups, by dtype name, a multiple of their own.
     """
     return _build_cached(tuple(_leaf_key(l) for l in leaves),
-                         int(bucket_bytes), int(pad_multiple))
+                         int(bucket_bytes), int(pad_multiple),
+                         tuple(sorted((str(n), int(m))
+                                      for n, m in group_pads)))

@@ -38,10 +38,11 @@ fixed-order sum for the reproducible path.
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core import topology
@@ -155,37 +156,82 @@ def allreduce_ring(x: jax.Array, axis: str, *, op: Op = jnp.add,
 
 
 # ---------------------------------------------------------------------------
-# Pipelined ring — B blocks in flight via the batched arena schedule (§6.2).
+# Batched ring — B blocks in flight, chunks picked by stagger class (§6.2).
 # ---------------------------------------------------------------------------
 #
 # The paper's multi-buffer aggregation keeps B reduction blocks in flight:
-# while block b's reduced chunks travel back down (all-gather), block b+1's
-# chunks are still being combined on the way up (reduce-scatter).  Our
-# realization is ``ring_allreduce_bucketed`` — B arbitrary blocks at once
-# via the vmapped ring: every round batches all B blocks' chunks into ONE
-# ppermute, 2(P-1) collective rounds total instead of the 2B(P-1) a
-# per-bucket loop costs.  (A single-vector double-buffer form with fused
-# all-gather/reduce-scatter waves — ``allreduce_ring_pipelined`` — was
-# retired: its fused sends measured *slower* than the plain ring it
-# pipelined, 462ms vs 281ms at 16 MiB, because a fori_loop stacking two
-# chunks per ppermute serializes exactly like two rings on the emulated
-# fabric; the arena schedule is the form that actually overlaps.)
+# every round of ``ring_allreduce_bucketed`` carries all B blocks' chunks
+# in ONE ppermute, 2(P-1) collective rounds total instead of the 2B(P-1)
+# a per-bucket loop costs.  Which chunk bucket b sends in a round,
+# ``(r - s - 1 + σ_b) % P``, depends on its stagger σ_b only through
+# σ_b mod P, so the B buckets fall into at most P *stagger classes* that
+# share one scalar chunk index per round: the schedule picks and writes
+# chunks with one ``dynamic_slice`` / ``dynamic_update_slice`` per class,
+# where a vmap over buckets made every index a per-bucket gather/scatter.
+
+#: Chunk alignment, in elements, that lets the ring's (bucket, chunk)
+#: view share the flat arena's memory on a TPU: one (8, 128) float32 tile.
+CHUNK_ALIGN = 1024
+
+
+def static_staggers(staggers) -> tuple[int, ...] | None:
+    """The per-bucket staggers as Python ints, or None when only the
+    traced program knows them."""
+    if isinstance(staggers, jax.core.Tracer):
+        return None
+    return tuple(int(s) for s in np.asarray(staggers))
+
+
+def _class_blocks(classes: Sequence[int], p: int):
+    """Tile the bucket axis into blocks whose columns share a class.
+
+    Returns ``(start, rows, width, runs)`` per block: buckets ``start ..
+    start + rows*width`` viewed as ``(rows, width)``, column ``j`` of
+    every row in class ``classes[start + j]``, and ``runs`` the maximal
+    ``(j0, j1, class)`` column ranges of one class.  The plan's staggers
+    (``base + b``, or all zero) repeat with period P, so whole groups of
+    P buckets form one block and a short tail another; staggers of no
+    period are refused.
+    """
+    b = len(classes)
+    q = b // p
+    if any(classes[i] != classes[i % p] for i in range(b)):
+        raise ValueError("ring_allreduce_bucketed: static staggers must "
+                         f"repeat with period {p}; classes {list(classes)}")
+    spans = [(0, q, p)] if q else []
+    if b > q * p:
+        spans.append((q * p, 1, b - q * p))
+    blocks = []
+    for start, rows, width in spans:
+        runs, j0 = [], 0
+        for j in range(1, width + 1):
+            if j == width or classes[start + j] != classes[start + j0]:
+                runs.append((j0, j, classes[start + j0]))
+                j0 = j
+        blocks.append((start, rows, width, runs))
+    return blocks
 
 
 def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
-                            staggers: jax.Array | None = None) -> jax.Array:
+                            staggers: Sequence[int] | jax.Array | None = None,
+                            ) -> jax.Array:
     """Ring allreduce of B equal-size buckets with all B blocks in flight.
 
     ``arena`` is ``(B, S)`` with ``S`` divisible by the axis size (the
-    arena plan guarantees this).  The schedule is the vmapped ring: round
-    s of *every* bucket's reduce-scatter (then all-gather) executes as
-    ONE batched ppermute carrying a ``(B, S/P)`` payload — the paper's B
-    concurrent reduction blocks sharing the network (§6.2), each offset
-    by its own ``stagger`` phase (§5) so no two blocks touch the same
-    chunk index in the same round.  2(P-1) collective rounds total,
-    versus 2B(P-1) for the seed's one-bucket-at-a-time loop; per bucket
-    the combine chain is exactly ``allreduce_ring``'s, so results are
-    bitwise-equal to the per-bucket loop.
+    arena plan guarantees this).  Round s of *every* bucket's
+    reduce-scatter (then all-gather) executes as ONE ppermute carrying a
+    ``(B, S/P)`` payload — the paper's B concurrent reduction blocks
+    sharing the network (§6.2), each offset by its own ``stagger`` phase
+    (§5).  Per bucket the combine chain is exactly ``allreduce_ring``'s,
+    so results are bitwise-equal to the per-bucket loop.
+
+    With static staggers (the arena plan's) each round picks and writes
+    chunks per stagger class, on a ``(rows, width, P, chunk)`` view of
+    the arena; with a chunk of whole ``CHUNK_ALIGN`` tiles that view is
+    a bitcast of the flat arena on a TPU, so XLA copies nothing in or
+    out.  Static staggers must repeat with period P, as the plan's do.
+    Staggers known only to the traced program take the vmapped
+    per-bucket ring.
     """
     b, size = arena.shape
     p = lax.axis_size(axis)
@@ -194,10 +240,103 @@ def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
     if size % p:
         raise ValueError(f"ring_allreduce_bucketed: S {size} % {p} != 0")
     if staggers is None:
-        staggers = jnp.zeros((b,), jnp.int32)
-    return jax.vmap(
-        lambda v, s: allreduce_ring(v, axis, op=op, stagger=s)
-    )(arena, staggers)
+        staggers = (0,) * b
+    sig = static_staggers(staggers)
+    if sig is None:
+        # traced staggers: only check_arena_pipeline's bitwise claim
+        # (tests/multidevice_checks.py) still passes them; the arena
+        # plan's are static, and DenseTransport vmaps traced ones itself
+        return jax.vmap(
+            lambda v, s: allreduce_ring(v, axis, op=op, stagger=s)
+        )(arena, staggers)
+    c = size // p
+    lanes = (c // 128, 128) if c % 128 == 0 else (c,)
+    blocks = _class_blocks([s % p for s in sig], p)
+    views = [arena[start:start + rows * width].reshape(
+                 (rows, width, p) + lanes)
+             for start, rows, width, _ in blocks]
+    payload = _ring_bucketed_reduce_scatter(views, blocks, axis, op, lanes)
+    # the all-gather writes into the views in place: the barrier keeps
+    # XLA from fusing the last reads of them into those writes, which
+    # would cost a copy of the whole arena
+    views, payload = lax.optimization_barrier((views, payload))
+    out = _ring_bucketed_all_gather(views, blocks, payload, axis, lanes)
+    return out.reshape(b, size)
+
+
+def _chunk_index(r, cls: int, shift: int, p: int):
+    """``(r + cls + shift) % P`` with the static part folded first."""
+    return (r + (cls + shift) % p) % p
+
+
+def _segments(blocks):
+    """The payload's row ranges: one per (block, run), class-major.
+
+    Each round's payload stacks every run's chunks along its leading
+    axis, ``(block, j0, j1, class, offset)`` per segment, so every run
+    is one contiguous row range of it."""
+    segs, off = [], 0
+    for i, (_, rows, _, runs) in enumerate(blocks):
+        for j0, j1, cls in runs:
+            segs.append((i, j0, j1, cls, off))
+            off += rows * (j1 - j0)
+    return segs
+
+
+@jax.named_scope("flare.ring.reduce_scatter")
+def _ring_bucketed_reduce_scatter(views, blocks, axis, op, lanes):
+    """Every bucket's reduce-scatter at once; the ``(B, *lanes)`` result
+    holds bucket b's reduced chunk ``(r + 1 + σ_b) % P``, in the
+    payload's class-major order (``_segments``)."""
+    p = lax.axis_size(axis)
+    r = lax.axis_index(axis)
+    tail = (0,) * len(lanes)
+
+    def mine(seg, shift):
+        i, j0, j1, cls, _ = seg
+        return lax.dynamic_slice(
+            views[i], (0, j0, _chunk_index(r, cls, shift, p)) + tail,
+            (blocks[i][1], j1 - j0, 1) + lanes).reshape((-1,) + lanes)
+
+    segs = _segments(blocks)
+    acc = jnp.concatenate([mine(g, 0) for g in segs])
+    for s in range(p - 1):
+        acc = lax.ppermute(acc, axis, _ring_perm(p))
+        for g in segs:
+            # combined into the received buffer in place: one pass over
+            # each class's rows, with no concatenate per round
+            m = mine(g, -s - 1)
+            got = lax.slice_in_dim(acc, g[4], g[4] + m.shape[0])
+            acc = lax.dynamic_update_slice_in_dim(acc, op(m, got), g[4], 0)
+    return acc
+
+
+@jax.named_scope("flare.ring.all_gather")
+def _ring_bucketed_all_gather(views, blocks, chunk, axis, lanes):
+    """Inverse of the reduce-scatter: every bucket's all-gather at once.
+
+    Each round writes every chunk once, so the reduce-scatter's views,
+    dead by then, serve as the output buffers."""
+    p = lax.axis_size(axis)
+    r = lax.axis_index(axis)
+    tail = (0,) * len(lanes)
+    outs = list(views)
+
+    def put(payload, shift):
+        for i, j0, j1, cls, off in _segments(blocks):
+            rows = blocks[i][1]
+            part = payload[off:off + rows * (j1 - j0)].reshape(
+                (rows, j1 - j0, 1) + lanes)
+            outs[i] = lax.dynamic_update_slice(
+                outs[i], part, (0, j0, _chunk_index(r, cls, shift, p)) + tail)
+
+    put(chunk, 1)
+    send = chunk
+    for s in range(p - 1):
+        send = lax.ppermute(send, axis, _ring_perm(p))
+        put(send, -s)
+    flat = [o.reshape((-1, p) + lanes) for o in outs]
+    return jnp.concatenate(flat) if len(flat) > 1 else flat[0]
 
 
 # ---------------------------------------------------------------------------

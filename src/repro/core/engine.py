@@ -12,7 +12,8 @@ an *unreduced* gradient pytree inside a manual ``shard_map`` region.  It:
      128 KiB ≤ rhd < 512 KiB ≤ ring/two-level), int8 quantized (F1), or
      §7 top-k sparse — the three-way dispatch lives in exactly one place,
   3. reduces **all B buckets of a group in one batched schedule**: the
-     dense path vmaps its collective rounds, the sparse path issues one
+     dense ring picks each round's chunks per stagger class (the other
+     dense algorithms vmap their rounds), the sparse path issues one
      ppermute per recursive-doubling step carrying every bucket's
      coordinate list, the int8 path moves the whole arena's payload in a
      single all_to_all/all_gather pair — the paper's multi-buffer
@@ -21,7 +22,7 @@ an *unreduced* gradient pytree inside a manual ``shard_map`` region.  It:
      residual computed by ``compression.error_feedback_step`` and ``k``
      derived from each bucket's unpadded extent (``sparse.sparse_k``),
   5. staggers concurrent blocks' ring phases (staggered sending, §5) via
-     a per-bucket phase scalar,
+     a static per-bucket phase,
   6. guarantees bitwise reproducibility when asked (F3: fixed-tree only,
      fp32 accumulation) — the arena and legacy paths are bitwise-equal
      there because the fixed tree combines elementwise.
@@ -47,6 +48,7 @@ from jax import lax
 from repro import compat
 from repro.core import arena as arena_mod
 from repro.core import bucketing, transports
+from repro.core import collectives as coll
 
 
 @dataclasses.dataclass(frozen=True)
@@ -217,15 +219,44 @@ class GradReducer:
             pad = math.lcm(pad, world * transports.QUANT_BLOCK)
         return pad
 
+    def _plan(self, leaves) -> arena_mod.FlatArena:
+        """The arena plan, buckets a multiple of ``_pad_multiple``.
+
+        A dtype group that the dense transport reduces with the
+        stagger-class ring (``DenseTransport.class_ring`` at that
+        bucket size) pads its chunks to whole ``coll.CHUNK_ALIGN``
+        tiles, so the ring's (bucket, chunk) view of the arena needs no
+        relayout on a TPU — where that grows its buckets by at most
+        1/64; at many ranks a tile per rank may outweigh the bucket.
+        """
+        c = self.config
+        world = self._world()
+        pad = self._pad_multiple(world)
+        plan = arena_mod.build_plan(leaves, c.bucket_bytes,
+                                    pad_multiple=pad)
+        if c.transport != "auto":
+            return plan
+        tile = math.lcm(pad, world * coll.CHUNK_ALIGN)
+        aligned = []
+        for g in plan.groups:
+            t = self._transport(g.dtype, batched=True)
+            s = g.bucket_elems
+            if (isinstance(t, transports.DenseTransport)
+                    and t.class_ring(s * g.dtype.itemsize)
+                    and -s % tile <= s // 64):
+                aligned.append((g.dtype.name, tile))
+        if not aligned:
+            return plan
+        return arena_mod.build_plan(leaves, c.bucket_bytes, pad_multiple=pad,
+                                    group_pads=aligned)
+
     # -- flat-arena pipelined path (the hot path) ----------------------------
     def _reduce_arena(self, grads: Any, state: Any) -> tuple[Any, Any]:
         c = self.config
         leaves, treedef = jax.tree.flatten(grads)
         ef_leaves = (jax.tree.flatten(state)[0] if state is not None
                      else None)
-        plan = arena_mod.build_plan(leaves, c.bucket_bytes,
-                                    pad_multiple=self._pad_multiple(
-                                        self._world()))
+        plan = self._plan(leaves)
 
         ef_out_groups: list[jax.Array | None] = []
         red_groups: list[jax.Array] = []

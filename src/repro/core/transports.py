@@ -12,9 +12,10 @@ error-feedback folded into the same trace.
 =============  ============================================================
 transport       batched wire schedule (per dtype group)
 =============  ============================================================
-``dense``       vmapped allreduce: every ring/rhd/tree round carries all B
+``dense``       batched allreduce: every ring/rhd/tree round carries all B
                 buckets' chunks in one collective — 2(P-1) or log P rounds
-                total (PR 1's §6.2 multi-buffer schedule, unchanged).
+                total (the §6.2 multi-buffer schedule).  The flat ring
+                picks chunks per stagger class; the others are vmapped.
 ``int8``        ``compression.quantized_allreduce_batched``: ONE
                 ``all_to_all`` + ONE ``all_gather`` pair move every
                 bucket's int8 payload — O(1) collectives per group.
@@ -70,7 +71,9 @@ class Transport:
     ``__call__(buf, ef, staggers, extents)``:
       * ``buf`` — the ``(B, S)`` arena buffer;
       * ``ef`` — error-feedback residuals of the same shape (or None);
-      * ``staggers`` — per-bucket ring-phase offsets (§5), shape ``(B,)``;
+      * ``staggers`` — per-bucket ring-phase offsets (§5), shape ``(B,)``:
+        the plan's are static (numpy), which the dense ring needs to
+        group buckets by stagger class;
       * ``extents`` — static per-bucket unpadded element counts from the
         arena plan (``DtypeArena.valid_extents``); k and other
         size-derived knobs come from these, never the padded S.
@@ -90,8 +93,8 @@ class Transport:
     #: ``compare=False`` — attaching telemetry never changes a
     #: transport's identity, so jit cache keys and session specs are
     #: untouched.  The switch transport records its static counters and
-    #: retry instants into it; the wire transports carry it for
-    #: callers but add nothing themselves.
+    #: retry instants into it; the dense ring its ``wire.ring.*``
+    #: engagement counters; the other wire transports add nothing.
     telemetry: Any = dataclasses.field(default=None, compare=False,
                                        repr=False)
 
@@ -120,12 +123,17 @@ class Transport:
 
 @dataclasses.dataclass(frozen=True)
 class DenseTransport(Transport):
-    """Lossless allreduce of the arena — PR 1's vmapped schedule."""
+    """Lossless allreduce of the arena, all B buckets in one schedule.
+
+    The ring on one axis runs ``coll.ring_allreduce_bucketed``'s
+    stagger-class schedule; rhd, the fixed tree, two-level and
+    hierarchical vmap their single-vector schedule over the buckets."""
 
     algorithm: str = "auto"
     reproducible: bool = False
 
-    def _resolve(self, buf: jax.Array) -> str:
+    def _resolve(self, nbytes: int) -> str:
+        """The algorithm for buckets of ``nbytes`` bytes each."""
         alg = self.algorithm
         if alg == "auto":
             if self._use_hierarchy():
@@ -133,17 +141,43 @@ class DenseTransport(Transport):
                 # schedule: every size class rides the tree-driven path
                 # (reproducible mode takes its fixed-tree variant).
                 return "hierarchical"
-            nbytes = buf.shape[1] * jnp.dtype(buf.dtype).itemsize
             alg = coll.select_algorithm(nbytes, reproducible=self.reproducible,
                                         multi_level=len(self.axes) > 1)
         return alg
 
+    def class_ring(self, nbytes: int) -> bool:
+        """Whether a ``(B, S)`` arena of ``nbytes`` per bucket takes the
+        batched stagger-class ring: the ring, asked for or what ``auto``
+        picks at that size, on one axis of more than one rank.
+
+        The one place that decides it: the call routes on it, and
+        ``GradReducer`` pads the chunks of such arenas to whole tiles.
+        Padding only grows S, and the ring's size range has no upper
+        end, so the padded arena still takes the ring."""
+        return (self.batched and not self.reproducible
+                and len(self.axes) == 1 and lax.axis_size(self.axes[0]) > 1
+                and self._resolve(nbytes) == "ring")
+
     def __call__(self, buf, ef, staggers, extents):
-        alg = self._resolve(buf)
+        nbytes = buf.shape[1] * jnp.dtype(buf.dtype).itemsize
+        alg = self._resolve(nbytes)
         one = lambda v, s: coll.allreduce(
             v, self.axes, algorithm=alg, reproducible=self.reproducible,
             stagger=s)
-        if self.batched:
+        sig = (coll.static_staggers(staggers) if self.class_ring(nbytes)
+               else None)
+        if sig is not None:
+            # one ppermute per round for all B buckets, chunks picked
+            # and written once per stagger class (collectives.py)
+            red = coll.ring_allreduce_bucketed(buf, self.axes[0],
+                                               staggers=sig)
+            if self.telemetry is not None:
+                p = lax.axis_size(self.axes[0])
+                reg = self.telemetry.registry
+                reg.counter("wire.ring.class_batched_buckets").inc(len(sig))
+                reg.counter("wire.ring.stagger_classes").inc(
+                    len({s % p for s in sig}))
+        elif self.batched:
             # all B buckets in one vmapped schedule: every collective
             # round carries the whole arena's worth of payload in one
             # batched ppermute/exchange (§6.2 multi-buffer parallelism).

@@ -7,7 +7,8 @@ Invoked by tests/test_collectives.py as::
 
 Groups: collectives | arena_pipeline | sparse_quant | fsdp_engine |
         trainer | repro | transports | hierarchy | switch | runtime |
-        sparse_densify | chaos | canary | obs | health | scopes
+        sparse_densify | chaos | canary | obs | health | scopes |
+        ring_classes (4 devices)
 Exits non-zero on any failure (assertion output on stderr).
 
 The ``hierarchy``, ``switch``, ``runtime``, ``sparse_densify``,
@@ -169,6 +170,246 @@ def check_arena_pipeline():
         assert np.allclose(got, expect, rtol=1e-5,
                            atol=1e-2), f"arena engine {alg}"
     print("arena/pipeline OK")
+
+
+#: Stagger-class ring cases: name → (P, B, staggers, op, chunk length).
+#: Staggers are ``("base", k)`` for the plan's ``k + b`` or ``("off",)``;
+#: a chunk of 256 takes the lane-split (…, C/128, 128) view, 6 the plain.
+RING_CLASS_CASES = {
+    "p2-b5-base3-add": (2, 5, ("base", 3), "add", 6),
+    "p2-b6-base3-add": (2, 6, ("base", 3), "add", 256),
+    "p4-b5-base3-add": (4, 5, ("base", 3), "add", 256),
+    "p4-b6-base3-add": (4, 6, ("base", 3), "add", 6),
+    "p4-b8-base1-add": (4, 8, ("base", 1), "add", 256),
+    "p2-b5-off-add": (2, 5, ("off",), "add", 256),
+    "p4-b6-off-add": (4, 6, ("off",), "add", 6),
+    "p4-b5-base3-max": (4, 5, ("base", 3), "max", 256),
+    "p2-b6-base0-max": (2, 6, ("base", 0), "max", 6),
+}
+
+#: ``GradReducer`` arena plans on four ranks: name → (mesh shape, config
+#: fields, bucket elements, buckets, whether the chunks are padded to
+#: whole tiles).  One fp32 leaf of B·S elements, bucket_bytes 4·S, so
+#: the unpadded plan has B buckets of S; S picks the §6.4 size class,
+#: and all but the capped case lie within 1/64 below a whole tile.
+RING_PLAN_CASES = {
+    # < 128 KiB: the fixed tree
+    "plan-fixed-tree-unpadded": ((4,), {}, 28600, 3, False),
+    # 128 KiB ≤ S < 512 KiB: rhd, auto and asked for
+    "plan-rhd-unpadded": ((4,), {}, 102000, 3, False),
+    "plan-rhd-asked-unpadded": ((4,), {"algorithm": "rhd"}, 262000, 3,
+                                False),
+    # two reduction axes: two-level or hierarchical, and a flat ring on
+    # two axes, none of them the stagger-class ring
+    "plan-two-axis-unpadded": ((2, 2), {"axes": ("pod", "data")}, 262000,
+                               3, False),
+    "plan-two-axis-ring-unpadded": ((2, 2), {"axes": ("pod", "data"),
+                                             "algorithm": "ring",
+                                             "hierarchical": False},
+                                    262000, 3, False),
+    # the ring on one axis: chunks of whole tiles ...
+    "plan-ring-aligned": ((4,), {}, 262000, 3, True),
+    # ... unless that grows the bucket by more than 1/64
+    "plan-ring-growth-capped": ((4,), {"algorithm": "ring"}, 5000, 3,
+                                False),
+}
+RING_CLASS_CHECKS = (tuple(RING_CLASS_CASES) + tuple(RING_PLAN_CASES)
+                     + ("aperiodic-staggers-refused",
+                        "dense-ring-batched-eq-scan", "ring-structure",
+                        "reducer-ring-multibucket"))
+
+
+def _flat_mesh(p: int):
+    return jax.make_mesh((p,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:p])
+
+
+def _run_flat(fn, xs, p):
+    """``fn`` on rank r's row of ``xs``, every rank's output stacked."""
+    g = jax.jit(jax.shard_map(lambda x: fn(x[0])[None], mesh=_flat_mesh(p),
+                              in_specs=P("data"), out_specs=P("data"),
+                              check_vma=False))
+    return np.asarray(g(xs))
+
+
+def _traced_plan(mesh_shape, cfg, leaf):
+    """``GradReducer(cfg)._plan([leaf])`` as traced on a mesh of that
+    shape (axes ``data`` or ``pod, data``)."""
+    names = ("data",) if len(mesh_shape) == 1 else ("pod", "data")
+    mesh = jax.make_mesh(mesh_shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=jax.devices()[:math.prod(mesh_shape)])
+    got = []
+
+    def body(x):
+        got.append(GradReducer(cfg)._plan([x]))
+        return x
+    jax.make_jaxpr(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False))(leaf)
+    return got[0]
+
+
+def check_ring_classes():
+    """The stagger-class batched ring (``ring_allreduce_bucketed`` with
+    static staggers) is bitwise-equal to the per-bucket
+    ``allreduce_ring`` loop with the same staggers, for P ∈ {2, 4}, B a
+    multiple of P and not, a nonzero stagger base, staggers off, and a
+    max operator, and refuses staggers of no period;
+    ``GradReducer`` pads chunks to whole tiles only where that ring
+    runs; ``DenseTransport(batched=True)`` ≡ ``batched=False`` for the
+    ring; the batched ring's jaxpr holds no gather or scatter, exactly
+    2(P-1) ppermutes, while the telemetry counters read the buckets and
+    classes it took; and a six-bucket ``GradReducer`` on one axis is
+    bitwise-equal to the per-bucket ring.  Four devices."""
+    from repro.core import arena
+    from repro.obs import Telemetry
+
+    for name, (p, b, stag, op_name, c) in RING_CLASS_CASES.items():
+        sig = (tuple(stag[1] + i for i in range(b)) if stag[0] == "base"
+               else (0,) * b)
+        op = {"add": jnp.add, "max": jnp.maximum}[op_name]
+        rng = np.random.default_rng(b * 1000 + c)
+        xs = jnp.asarray((rng.normal(size=(p, b, p * c)) * 1e3)
+                         .astype(np.float32))
+        got = _run_flat(lambda a: coll.ring_allreduce_bucketed(
+            a, "data", op=op, staggers=sig), xs, p)
+        want = _run_flat(lambda a: jnp.stack(
+            [coll.allreduce_ring(a[i], "data", op=op, stagger=sig[i])
+             for i in range(b)]), xs, p)
+        assert got.tobytes() == want.tobytes(), name
+        print(f"ring_classes {name} OK")
+
+    # static staggers of no period have no class blocks: refused
+    try:
+        jax.make_jaxpr(jax.shard_map(
+            lambda a: coll.ring_allreduce_bucketed(
+                a, "data", staggers=(0, 3, 3, 1, 6, 0, 2)),
+            mesh=_flat_mesh(4), in_specs=P(), out_specs=P(),
+            check_vma=False))(jax.ShapeDtypeStruct((7, 1024), jnp.float32))
+    except ValueError as e:
+        assert "period 4" in str(e), e
+    else:
+        raise AssertionError("aperiodic staggers were not refused")
+    print("ring_classes aperiodic-staggers-refused OK")
+
+    # the plan pads chunks to whole tiles only where the stagger-class
+    # ring runs; every other plan keeps the unpadded S and B
+    for name, (shape, kw, s, b, aligned) in RING_PLAN_CASES.items():
+        leaf = jax.ShapeDtypeStruct((b * s,), jnp.float32)
+        cfg = FlareConfig(bucket_bytes=4 * s, **kw)
+        g = _traced_plan(shape, cfg, leaf).groups[0]
+        base = arena.build_plan([leaf], 4 * s, pad_multiple=8).groups[0]
+        assert (base.num_buckets, base.bucket_elems) == (b, s), name
+        if aligned:
+            assert g.bucket_elems % (4 * coll.CHUNK_ALIGN) == 0, name
+            assert 0 < g.bucket_elems - s <= s // 64, (name, g.bucket_elems)
+            assert g.num_buckets == b, (name, g.num_buckets)
+        else:
+            assert (g.num_buckets, g.bucket_elems) == (b, s), (
+                name, g.num_buckets, g.bucket_elems)
+        print(f"ring_classes {name} OK")
+
+    # DenseTransport: batched (stagger classes) ≡ the per-bucket scan,
+    # with the plan's own static staggers; the counters show which ran
+    p = 4
+    leaves = [jax.ShapeDtypeStruct((7, 300), jnp.float32),
+              jax.ShapeDtypeStruct((900,), jnp.float32)]
+    g = arena.build_plan(leaves, bucket_bytes=2048,
+                         pad_multiple=2 * p).groups[0]
+    assert g.num_buckets % p, g.num_buckets      # a tail block too
+    rng = np.random.default_rng(5)
+    xs = jnp.asarray(rng.normal(size=(p, g.num_buckets, g.bucket_elems))
+                     .astype(np.float32))
+    tel = Telemetry.create()
+
+    def dense(batched, telemetry=None):
+        t = transports.DenseTransport(("data",), batched=batched,
+                                      algorithm="ring", telemetry=telemetry)
+        return lambda a: t(a, None, g.staggers(True), g.valid_extents)[0]
+
+    got = _run_flat(dense(True, tel), xs, p)
+    want = _run_flat(dense(False), xs, p)
+    assert got.tobytes() == want.tobytes(), "dense ring batched != scan"
+    assert np.allclose(got[0], np.asarray(xs).sum(0), rtol=1e-5, atol=1e-4)
+    reg = tel.registry
+    assert reg.value("wire.ring.class_batched_buckets") == g.num_buckets
+    assert reg.value("wire.ring.stagger_classes") == p
+    print("ring_classes dense-ring-batched-eq-scan OK")
+
+    # structure: one ppermute per round, no per-bucket gather/scatter
+    tel = Telemetry.create()
+    b, s = 6, p * 256
+    t = transports.DenseTransport(("data",), algorithm="ring", telemetry=tel)
+    fn = jax.shard_map(
+        lambda a: t(a[0], None, 2 + np.arange(b, dtype=np.int32),
+                    (s,) * b)[0][None],
+        mesh=_flat_mesh(p), in_specs=P("data"), out_specs=P("data"),
+        check_vma=False)
+    jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((p, b, s), jnp.float32))
+    prims = []
+
+    def walk(jx):
+        for e in jx.eqns:
+            prims.append(e.primitive.name)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    assert not {"gather", "scatter", "scatter-add", "while"} & set(prims), \
+        sorted(set(prims))
+    assert prims.count("ppermute") == 2 * (p - 1), prims.count("ppermute")
+    reg = tel.registry
+    assert {n: reg.value(n) for n in reg.names()} == {
+        "wire.ring.class_batched_buckets": b,
+        "wire.ring.stagger_classes": p}
+    print("ring_classes ring-structure OK")
+
+    # GradReducer on one axis: six buckets of whole tiles (a block of
+    # four and a tail of two) through the class ring, bitwise-equal to
+    # the per-bucket ring on the same plan
+    p, tel = 4, Telemetry.create()
+    sizes = ((100, 300), (68000,), (100,))
+    n = sum(math.prod(z) for z in sizes)
+    cfg = FlareConfig(algorithm="ring", bucket_bytes=65536, telemetry=tel)
+
+    def leaves_of(x):
+        out, off = [], 0
+        for z in sizes:
+            out.append(x[off:off + math.prod(z)].reshape(z))
+            off += math.prod(z)
+        return out
+
+    def flat(ls):
+        return jnp.concatenate([v.reshape(-1) for v in ls])
+
+    def reducer(x):
+        red, _ = GradReducer(cfg)(leaves_of(x))
+        return flat(red)
+
+    def per_bucket(x):
+        ls = leaves_of(x)
+        g = GradReducer(cfg)._plan(ls).groups[0]
+        assert g.num_buckets == 6, g.num_buckets
+        assert g.bucket_elems % (p * coll.CHUNK_ALIGN) == 0, g.bucket_elems
+        buf, st = g.pack(ls), g.staggers(True)
+        red = jnp.stack([coll.allreduce_ring(buf[i], "data",
+                                             stagger=int(st[i]))
+                         for i in range(g.num_buckets)])
+        out = [None] * len(ls)
+        g.unpack(red, out)
+        return flat(out)
+
+    rng = np.random.default_rng(9)
+    xs = jnp.asarray(rng.normal(size=(p, n)).astype(np.float32))
+    got = _run_flat(reducer, xs, p)
+    want = _run_flat(per_bucket, xs, p)
+    assert got.tobytes() == want.tobytes(), "reducer ring != per-bucket"
+    assert np.allclose(got[0], np.asarray(xs).sum(0), rtol=1e-5, atol=1e-4)
+    reg = tel.registry
+    assert reg.value("wire.ring.class_batched_buckets") == 6
+    assert reg.value("wire.ring.stagger_classes") == p
+    print("ring_classes reducer-ring-multibucket OK")
+    print("ring_classes OK")
 
 
 def check_sparse_quant():
@@ -1750,6 +1991,7 @@ GROUPS = {
     "obs": check_obs,
     "health": check_health,
     "scopes": check_scopes,
+    "ring_classes": check_ring_classes,
 }
 
 if __name__ == "__main__":

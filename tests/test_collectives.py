@@ -9,12 +9,14 @@ import sys
 
 import pytest
 
+from multidevice_checks import RING_CLASS_CHECKS
+
 _SCRIPT = os.path.join(os.path.dirname(__file__), "multidevice_checks.py")
 
 
-def _run_group(group: str, mesh_shape: str | None = None):
+def _run_group(group: str, mesh_shape: str | None = None, devices: int = 8):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
@@ -33,6 +35,20 @@ def _run_group(group: str, mesh_shape: str | None = None):
 def test_multidevice(group):
     out = _run_group(group)
     assert "OK" in out
+
+
+@pytest.fixture(scope="module")
+def ring_classes_out():
+    """One four-device run of every stagger-class ring check."""
+    return _run_group("ring_classes", devices=4)
+
+
+@pytest.mark.parametrize("case", RING_CLASS_CHECKS)
+def test_ring_stagger_classes(ring_classes_out, case):
+    """The batched ring that picks chunks by stagger class: bitwise-equal
+    to the per-bucket ring (and the dense transport's scan), with one
+    ppermute per round and no gather or scatter."""
+    assert f"ring_classes {case} OK" in ring_classes_out
 
 
 def test_multidevice_hierarchy(mesh_shape):
