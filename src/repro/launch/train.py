@@ -312,6 +312,7 @@ def run(args, cfg) -> dict | None:
     from repro.data import pipeline
     from repro.ft import CheckpointManager
     from repro.models import get_model
+    from repro.obs.metrics import observe_moe
     from repro.sharding import rules
     from repro.train import trainer
 
@@ -405,6 +406,8 @@ def run(args, cfg) -> dict | None:
             with _step_span(telemetry, step):
                 params, opt, metrics = step_fn(params, opt, batch)
                 loss = float(metrics["loss"])
+            if telemetry is not None and "moe_rows" in metrics:
+                observe_moe(telemetry.registry, jax.device_get(metrics))
             dt = time.perf_counter() - t0
             result["losses"].append(loss)
             result["step_s"].append(dt)

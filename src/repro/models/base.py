@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -36,7 +37,10 @@ class ModelConfig:
     moe_d_ff: int = 0
     n_shared_experts: int = 0
     first_dense_layers: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # 0 → dropless over the experts held
+    experts_held: int = 0          # experts [0, held) live here; 0 → all
+    norm_topk_prob: bool = True    # renormalise the top-k gates (dropless)
+    aux_loss_alpha: float = 0.0    # sequence-wise balance loss weight
 
     # --- MLA (deepseek) ------------------------------------------------------
     mla_kv_lora: int = 0
@@ -54,6 +58,13 @@ class ModelConfig:
     # --- attention extras ------------------------------------------------------
     qk_norm: bool = False          # qwen3 per-head q/k RMSNorm
     rope_theta: float = 1e4
+    # YaRN (arXiv:2309.00071); factor 0 → plain RoPE.  ``yarn_mscale`` is
+    # the source's ``mscale_all_dim``: the softmax scale takes
+    # mscale(factor, yarn_mscale)²; cos and sin stay unscaled, as where
+    # the source's ``mscale`` equals it (DeepSeek-V2)
+    yarn_factor: float = 0.0
+    yarn_original: int = 4096
+    yarn_mscale: float = 0.0
 
     # --- SSM (mamba2) ----------------------------------------------------------
     ssm_state: int = 0
@@ -84,6 +95,7 @@ class ModelConfig:
     # --- performance knobs (hillclimb levers; defaults = paper-faithful
     # baseline, see EXPERIMENTS.md §Perf) -----------------------------------
     attn_chunk: int = 0            # >0 → chunked online-softmax attention
+    moe_chunk: int = 0             # >0 → dropless MoE tokens per pass
     moe_combine: str = "gather"    # gather | scatter_ar (EP combine path)
     remat_policy: str = "full"     # full | dots | names
     mla_absorbed: bool = False     # decode attends in the latent space
@@ -105,6 +117,15 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def dropless(self) -> bool:
+        return self.is_moe and self.capacity_factor == 0
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return self.experts_held or self.n_experts
 
     def scaled(self, **overrides) -> "ModelConfig":
         """Reduced config for CPU smoke tests (same family/topology)."""
@@ -136,10 +157,58 @@ def rope_freqs(hd: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
 
 
-def apply_rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); pos: (..., S) absolute positions."""
+#: YaRN's bounds of the frequency ramp, in turns over the original context
+#: (the paper's and DeepSeek-V2's beta_fast and beta_slow)
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1·m·ln s + 1`` (1 for
+    s ≤ 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(hd: int, theta: float, factor: float,
+               original: int) -> jax.Array:
+    """YaRN's "NTK-by-parts" frequencies (arXiv:2309.00071 §3.2, as the
+    DeepSeek-V2 modeling code computes them): dimensions that turn more
+    than ``YARN_BETA_FAST`` times over the original context keep their
+    frequency, those that turn fewer than ``YARN_BETA_SLOW`` times are
+    divided by ``factor``, and a linear ramp blends the ones between."""
+    def dim_of(turns):
+        return hd * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    lo = max(math.floor(dim_of(YARN_BETA_FAST)), 0)
+    hi = min(math.ceil(dim_of(YARN_BETA_SLOW)), hd - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    base = rope_freqs(hd, theta)
+    return base / factor * ramp + base * (1.0 - ramp)
+
+
+def rope_of(cfg: "ModelConfig", hd: int) -> jax.Array:
+    """The frequencies of ``cfg``'s rotary embedding: YaRN's where it
+    sets a factor."""
+    if cfg.yarn_factor <= 0:
+        return rope_freqs(hd, cfg.rope_theta)
+    return yarn_freqs(hd, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original)
+
+
+def softmax_mscale(cfg: "ModelConfig") -> float:
+    """YaRN's factor on the softmax scale: ``mscale(s, yarn_mscale)²``."""
+    if cfg.yarn_factor <= 0 or not cfg.yarn_mscale:
+        return 1.0
+    return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) ** 2
+
+
+def apply_rope(x: jax.Array, pos: jax.Array, theta: float, *,
+               freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., S, H, hd); pos: (..., S) absolute positions.  ``freqs``
+    (hd/2,) replaces the plain ``theta`` frequencies (YaRN)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     ang = pos[..., None].astype(jnp.float32) * freqs    # (..., S, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     cos = cos[..., None, :]                             # broadcast over heads
@@ -237,7 +306,9 @@ def _attend_chunked(q, k, v, *, causal, window, attn_cap, scale, chunk):
     softmax intermediates) to O(S²·vd/chunk) carry writes + O(S²/chunk)
     KV reloads; with chunk ≫ vd that is a ≥8× cut on the memory term
     (EXPERIMENTS.md §Perf iteration 2 — iteration 1's KV-only tiling was
-    refuted: its carry was full-output-sized).
+    refuted: its carry was full-output-sized).  Both scan bodies are
+    rematerialised, so the backward pass keeps one query chunk's (m, l,
+    o) carries and never a chunk of scores per step: no O(S²) residual.
     """
     b, sq, h, hd = q.shape
     sk, kv = k.shape[1], k.shape[2]
@@ -255,10 +326,12 @@ def _attend_chunked(q, k, v, *, causal, window, attn_cap, scale, chunk):
     vc = jnp.moveaxis(v.astype(jnp.float32)
                       .reshape(b, nk, chunk, kv, vd), 1, 0)
 
+    @jax.checkpoint
     def q_body(_, xs):
         qi, qb = xs                                   # (B,qc,KV,G,hd)
         qpos = qi * qc_len + jnp.arange(qc_len)
 
+        @jax.checkpoint
         def kv_body(carry, xs2):
             m, l, o = carry
             ki, kb, vb = xs2
@@ -439,6 +512,148 @@ def moe_block(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     if cfg.n_shared_experts > 0:
         out = out + swiglu(p["shared"], xt)
     return out.reshape(b, s, d)
+
+
+def moe_dropless(cfg: ModelConfig, p: dict, x: jax.Array):
+    """Top-k MoE without dropped tokens, over the experts held here.
+
+    The router scores all ``n_experts`` (softmax, greedy top-k, gates
+    renormalised only if ``norm_topk_prob``); the layer computes the
+    part of the result that its held experts ``[0, n_held)`` give, for
+    exactly the (token, choice) rows routed to them.  Those rows are sorted by expert and run as one grouped
+    product per weight (``grouped_matmul``), whose work follows the
+    group sizes; the rows routed to experts held elsewhere sort last and
+    come back as zeros.  With ``moe_chunk`` the tokens go through that
+    in chunks of ``moe_chunk``, each sorted on its own and recomputed in
+    backward, so one chunk's (token, choice) rows are live at a time.
+    Shared experts add their output for every token.
+
+    Weights: ``router`` (D, E) float32; ``w_gate``/``w_up`` (held, D, F),
+    ``w_down`` (held, F, D); ``shared`` a SwiGLU.  Returns ``(out,
+    stats)``: ``stats["aux"]`` is the sequence-wise balance loss
+    (DeepSeek-V2 §2.2.3, over all experts, averaged over the batch),
+    ``stats["rows"]`` the rows routed to the held experts.
+    """
+    b, s, d = x.shape
+    t = b * s
+    e, k, held = cfg.n_experts, cfg.experts_per_token, cfg.n_held
+    xt = x.reshape(t, d)
+
+    with jax.named_scope("lm.moe.route"):
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)               # (T, E)
+        gate, idx = jax.lax.top_k(probs, k)                   # (T, k)
+        if cfg.norm_topk_prob:
+            gate = gate / jnp.sum(gate, -1, keepdims=True)
+        gate = gate.astype(cfg.dtype)
+        aux = _seq_balance_loss(cfg, probs, idx, b, s)
+
+    xt = xt.astype(cfg.dtype)
+    chunk = cfg.moe_chunk if 0 < cfg.moe_chunk < t and t % cfg.moe_chunk \
+        == 0 else t
+    if chunk == t:
+        out, rows = _held_experts(cfg, p, xt, gate, idx)
+    else:
+        def body(_, xs):
+            return None, _held_experts(cfg, p, *xs)
+        split = lambda a: a.reshape((t // chunk, chunk) + a.shape[1:])
+        _, (out, rows) = jax.lax.scan(jax.checkpoint(body), None,
+                                      (split(xt), split(gate), split(idx)))
+        out, rows = out.reshape(t, d), jnp.sum(rows)
+    stats = {"aux": aux, "rows": rows}
+
+    if cfg.n_shared_experts > 0:
+        with jax.named_scope("lm.moe.shared"):
+            out = out + swiglu(p["shared"], xt)
+    return out.reshape(b, s, d), stats
+
+
+def _held_experts(cfg: ModelConfig, p: dict, xt, gate, idx):
+    """The held experts' part of the output for tokens ``xt`` (T, D)
+    with their top-k ``gate`` and ``idx`` (T, k), and the rows routed to
+    them."""
+    t, d = xt.shape
+    k, held = idx.shape[1], cfg.n_held
+    with jax.named_scope("lm.moe.route"):
+        # rows sorted by expert; choices of experts held elsewhere last
+        expert = jnp.minimum(idx.reshape(-1), held)           # (T·k,)
+        order = jnp.argsort(expert, stable=True)
+        inv = jnp.argsort(order)
+        sizes = jnp.bincount(expert, length=held + 1).astype(jnp.int32)
+
+    with jax.named_scope("lm.moe.dispatch"):
+        xs = _take_rows(xt, order // k, inv, k)
+
+    with jax.named_scope("lm.moe.experts"):
+        h = jax.nn.silu(grouped_matmul(xs, p["w_gate"], sizes, cfg.dtype))
+        h = h * grouped_matmul(xs, p["w_up"], sizes, cfg.dtype)
+        ys = grouped_matmul(h, p["w_down"], sizes, cfg.dtype)
+
+    with jax.named_scope("lm.moe.combine"):
+        yc = _take_rows(ys, inv, order, 1).reshape(t, k, d)
+        out = jnp.einsum("tkd,tk->td", yc, gate)
+    return out, jnp.sum(sizes[:held])
+
+
+def _seq_balance_loss(cfg: ModelConfig, probs, idx, b: int, s: int):
+    """``α · Σᵢ fᵢ·Pᵢ`` per sequence, averaged: ``fᵢ`` the share of the
+    sequence's k·S choices that went to expert i, times E/k; ``Pᵢ`` its
+    mean router probability over the sequence."""
+    if cfg.aux_loss_alpha <= 0:
+        return jnp.float32(0)
+    e, k = cfg.n_experts, cfg.experts_per_token
+    counts = jnp.sum(jax.nn.one_hot(idx.reshape(b, s * k), e,
+                                    dtype=jnp.float32), 1)   # (B, E)
+    f = counts * (e / (s * k))
+    pm = jnp.mean(probs.reshape(b, s, e), 1)
+    return cfg.aux_loss_alpha * jnp.mean(jnp.sum(f * pm, -1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, idx, back, fold):
+    """``x[idx]`` where ``idx`` visits every row of ``x`` ``fold`` times
+    (a permutation, up to copies).  Its transpose is the gather
+    ``Σ_fold ḡ[back]``, with ``back`` the inverse permutation: no
+    scatter in either pass."""
+    return x[idx]
+
+
+def _take_rows_fwd(x, idx, back, fold):
+    return x[idx], back
+
+
+def _take_rows_bwd(fold, back, g):
+    gx = g[back]
+    if fold > 1:
+        gx = jnp.sum(gx.reshape(-1, fold, g.shape[-1]), 1)
+    return gx, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array,
+                   dtype) -> jax.Array:
+    """Rows of ``x`` (M, K), sorted into groups, times their group's
+    weight ``w`` (G, K, N).  ``sizes`` (G + 1,) counts the rows of each
+    group, and last the rows past them, which come out as zeros and are
+    not computed.
+
+    On a TPU this is megablox's grouped product (a Pallas kernel whose
+    grid follows the group sizes, with its own backward); elsewhere
+    ``jax.lax.ragged_dot``."""
+    from repro.kernels import ops
+    w = w.astype(dtype)
+    if not ops._on_tpu():
+        return jax.lax.ragged_dot(x, w, sizes[:-1],
+                                  preferred_element_type=dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+    m, kk = x.shape
+    n = w.shape[-1]
+    tm = next(t for t in (256, 128, 64, 32, 16, 8) if m % t == 0)
+    return mb.gmm(x, w, sizes, dtype, (tm, min(512, kk), min(512, n)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

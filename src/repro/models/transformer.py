@@ -58,6 +58,7 @@ def _mla_params(cfg: ModelConfig, key, scale):
             ks[3], (cfg.mla_kv_lora, h * (cfg.mla_qk_nope + cfg.mla_v_dim)),
             cfg.mla_kv_lora ** -0.5),
         "wo": base.dense_init(ks[4], (h * cfg.mla_v_dim, d), scale),
+        "kv_norm": jnp.zeros((cfg.mla_kv_lora,)),
     }
 
 
@@ -72,13 +73,13 @@ def _mlp_params(cfg: ModelConfig, key, scale, d_ff=None):
 
 
 def _moe_params(cfg: ModelConfig, key, scale):
-    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    d, f, e, held = cfg.d_model, cfg.moe_d_ff, cfg.n_experts, cfg.n_held
     ks = jax.random.split(key, 5)
     p = {
         "router": base.dense_init(ks[0], (d, e), scale),
-        "w_gate": base.dense_init(ks[1], (e, d, f), scale),
-        "w_up": base.dense_init(ks[2], (e, d, f), scale),
-        "w_down": base.dense_init(ks[3], (e, f, d), f ** -0.5),
+        "w_gate": base.dense_init(ks[1], (held, d, f), scale),
+        "w_up": base.dense_init(ks[2], (held, d, f), scale),
+        "w_down": base.dense_init(ks[3], (held, f, d), f ** -0.5),
     }
     if cfg.n_shared_experts:
         p["shared"] = _mlp_params(cfg, ks[4], scale,
@@ -144,15 +145,15 @@ def init_params(cfg: ModelConfig, key) -> dict:
         params["global_layers"] = _stack(
             gk, lambda k: _layer_params(cfg, k, moe=moe))
     elif cfg.first_dense_layers > 0:
+        # deepseek's leading dense layers (wider ffn), then the MoE ones:
+        # two stacks, both under the root ``layers``
         dk = jax.random.split(keys[2], cfg.first_dense_layers)
         mk = jax.random.split(keys[3], n - cfg.first_dense_layers)
-        # deepseek's dense first layer uses a wider dense ffn
-        def dense_layer(k):
-            p = _layer_params(cfg, k, moe=False, mla=mla)
-            return p
-        params["dense_layers"] = _stack(dk, dense_layer)
-        params["layers"] = _stack(
-            mk, lambda k: _layer_params(cfg, k, moe=moe, mla=mla))
+        params["layers"] = {
+            "dense": _stack(dk, lambda k: _layer_params(cfg, k, moe=False,
+                                                        mla=mla)),
+            "moe": _stack(mk, lambda k: _layer_params(cfg, k, moe=moe,
+                                                      mla=mla))}
     else:
         lk = jax.random.split(keys[2], n)
         params["layers"] = _stack(
@@ -174,32 +175,59 @@ def _self_layer(cfg: ModelConfig, lp: dict, x, *, window=0, cache=None,
         attn_out = base.rmsnorm(attn_out, lp["ln1b"], cfg.norm_eps)
     x = x + attn_out
     h = base.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-    ffn_out = base.moe_block(cfg, lp["ffn"], h) if moe \
-        else base.swiglu(lp["ffn"], h)
+    ffn_out, stats = _ffn(cfg, lp["ffn"], h, moe)
     ffn_out = base.tag_block_out(cfg, ffn_out)
     if cfg.post_norms:
         ffn_out = base.rmsnorm(ffn_out, lp["ln2b"], cfg.norm_eps)
-    return x + ffn_out, newkv
+    return x + ffn_out, newkv, stats
+
+
+def _ffn(cfg: ModelConfig, p: dict, h, moe: bool):
+    """The feed-forward block and its MoE stats (empty unless dropless)."""
+    if moe and cfg.dropless:
+        return base.moe_dropless(cfg, p, h)
+    if moe:
+        return base.moe_block(cfg, p, h), {}
+    with jax.named_scope("lm.mlp"):
+        return base.swiglu(p, h), {}
 
 
 def _mla_layer(cfg: ModelConfig, lp: dict, x, *, cache=None,
                pos_offset=None, moe: bool):
     """Deepseek MLA block: low-rank compressed KV + decoupled rope key."""
-    b, s, _ = x.shape
     h = base.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    ap = lp["attn"]
+    with jax.named_scope("lm.mla"):
+        out, newkv = _mla_attention(cfg, lp["attn"], h, cache=cache,
+                                    pos_offset=pos_offset)
+    x = x + base.tag_block_out(cfg, out)
+
+    h = base.rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    ffn_out, stats = _ffn(cfg, lp["ffn"], h, moe)
+    return x + base.tag_block_out(cfg, ffn_out), newkv, stats
+
+
+def _mla_attention(cfg: ModelConfig, ap: dict, h, *, cache=None,
+                   pos_offset=None):
+    """MLA: queries, the compressed KV latent with its RMSNorm and the
+    shared rope key; RoPE or YaRN on the rope parts;
+    attention (absorbed in the latent space for a cached decode when
+    ``mla_absorbed``).  Returns (out (B,S,D), (c_kv, k_rope))."""
+    b, s, _ = h.shape
     nope, rope, vd = cfg.mla_qk_nope, cfg.mla_qk_rope, cfg.mla_v_dim
     nh = cfg.n_heads
 
     q = (h @ ap["wq"]).reshape(b, s, nh, nope + rope)
     c_kv = h @ ap["w_dkv"]                         # (B,S,kv_lora)
+    c_kv = base.rmsnorm(c_kv, ap["kv_norm"], cfg.norm_eps)
     k_r = (h @ ap["w_kr"]).reshape(b, s, 1, rope)  # shared rope key
 
     pos0 = pos_offset if pos_offset is not None else jnp.int32(0)
     pos = pos0 + jnp.arange(s)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = base.apply_rope(q_rope, pos, cfg.rope_theta)
-    k_r = base.apply_rope(k_r, pos, cfg.rope_theta)
+    freqs = base.rope_of(cfg, rope)
+    q_rope = base.apply_rope(q_rope, pos, cfg.rope_theta, freqs=freqs)
+    k_r = base.apply_rope(k_r, pos, cfg.rope_theta, freqs=freqs)
+    scale = (nope + rope) ** -0.5 * base.softmax_mscale(cfg)
 
     if cache is not None:
         c_kv = jax.lax.dynamic_update_slice_in_dim(
@@ -228,7 +256,7 @@ def _mla_layer(cfg: ModelConfig, lp: dict, x, *, cache=None,
         scores = scores + jnp.einsum(
             "bshr,btqr->bhst", q_rope.astype(jnp.float32),
             k_r.astype(jnp.float32))
-        scores = scores * (nope + rope) ** -0.5
+        scores = scores * scale
         kpos = jnp.arange(sk)
         mask = (kpos[None, :] <= q_pos[:, None]) & (kpos[None, :] < kv_len)
         scores = jnp.where(mask[None, None], scores, -1e30)
@@ -244,16 +272,9 @@ def _mla_layer(cfg: ModelConfig, lp: dict, x, *, cache=None,
                             axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
         out = base.attend(qq, k, v, causal=True, q_pos=q_pos, kv_len=kv_len,
-                          scale=(nope + rope) ** -0.5,
+                          scale=scale,
                           chunk=cfg.attn_chunk if cache is None else 0)
-    x = x + base.tag_block_out(cfg, out.reshape(b, s, nh * vd) @ ap["wo"])
-
-    h = base.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-    ffn_out = base.tag_block_out(
-        cfg, base.moe_block(cfg, lp["ffn"], h) if moe
-        else base.swiglu(lp["ffn"], h))
-    newkv = (c_kv, k_r) if cache is not None else (c_kv, k_r)
-    return x + ffn_out, newkv
+    return out.reshape(b, s, nh * vd) @ ap["wo"], (c_kv, k_r)
 
 
 def _cross_layer(cfg: ModelConfig, lp: dict, x, vision_kv):
@@ -297,9 +318,11 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
               vision_embeds=None, gather: Gather = None):
     """Run all layers. mode ∈ {train, prefill, decode}.
 
-    Returns (x, new_cache_pytree_or_None).  Cache layout per stack:
-    ``{"k": (L,B,S,KV,hd), "v": ...}`` (or MLA/cross variants), plus
-    ``pos`` managed by the caller.
+    Returns (x, new_cache_pytree_or_None, stats).  Cache layout per
+    stack: ``{"k": (L,B,S,KV,hd), "v": ...}`` (or MLA/cross variants),
+    plus ``pos`` managed by the caller.  ``stats`` sums the dropless MoE
+    layers' stats (``base.moe_dropless``) over the layers; empty for
+    every other model.
     """
     moe, mla = cfg.is_moe, cfg.mla_kv_lora > 0
     want_cache = mode in ("prefill", "decode")
@@ -313,11 +336,11 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
             if mode == "decode":
                 c = dict(layer_cache)
                 c["pos"] = pos
-            out, newkv = layer_fn(x, lp, c)
+            out, newkv, stats = layer_fn(x, lp, c)
             ys = None
             if want_cache:
                 ys = _cache_entry(newkv, mla)
-            return out, ys
+            return out, (ys, stats)
         return body
 
     def _cache_entry(newkv, is_mla):
@@ -331,8 +354,8 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
             body = base.remat(cfg, body)
         xs = (stack, cache_stack if cache_stack is not None
               else _null_cache(stack))
-        x, ys = jax.lax.scan(body, x, xs)
-        return x, ys
+        x, (ys, stats) = jax.lax.scan(body, x, xs)
+        return x, ys, jax.tree.map(lambda a: jnp.sum(a, 0), stats)
 
     def _null_cache(stack):
         # scan requires a pytree with matching leading dim; use per-layer None
@@ -366,8 +389,8 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
                     lp, lcache = xs2
                     lp = _g(gather, lp)
                     c = dict(lcache); c["pos"] = pos
-                    out, newkv = _self_layer(cfg, lp, xc, moe=False, cache=c,
-                                             pos_offset=pos)
+                    out, newkv, _ = _self_layer(cfg, lp, xc, moe=False,
+                                                cache=c, pos_offset=pos)
                     return out, _cache_entry(newkv, False)
                 x, ys = jax.lax.scan(inner, x, (gstack, gcache))
                 cp = _g(gather, cstack)
@@ -378,7 +401,7 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
                                  (self_stack, sc, cross_cache, cross_stack))
             new_self = jax.tree.map(
                 lambda a: a.reshape((-1,) + a.shape[2:]), ys)
-            return x, {"self": new_self, "cross": cross_cache}
+            return x, {"self": new_self, "cross": cross_cache}, {}
 
         x, ys = jax.lax.scan(
             _vlm_group_body(cfg, gather, mode, want_cache, vision_embeds,
@@ -388,8 +411,8 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
             self_c, cross_c = ys
             self_c = jax.tree.map(
                 lambda a: a.reshape((-1,) + a.shape[2:]), self_c)
-            return x, {"self": self_c, "cross": cross_c}
-        return x, None
+            return x, {"self": self_c, "cross": cross_c}, {}
+        return x, None, {}
 
     if cfg.local_global:
         def pair_body(carry, xs):
@@ -400,10 +423,11 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
                 cl = dict(cache_l); cl["pos"] = pos
                 cg = dict(cache_g); cg["pos"] = pos
             po = pos if mode != "train" else None
-            x, kv_l = _self_layer(cfg, _g(gather, lp_l), x, moe=moe,
-                                  window=cfg.window, cache=cl, pos_offset=po)
-            x, kv_g = _self_layer(cfg, _g(gather, lp_g), x, moe=moe,
-                                  cache=cg, pos_offset=po)
+            x, kv_l, _ = _self_layer(cfg, _g(gather, lp_l), x, moe=moe,
+                                     window=cfg.window, cache=cl,
+                                     pos_offset=po)
+            x, kv_g, _ = _self_layer(cfg, _g(gather, lp_g), x, moe=moe,
+                                     cache=cg, pos_offset=po)
             ys = None
             if want_cache:
                 ys = (_cache_entry(kv_l, False), _cache_entry(kv_g, False))
@@ -416,8 +440,8 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
         x, ys = jax.lax.scan(body, x, (params["local_layers"],
                                        params["global_layers"], nc_l, nc_g))
         if want_cache:
-            return x, {"local": ys[0], "global": ys[1]}
-        return x, None
+            return x, {"local": ys[0], "global": ys[1]}, {}
+        return x, None, {}
 
     layer_fn_moe = moe
     def plain_fn(x, lp, c):
@@ -435,21 +459,20 @@ def run_stack(cfg: ModelConfig, params: dict, x, *, mode: str,
                 return _mla_layer(cfg, lp, x, cache=c, pos_offset=po,
                                   moe=False)
             return _self_layer(cfg, lp, x, cache=c, pos_offset=po, moe=False)
-        dc = cache["dense"] if mode == "decode" else \
-            _null_cache(params["dense_layers"])
-        x, ys_d = scan_layers(x, params["dense_layers"], dense_fn, dc
-                              if mode == "decode" else None)
+        dc = cache["dense"] if mode == "decode" else None
+        x, ys_d, _ = scan_layers(x, params["layers"]["dense"], dense_fn, dc)
         mc = cache["moe"] if mode == "decode" else None
-        x, ys_m = scan_layers(x, params["layers"], plain_fn, mc)
+        x, ys_m, stats = scan_layers(x, params["layers"]["moe"], plain_fn,
+                                     mc)
         if want_cache:
-            return x, {"dense": ys_d, "moe": ys_m}
-        return x, None
+            return x, {"dense": ys_d, "moe": ys_m}, stats
+        return x, None, stats
 
     lc = cache["layers"] if mode == "decode" else None
-    x, ys = scan_layers(x, params["layers"], plain_fn, lc)
+    x, ys, stats = scan_layers(x, params["layers"], plain_fn, lc)
     if want_cache:
-        return x, {"layers": ys}
-    return x, None
+        return x, {"layers": ys}, stats
+    return x, None, stats
 
 
 def _vlm_group_body(cfg, gather, mode, want_cache, vision_embeds, pos, moe):
@@ -461,7 +484,7 @@ def _vlm_group_body(cfg, gather, mode, want_cache, vision_embeds, pos, moe):
 
         def inner(xc, lp):
             lp = _g(gather, lp)
-            out, newkv = _self_layer(cfg, lp, xc, moe=False)
+            out, newkv, _ = _self_layer(cfg, lp, xc, moe=False)
             ys = {"k": newkv[0], "v": newkv[1]} if want_cache else None
             return out, ys
         if mode == "train":
@@ -498,13 +521,28 @@ def _head(cfg: ModelConfig, params, emb, gather: Gather):
 
 def loss_fn(cfg: ModelConfig, params: dict, batch: dict, *,
             gather: Gather = None, loss_chunk: int = 2048) -> jax.Array:
+    return loss_and_aux(cfg, params, batch, gather=gather,
+                        loss_chunk=loss_chunk)[0]
+
+
+def loss_and_aux(cfg: ModelConfig, params: dict, batch: dict, *,
+                 gather: Gather = None, loss_chunk: int = 2048):
+    """(loss, counters): the cross-entropy plus the dropless MoE layers'
+    balance loss; ``counters["moe_rows"]`` the rows routed to the held
+    experts, summed over layers (dropless MoE only, else no counters)."""
     tokens, labels = batch["tokens"], batch["labels"]
-    x, emb = _embed(cfg, params, tokens, gather)
-    x, _ = run_stack(cfg, params, x, mode="train",
-                     vision_embeds=batch.get("vision_embeds"), gather=gather)
-    x = base.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = _head(cfg, params, emb, gather)
-    return chunked_ce(cfg, x, head, labels, loss_chunk)
+    with jax.named_scope("lm.embed"):
+        x, emb = _embed(cfg, params, tokens, gather)
+    x, _, stats = run_stack(cfg, params, x, mode="train",
+                            vision_embeds=batch.get("vision_embeds"),
+                            gather=gather)
+    with jax.named_scope("lm.head_loss"):
+        x = base.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = _head(cfg, params, emb, gather)
+        loss = chunked_ce(cfg, x, head, labels, loss_chunk)
+    if "rows" not in stats:
+        return loss, {}
+    return loss + stats["aux"], {"moe_rows": stats["rows"]}
 
 
 def chunked_ce(cfg, x, head, labels, chunk):
@@ -531,9 +569,9 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, *,
     """Forward pass over a prompt; returns (last-token logits, cache)."""
     tokens = batch["tokens"]
     x, emb = _embed(cfg, params, tokens, gather)
-    x, cache = run_stack(cfg, params, x, mode="prefill",
-                         vision_embeds=batch.get("vision_embeds"),
-                         gather=gather)
+    x, cache, _ = run_stack(cfg, params, x, mode="prefill",
+                            vision_embeds=batch.get("vision_embeds"),
+                            gather=gather)
     x = base.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = _head(cfg, params, emb, gather)
     logits = x[:, -1:] @ head
@@ -548,8 +586,8 @@ def decode_step(cfg: ModelConfig, params: dict, token, cache: dict, *,
     pos = cache["pos"]
     x, emb = _embed(cfg, params, token, gather)
     layer_caches = {k: v for k, v in cache.items() if k != "pos"}
-    x, new_cache = run_stack(cfg, params, x, mode="decode",
-                             cache=layer_caches, pos=pos, gather=gather)
+    x, new_cache, _ = run_stack(cfg, params, x, mode="decode",
+                                cache=layer_caches, pos=pos, gather=gather)
     x = base.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = _head(cfg, params, emb, gather)
     logits = base.softcap(x @ head, cfg.logit_softcap)

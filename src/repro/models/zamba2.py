@@ -78,7 +78,8 @@ def _run(cfg: ModelConfig, params, x, *, mode: str, cache=None, pos=None,
             c = dict(gacache)
             c["pos"] = pos
         po = pos if mode != "train" else None
-        x, kv = tf._self_layer(cfg, sp, x, moe=False, cache=c, pos_offset=po)
+        x, kv, _ = tf._self_layer(cfg, sp, x, moe=False, cache=c,
+                                  pos_offset=po)
         ays = {"k": kv[0], "v": kv[1]} if want_cache else None
         return x, (mys, ays)
 

@@ -18,7 +18,11 @@ hierarchical name schema (DESIGN.md §16):
 ``schedule.{occupancy_cycles,makespan_cycles,utilization}``
     the shared-schedule aggregates ``CongestionMonitor`` consumes;
 ``congestion.l<level>s<index>.hotness``
-    per physical fabric slot, the observed congestion map.
+    per physical fabric slot, the observed congestion map;
+``moe.{rows,dropped}``
+    a dropless MoE train step's rows routed to the experts held, summed
+    over layers, and the rows dropped (none), read back with the loss
+    (``observe_moe``).
 
 Three instrument types, strictly typed per name — registering a name as
 a counter and later as a gauge is an error, never a silent coercion:
@@ -214,3 +218,16 @@ class MetricsRegistry:
     def write(self, path: str) -> None:
         with open(path, "w") as f:
             f.write(self.to_json())
+
+
+#: the counters of a dropless MoE train step (``observe_moe``)
+MOE_COUNTERS = ("moe.rows", "moe.dropped")
+
+
+def observe_moe(registry: MetricsRegistry, step_metrics: dict) -> None:
+    """Fold one train step's MoE counters, already read on the host, into
+    ``moe.rows`` (the step's ``moe_rows``: rows routed to the experts
+    held, summed over layers) and ``moe.dropped``, which the dropless
+    layer keeps at 0."""
+    registry.counter("moe.rows").inc(step_metrics["moe_rows"])
+    registry.counter("moe.dropped").inc(0)

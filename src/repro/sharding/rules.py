@@ -32,7 +32,7 @@ from repro.core import fsdp as fsdp_mod
 #: leading-axis-stacked parameter collections (per-layer scan stacks)
 STACKED_ROOTS = frozenset({
     "layers", "local_layers", "global_layers", "cross_layers",
-    "dense_layers", "enc_layers", "dec_layers",
+    "enc_layers", "dec_layers",
 })
 
 MIN_FSDP_SIZE = 1 << 16

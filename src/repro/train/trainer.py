@@ -50,6 +50,12 @@ def _split_by_fsdp(tree: Any, dims: Any):
     return leaves, treedef, fsdp_idx, rep_idx
 
 
+def _metric_specs(model, spec) -> dict:
+    """One replicated ``spec`` per value the step returns: the loss, the
+    gradient norm and the model's counters (``model.aux_names``)."""
+    return {n: spec for n in ("loss", "grad_norm", *model.aux_names)}
+
+
 def make_train_step(model, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
                     params_tree: Any, *, reduce_manager=None,
                     tenant: str | None = None):
@@ -72,9 +78,11 @@ def make_train_step(model, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
     def step_body(params, opt_state, batch):
         def loss_fn(p):
             # local-mean / data_world → summed gradients = global mean
-            return model.loss(p, batch, gather=gather) / data_world
+            loss, counters = model.loss_aux(p, batch, gather=gather)
+            return loss / data_world, counters
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        (loss, counters), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
 
         # --- replicated-leaf reduction through the Flare engine ----------
         g_leaves, treedef, fsdp_idx, rep_idx = _split_by_fsdp(grads, dims)
@@ -108,7 +116,10 @@ def make_train_step(model, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
         if ef is not None:
             new_opt["ef"] = ef
         loss = jax.lax.psum(loss, reduce_axes)   # undo /data_world: global mean
-        return new_params, new_opt, {"loss": loss, "grad_norm": gnorm}
+        metrics = {"loss": loss, "grad_norm": gnorm}
+        for name in model.aux_names:             # counters summed over ranks
+            metrics[name] = jax.lax.psum(counters[name], reduce_axes)
+        return new_params, new_opt, metrics
 
     # --- shard_map wrapper -----------------------------------------------
     def wrap(batch_tree):
@@ -116,7 +127,7 @@ def make_train_step(model, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
         in_specs = ((manual_specs,
                      _opt_specs(manual_specs), bspec))
         out_specs = (manual_specs, _opt_specs(manual_specs),
-                     {"loss": P(), "grad_norm": P()})
+                     _metric_specs(model, P()))
         # a size-1 axis partitions nothing, so it is manual too: a
         # compiled Pallas kernel (the in-network switch handlers) cannot
         # sit in a region that leaves any mesh axis auto
@@ -167,7 +178,7 @@ def jit_train_step(model, mesh, mesh_cfg: rules.MeshCfg, tcfg: TrainConfig,
         opt_sh["ef"] = [ns(P()) for _ in rep_idx]
     bspec = rules.batch_spec(batch_tree, mesh_cfg)
     batch_sh = jax.tree.map(ns, bspec)
-    out_sh = (param_sh, opt_sh, {"loss": ns(P()), "grad_norm": ns(P())})
+    out_sh = (param_sh, opt_sh, _metric_specs(model, ns(P())))
 
     fn = jax.jit(smapped,
                  in_shardings=(param_sh, opt_sh, batch_sh),

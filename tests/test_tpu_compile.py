@@ -132,3 +132,27 @@ def test_innetwork_train_step_compiles_on_four_chips(topo, on_tpu):
         text = fn.lower(placed(params, param_sh), placed(opt, opt_sh),
                         placed(batch, batch_sh)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_dropless_moe_layer_compiles_with_grouped_products(one_chip, on_tpu):
+    """DeepSeek-V2-Lite's MoE layer at its published widths over 8 held
+    experts, forward and backward: the grouped products are megablox
+    kernels, and the chip's compiler takes them inside the token-chunk
+    scan."""
+    from repro import configs
+    from repro.models import base, get_model
+
+    cfg = configs.load("deepseek-v2-lite-16b").CONFIG.scaled(
+        n_layers=2, experts_held=8, moe_chunk=512)
+    shapes = jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
+    ffn = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=one_chip),
+        shapes["layers"]["moe"]["ffn"])
+    x = jax.ShapeDtypeStruct((1, 1024, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def grads(p, x):
+        return jax.grad(lambda p: jnp.sum(
+            base.moe_dropless(cfg, p, x)[0].astype(jnp.float32)))(p)
+    text = _compiled_text(grads, ffn, x)
+    assert text.count("tpu_custom_call") >= 3
