@@ -54,8 +54,9 @@ def _is_pow2(n: int) -> bool:
     return n > 0 and (n & (n - 1)) == 0
 
 
-def _ring_perm(p: int) -> list[tuple[int, int]]:
-    return [(i, (i + 1) % p) for i in range(p)]
+def _ring_perm(p: int, direction: int = 1) -> list[tuple[int, int]]:
+    """Rank i sends to i + 1 (``direction`` 1) or to i − 1 (−1)."""
+    return [(i, (i + direction) % p) for i in range(p)]
 
 
 def xor_perm(p: int, d: int) -> list[tuple[int, int]]:
@@ -97,15 +98,49 @@ def pad_to_multiple(x: jax.Array, m: int) -> tuple[jax.Array, int]:
 # Ring (Rabenseifner) — the paper's host-based baseline.
 # ---------------------------------------------------------------------------
 
+#: Chunk alignment, in elements, that lets the ring's (bucket, chunk)
+#: view share the flat arena's memory on a TPU: one (8, 128) float32 tile.
+CHUNK_ALIGN = 1024
+
+
+def ring_splits(p: int, chunk: int) -> bool:
+    """Whether the ring sends each chunk both ways round: its lower half
+    on the ring r → r+1, its upper half at the same time on the mirrored
+    ring r → r−1, so every rank drives both of its ring links.
+
+    The one split predicate, of what the call sees alone: P ≥ 3 and a
+    chunk of at least two whole ``CHUNK_ALIGN`` tiles.  At P = 2 the
+    one-way ring already uses both directions of the single link, and
+    one-tile chunks are latency-bound: both keep the one-way ring."""
+    return p >= 3 and chunk % CHUNK_ALIGN == 0 and chunk >= 2 * CHUNK_ALIGN
+
+
+def _split_at(chunk: int) -> int:
+    """Length of a split chunk's lower (forward) half: whole tiles, so
+    both halves stay tile-aligned views of the chunk."""
+    return chunk // (2 * CHUNK_ALIGN) * CHUNK_ALIGN
+
+
+def _ring_shift(direction: int, step: int) -> int:
+    """The chunk a ring rank handles ``step`` hops upstream of the one it
+    ends owning, ``(r + 1 + stagger) % P``, as a shift of ``r + stagger``.
+
+    Upstream is r − 1 on the forward ring and r + 1 on the mirrored one,
+    so both directions leave rank r owning the same chunk."""
+    return 1 - direction * step
+
+
 @jax.named_scope("flare.ring.reduce_scatter")
 def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
-                        stagger: int = 0) -> jax.Array:
+                        stagger: int = 0, direction: int = 1) -> jax.Array:
     """Reduce-scatter a flat vector over ``axis`` with a ppermute ring.
 
     Rank ``r`` returns the fully reduced chunk ``(r + 1 + stagger) % P``.
     ``stagger`` rotates which chunk each rank starts from — the paper's
     *staggered sending* (§5): concurrent buckets use different offsets so
     their traffic never contends for the same chunk/link at the same step.
+    ``direction`` 1 sends to rank r + 1, −1 to r − 1 (the mirrored ring:
+    each chunk's partial sums then meet in the opposite rank order).
     ``x.shape[0]`` must be divisible by the axis size.
     """
     p = lax.axis_size(axis)
@@ -113,13 +148,15 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
     if x.shape[0] % p:
         raise ValueError(f"ring_reduce_scatter: len {x.shape[0]} % {p} != 0")
     chunks = x.reshape((p, x.shape[0] // p) + x.shape[1:])
-    perm = _ring_perm(p)
-    send0 = jnp.take(chunks, (r + stagger) % p, axis=0)
+    perm = _ring_perm(p, direction)
+    send0 = jnp.take(chunks, (r + stagger + _ring_shift(direction, 1)) % p,
+                     axis=0)
 
     def body(s, carry):
         chunks, acc = carry
         recv = lax.ppermute(acc, axis, perm)
-        mine = jnp.take(chunks, (r - s - 1 + stagger) % p, axis=0)
+        mine = jnp.take(chunks, (r + stagger + _ring_shift(direction, s + 2))
+                        % p, axis=0)
         return chunks, op(mine, recv)
 
     _, acc = lax.fori_loop(0, p - 1, body, (chunks, send0))
@@ -127,18 +164,20 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, op: Op = jnp.add,
 
 
 @jax.named_scope("flare.ring.all_gather")
-def ring_all_gather(chunk: jax.Array, axis: str, *, stagger: int = 0) -> jax.Array:
+def ring_all_gather(chunk: jax.Array, axis: str, *, stagger: int = 0,
+                    direction: int = 1) -> jax.Array:
     """Inverse of ``ring_reduce_scatter``: gather P chunks back to a vector."""
     p = lax.axis_size(axis)
     r = lax.axis_index(axis)
-    perm = _ring_perm(p)
+    perm = _ring_perm(p, direction)
     out0 = jnp.zeros((p,) + chunk.shape, chunk.dtype)
     out0 = lax.dynamic_update_index_in_dim(out0, chunk, (r + 1 + stagger) % p, 0)
 
     def body(s, carry):
         out, send = carry
         recv = lax.ppermute(send, axis, perm)
-        out = lax.dynamic_update_index_in_dim(out, recv, (r - s + stagger) % p, 0)
+        out = lax.dynamic_update_index_in_dim(
+            out, recv, (r + stagger + _ring_shift(direction, s + 1)) % p, 0)
         return out, recv
 
     out, _ = lax.fori_loop(0, p - 1, body, (out0, chunk))
@@ -147,12 +186,30 @@ def ring_all_gather(chunk: jax.Array, axis: str, *, stagger: int = 0) -> jax.Arr
 
 def allreduce_ring(x: jax.Array, axis: str, *, op: Op = jnp.add,
                    stagger: int = 0) -> jax.Array:
-    """Rabenseifner ring allreduce: ~2Z(P-1)/P bytes per rank on the wire."""
+    """Rabenseifner ring allreduce: ~2Z(P-1)/P bytes per rank on the wire.
+
+    Where ``ring_splits`` holds, each chunk's lower ``_split_at`` elements
+    take the forward ring and the rest the mirrored one, as
+    ``ring_allreduce_bucketed`` sends them: the two agree bit for bit."""
     p = lax.axis_size(axis)
     xp, n = pad_to_multiple(x, p)
-    chunk = ring_reduce_scatter(xp, axis, op=op, stagger=stagger)
-    full = ring_all_gather(chunk, axis, stagger=stagger)
-    return full[:n]
+    c = xp.shape[0] // p
+    if not ring_splits(p, c):
+        chunk = ring_reduce_scatter(xp, axis, op=op, stagger=stagger)
+        full = ring_all_gather(chunk, axis, stagger=stagger)
+        return full[:n]
+    rest = xp.shape[1:]
+    chunks = xp.reshape((p, c) + rest)
+    h = _split_at(c)
+    halves = []
+    for direction, part in ((1, chunks[:, :h]), (-1, chunks[:, h:])):
+        flat = part.reshape((-1,) + rest)
+        got = ring_reduce_scatter(flat, axis, op=op, stagger=stagger,
+                                  direction=direction)
+        got = ring_all_gather(got, axis, stagger=stagger,
+                              direction=direction)
+        halves.append(got.reshape(part.shape))
+    return jnp.concatenate(halves, axis=1).reshape(xp.shape)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -161,18 +218,14 @@ def allreduce_ring(x: jax.Array, axis: str, *, op: Op = jnp.add,
 #
 # The paper's multi-buffer aggregation keeps B reduction blocks in flight:
 # every round of ``ring_allreduce_bucketed`` carries all B blocks' chunks
-# in ONE ppermute, 2(P-1) collective rounds total instead of the 2B(P-1)
-# a per-bucket loop costs.  Which chunk bucket b sends in a round,
-# ``(r - s - 1 + σ_b) % P``, depends on its stagger σ_b only through
-# σ_b mod P, so the B buckets fall into at most P *stagger classes* that
-# share one scalar chunk index per round: the schedule picks and writes
-# chunks with one ``dynamic_slice`` / ``dynamic_update_slice`` per class,
+# in ONE ppermute per ring direction, 2(P-1) collective rounds total
+# instead of the 2B(P-1) a per-bucket loop costs.  Which chunk bucket b
+# sends in a round, ``(r - s - 1 + σ_b) % P`` on the forward ring,
+# depends on its stagger σ_b only through σ_b mod P, so the B buckets
+# fall into at most P *stagger classes* that share one scalar chunk
+# index per round: the schedule picks and writes chunks with one
+# ``dynamic_slice`` / ``dynamic_update_slice`` per class and direction,
 # where a vmap over buckets made every index a per-bucket gather/scatter.
-
-#: Chunk alignment, in elements, that lets the ring's (bucket, chunk)
-#: view share the flat arena's memory on a TPU: one (8, 128) float32 tile.
-CHUNK_ALIGN = 1024
-
 
 def static_staggers(staggers) -> tuple[int, ...] | None:
     """The per-bucket staggers as Python ints, or None when only the
@@ -219,19 +272,23 @@ def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
 
     ``arena`` is ``(B, S)`` with ``S`` divisible by the axis size (the
     arena plan guarantees this).  Round s of *every* bucket's
-    reduce-scatter (then all-gather) executes as ONE ppermute carrying a
-    ``(B, S/P)`` payload — the paper's B concurrent reduction blocks
-    sharing the network (§6.2), each offset by its own ``stagger`` phase
-    (§5).  Per bucket the combine chain is exactly ``allreduce_ring``'s,
-    so results are bitwise-equal to the per-bucket loop.
+    reduce-scatter (then all-gather) executes as ONE ppermute per ring
+    direction carrying a ``(B, ·)`` payload — the paper's B concurrent
+    reduction blocks sharing the network (§6.2), each offset by its own
+    ``stagger`` phase (§5).  Where ``ring_splits`` holds, each chunk's
+    lower half takes the ring r → r+1 and its upper half the mirrored
+    ring r → r−1 in the same rounds, so every rank drives both of its
+    ring links; both halves end on the same rank.  Per bucket the combine
+    chain is exactly ``allreduce_ring``'s, so results are bitwise-equal
+    to the per-bucket loop.
 
     With static staggers (the arena plan's) each round picks and writes
-    chunks per stagger class, on a ``(rows, width, P, chunk)`` view of
-    the arena; with a chunk of whole ``CHUNK_ALIGN`` tiles that view is
-    a bitcast of the flat arena on a TPU, so XLA copies nothing in or
-    out.  Static staggers must repeat with period P, as the plan's do.
-    Staggers known only to the traced program take the vmapped
-    per-bucket ring.
+    chunks per stagger class and direction, on a ``(rows, width, P,
+    C/128, 128)`` view of the arena, the halves split at a whole tile;
+    with a chunk of whole ``CHUNK_ALIGN`` tiles that view is a bitcast of
+    the flat arena on a TPU, so XLA copies nothing in or out.  Static
+    staggers must repeat with period P, as the plan's do.  Staggers known
+    only to the traced program take the vmapped per-bucket ring.
     """
     b, size = arena.shape
     p = lax.axis_size(axis)
@@ -251,16 +308,21 @@ def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
         )(arena, staggers)
     c = size // p
     lanes = (c // 128, 128) if c % 128 == 0 else (c,)
+    if ring_splits(p, c):
+        h = _split_at(c) // 128
+        halves = [(1, (0, 0), (h, 128)), (-1, (h, 0), (lanes[0] - h, 128))]
+    else:
+        halves = [(1, (0,) * len(lanes), lanes)]
     blocks = _class_blocks([s % p for s in sig], p)
     views = [arena[start:start + rows * width].reshape(
                  (rows, width, p) + lanes)
              for start, rows, width, _ in blocks]
-    payload = _ring_bucketed_reduce_scatter(views, blocks, axis, op, lanes)
+    payloads = _ring_bucketed_reduce_scatter(views, blocks, axis, op, halves)
     # the all-gather writes into the views in place: the barrier keeps
     # XLA from fusing the last reads of them into those writes, which
     # would cost a copy of the whole arena
-    views, payload = lax.optimization_barrier((views, payload))
-    out = _ring_bucketed_all_gather(views, blocks, payload, axis, lanes)
+    views, payloads = lax.optimization_barrier((views, payloads))
+    out = _ring_bucketed_all_gather(views, blocks, payloads, axis, halves)
     return out.reshape(b, size)
 
 
@@ -284,58 +346,72 @@ def _segments(blocks):
 
 
 @jax.named_scope("flare.ring.reduce_scatter")
-def _ring_bucketed_reduce_scatter(views, blocks, axis, op, lanes):
-    """Every bucket's reduce-scatter at once; the ``(B, *lanes)`` result
-    holds bucket b's reduced chunk ``(r + 1 + σ_b) % P``, in the
-    payload's class-major order (``_segments``)."""
+def _ring_bucketed_reduce_scatter(views, blocks, axis, op, halves):
+    """Every bucket's reduce-scatter at once, one payload per half.
+
+    ``halves`` holds ``(direction, start, size)`` per ring direction: the
+    part of each chunk's lanes it carries.  Each ``(B, *size)`` result
+    holds bucket b's part of its reduced chunk ``(r + 1 + σ_b) % P``, in
+    the payload's class-major order (``_segments``)."""
     p = lax.axis_size(axis)
     r = lax.axis_index(axis)
-    tail = (0,) * len(lanes)
-
-    def mine(seg, shift):
-        i, j0, j1, cls, _ = seg
-        return lax.dynamic_slice(
-            views[i], (0, j0, _chunk_index(r, cls, shift, p)) + tail,
-            (blocks[i][1], j1 - j0, 1) + lanes).reshape((-1,) + lanes)
-
     segs = _segments(blocks)
-    acc = jnp.concatenate([mine(g, 0) for g in segs])
+
+    def mine(seg, half, step):
+        i, j0, j1, cls, _ = seg
+        direction, start, size = half
+        idx = _chunk_index(r, cls, _ring_shift(direction, step), p)
+        return lax.dynamic_slice(
+            views[i], (0, j0, idx) + start,
+            (blocks[i][1], j1 - j0, 1) + size).reshape((-1,) + size)
+
+    accs = [jnp.concatenate([mine(g, half, 1) for g in segs])
+            for half in halves]
     for s in range(p - 1):
-        acc = lax.ppermute(acc, axis, _ring_perm(p))
-        for g in segs:
-            # combined into the received buffer in place: one pass over
-            # each class's rows, with no concatenate per round
-            m = mine(g, -s - 1)
-            got = lax.slice_in_dim(acc, g[4], g[4] + m.shape[0])
-            acc = lax.dynamic_update_slice_in_dim(acc, op(m, got), g[4], 0)
-    return acc
+        # every direction's permute is issued before any round's combine
+        accs = [lax.ppermute(acc, axis, _ring_perm(p, half[0]))
+                for acc, half in zip(accs, halves)]
+        for k, half in enumerate(halves):
+            for g in segs:
+                # combined into the received buffer in place: one pass
+                # over each class's rows, with no concatenate per round
+                m = mine(g, half, s + 2)
+                got = lax.slice_in_dim(accs[k], g[4], g[4] + m.shape[0])
+                accs[k] = lax.dynamic_update_slice_in_dim(
+                    accs[k], op(m, got), g[4], 0)
+    return accs
 
 
 @jax.named_scope("flare.ring.all_gather")
-def _ring_bucketed_all_gather(views, blocks, chunk, axis, lanes):
+def _ring_bucketed_all_gather(views, blocks, chunks, axis, halves):
     """Inverse of the reduce-scatter: every bucket's all-gather at once.
 
-    Each round writes every chunk once, so the reduce-scatter's views,
-    dead by then, serve as the output buffers."""
+    Each round writes every chunk's half once per direction, so the
+    reduce-scatter's views, dead by then, serve as the output buffers."""
     p = lax.axis_size(axis)
     r = lax.axis_index(axis)
-    tail = (0,) * len(lanes)
+    segs = _segments(blocks)
     outs = list(views)
 
-    def put(payload, shift):
-        for i, j0, j1, cls, off in _segments(blocks):
+    def put(payload, half, step):
+        direction, start, size = half
+        for i, j0, j1, cls, off in segs:
             rows = blocks[i][1]
             part = payload[off:off + rows * (j1 - j0)].reshape(
-                (rows, j1 - j0, 1) + lanes)
+                (rows, j1 - j0, 1) + size)
+            idx = _chunk_index(r, cls, _ring_shift(direction, step), p)
             outs[i] = lax.dynamic_update_slice(
-                outs[i], part, (0, j0, _chunk_index(r, cls, shift, p)) + tail)
+                outs[i], part, (0, j0, idx) + start)
 
-    put(chunk, 1)
-    send = chunk
+    for chunk, half in zip(chunks, halves):
+        put(chunk, half, 0)
+    sends = list(chunks)
     for s in range(p - 1):
-        send = lax.ppermute(send, axis, _ring_perm(p))
-        put(send, -s)
-    flat = [o.reshape((-1, p) + lanes) for o in outs]
+        sends = [lax.ppermute(send, axis, _ring_perm(p, half[0]))
+                 for send, half in zip(sends, halves)]
+        for send, half in zip(sends, halves):
+            put(send, half, s + 1)
+    flat = [o.reshape((-1, p) + o.shape[3:]) for o in outs]
     return jnp.concatenate(flat) if len(flat) > 1 else flat[0]
 
 

@@ -126,7 +126,8 @@ class DenseTransport(Transport):
     """Lossless allreduce of the arena, all B buckets in one schedule.
 
     The ring on one axis runs ``coll.ring_allreduce_bucketed``'s
-    stagger-class schedule; rhd, the fixed tree, two-level and
+    stagger-class schedule, each chunk's halves both ways round where
+    ``coll.ring_splits`` holds; rhd, the fixed tree, two-level and
     hierarchical vmap their single-vector schedule over the buckets."""
 
     algorithm: str = "auto"
@@ -167,14 +168,18 @@ class DenseTransport(Transport):
         sig = (coll.static_staggers(staggers) if self.class_ring(nbytes)
                else None)
         if sig is not None:
-            # one ppermute per round for all B buckets, chunks picked
-            # and written once per stagger class (collectives.py)
+            # one ppermute per round and ring direction for all B
+            # buckets, chunks picked and written once per stagger class
+            # and direction (collectives.py)
             red = coll.ring_allreduce_bucketed(buf, self.axes[0],
                                                staggers=sig)
             if self.telemetry is not None:
                 p = lax.axis_size(self.axes[0])
                 reg = self.telemetry.registry
                 reg.counter("wire.ring.class_batched_buckets").inc(len(sig))
+                both = coll.ring_splits(p, buf.shape[1] // p)
+                reg.counter("wire.ring.bidirectional_buckets").inc(
+                    len(sig) if both else 0)
                 reg.counter("wire.ring.stagger_classes").inc(
                     len({s % p for s in sig}))
         elif self.batched:

@@ -213,10 +213,64 @@ RING_PLAN_CASES = {
     "plan-ring-growth-capped": ((4,), {"algorithm": "ring"}, 5000, 3,
                                 False),
 }
+#: Bidirectional ring cases: name → (P, B, staggers, op, chunk length,
+#: whether each chunk's halves go both ways round).  The split needs
+#: P ≥ 3 and a chunk of at least two whole 1024-element tiles; three
+#: tiles split unevenly (one forward, two mirrored).
+RING_SPLIT_CASES = {
+    "split-p4-b8-base1-add": (4, 8, ("base", 1), "add", 2048, True),
+    "split-p4-b5-base3-add": (4, 5, ("base", 3), "add", 3072, True),
+    "split-p4-b6-off-add": (4, 6, ("off",), "add", 2048, True),
+    "split-p4-b5-base3-max": (4, 5, ("base", 3), "max", 2048, True),
+    "split-p3-b4-base1-add": (3, 4, ("base", 1), "add", 2048, True),
+    "oneway-p2-b5-base3-add": (2, 5, ("base", 3), "add", 4096, False),
+    "oneway-p4-b6-base3-one-tile": (4, 6, ("base", 3), "add", 1024, False),
+}
 RING_CLASS_CHECKS = (tuple(RING_CLASS_CASES) + tuple(RING_PLAN_CASES)
+                     + tuple(RING_SPLIT_CASES)
                      + ("aperiodic-staggers-refused",
                         "dense-ring-batched-eq-scan", "ring-structure",
                         "reducer-ring-multibucket"))
+
+
+def ring_oracle(xs: np.ndarray, staggers, op, split: bool) -> np.ndarray:
+    """The ring allreduce of ``xs`` (``(P, B, S)``, rank-major) in numpy
+    float32, one combine at a time in the ring's own order.
+
+    Bucket b's chunk k ends on rank ``k − 1 − σ_b``.  On the forward
+    ring its partial sum starts at rank ``k − σ_b`` and gathers ranks
+    upwards; with ``split`` the chunk's upper half (from the last whole
+    1024-element tile at or below its middle) runs the mirrored ring,
+    starting at rank ``k − σ_b − 2`` and gathering ranks downwards."""
+    p, b, size = xs.shape
+    c = size // p
+    h = c // 2048 * 1024 if split else c
+    parts = [(0, h, 1)] + ([(h, c, -1)] if split else [])
+    out = np.empty((b, size), np.float32)
+    for i in range(b):
+        for k in range(p):
+            for lo, hi, d in parts:
+                cols = slice(k * c + lo, k * c + hi)
+                rank = (k - staggers[i] - (1 - d)) % p
+                acc = xs[rank, i, cols]
+                for _ in range(p - 1):
+                    rank = (rank + d) % p
+                    acc = op(xs[rank, i, cols], acc)
+                out[i, cols] = acc
+    return out
+
+
+def _prims(jaxpr) -> list[str]:
+    """Every primitive of a jaxpr, sub-jaxprs included."""
+    prims = []
+
+    def walk(jx):
+        for e in jx.eqns:
+            prims.append(e.primitive.name)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    return prims
 
 
 def _flat_mesh(p: int):
@@ -278,6 +332,45 @@ def check_ring_classes():
             [coll.allreduce_ring(a[i], "data", op=op, stagger=sig[i])
              for i in range(b)]), xs, p)
         assert got.tobytes() == want.tobytes(), name
+        print(f"ring_classes {name} OK")
+
+    # the bidirectional ring: the class ring, the per-bucket ring and
+    # the dense transport against the numpy oracle of each half's
+    # combine order; P = 2 and one-tile chunks keep the one-way ring,
+    # 2(P-1) ppermutes and its bits, a split chunk 4(P-1)
+    for name, (p, b, stag, op_name, c, split) in RING_SPLIT_CASES.items():
+        sig = (tuple(stag[1] + i for i in range(b)) if stag[0] == "base"
+               else (0,) * b)
+        op = {"add": jnp.add, "max": jnp.maximum}[op_name]
+        rng = np.random.default_rng(b * 1000 + c + p)
+        xs = (rng.normal(size=(p, b, p * c)) * 1e3).astype(np.float32)
+        want = ring_oracle(xs, sig, {"add": np.add, "max": np.maximum}[
+            op_name], split).tobytes() * p
+        got = _run_flat(lambda a: coll.ring_allreduce_bucketed(
+            a, "data", op=op, staggers=sig), jnp.asarray(xs), p)
+        assert got.tobytes() == want, (name, "class ring")
+        got = _run_flat(lambda a: jnp.stack(
+            [coll.allreduce_ring(a[i], "data", op=op, stagger=sig[i])
+             for i in range(b)]), jnp.asarray(xs), p)
+        assert got.tobytes() == want, (name, "per-bucket ring")
+        tel = Telemetry.create()
+        t = transports.DenseTransport(("data",), algorithm="ring",
+                                      telemetry=tel)
+        if op_name == "add":
+            got = _run_flat(lambda a: t(a, None, np.asarray(sig, np.int32),
+                                        (p * c,) * b)[0], jnp.asarray(xs), p)
+            assert got.tobytes() == want, (name, "dense transport")
+            assert tel.registry.value("wire.ring.bidirectional_buckets") == (
+                b if split else 0), name
+        prims = _prims(jax.make_jaxpr(jax.shard_map(
+            lambda a: coll.ring_allreduce_bucketed(
+                a[0], "data", op=op, staggers=sig)[None],
+            mesh=_flat_mesh(p), in_specs=P("data"), out_specs=P("data"),
+            check_vma=False))(jax.ShapeDtypeStruct(xs.shape, jnp.float32)))
+        assert not {"gather", "scatter", "scatter-add", "while"} & set(
+            prims), (name, sorted(set(prims)))
+        assert prims.count("ppermute") == (4 if split else 2) * (p - 1), (
+            name, prims.count("ppermute"))
         print(f"ring_classes {name} OK")
 
     # static staggers of no period have no class blocks: refused
@@ -346,21 +439,15 @@ def check_ring_classes():
                     (s,) * b)[0][None],
         mesh=_flat_mesh(p), in_specs=P("data"), out_specs=P("data"),
         check_vma=False)
-    jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((p, b, s), jnp.float32))
-    prims = []
-
-    def walk(jx):
-        for e in jx.eqns:
-            prims.append(e.primitive.name)
-            for sub in jax.core.jaxprs_in_params(e.params):
-                walk(sub)
-    walk(jaxpr.jaxpr)
+    prims = _prims(jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct((p, b, s), jnp.float32)))
     assert not {"gather", "scatter", "scatter-add", "while"} & set(prims), \
         sorted(set(prims))
     assert prims.count("ppermute") == 2 * (p - 1), prims.count("ppermute")
     reg = tel.registry
     assert {n: reg.value(n) for n in reg.names()} == {
         "wire.ring.class_batched_buckets": b,
+        "wire.ring.bidirectional_buckets": 0,
         "wire.ring.stagger_classes": p}
     print("ring_classes ring-structure OK")
 
@@ -407,6 +494,7 @@ def check_ring_classes():
     assert np.allclose(got[0], np.asarray(xs).sum(0), rtol=1e-5, atol=1e-4)
     reg = tel.registry
     assert reg.value("wire.ring.class_batched_buckets") == 6
+    assert reg.value("wire.ring.bidirectional_buckets") == 6
     assert reg.value("wire.ring.stagger_classes") == p
     print("ring_classes reducer-ring-multibucket OK")
     print("ring_classes OK")
