@@ -1,75 +1,23 @@
 """Benchmark aggregator: one module per paper table/figure.
 
-Prints ``name,value,derived`` CSV.  Figure benchmarks are deterministic
-models/simulations; ``collectives_bench`` adds wall-clock numbers from an
-8-device subprocess (and persists them to ``BENCH_collectives.json`` at
-the repo root — the tracked perf trajectory); ``roofline`` reads the
-dry-run artifacts if present.
+Prints ``name,value,derived`` CSV.  The figure benchmarks are
+deterministic models/simulations of the paper's switch; ``roofline``
+reads the dry-run artifacts if present.  None of them times anything:
+speed is measured on the chip by ``bench/run.py``.
 
-``--json`` runs only the collective wall-clock benchmark and (re)writes
-``BENCH_collectives.json``.
-
-``--quick`` runs the tiny-shape transport benchmark (all three
-transports, per-bucket scan vs batched, 8 fake CPU devices, seconds not
-minutes) and never writes the JSON — the tier-1 smoke test invokes this
-so the harness can't silently rot.
-
-``--check-regressions`` is the perf-regression sentinel: a fresh
-wall-clock run compared against the committed ``BENCH_collectives.json``
-(provenance via its ``meta`` key); any ``*_x`` ratio row degraded by
-more than 20% exits nonzero.  The baseline is never rewritten by this
-mode.
+Run: ``PYTHONPATH=src python -m benchmarks.run``
 """
 import sys
 import time
 
 
-def main(argv=None) -> None:
-    argv = sys.argv[1:] if argv is None else argv
-    from benchmarks import (collectives_bench, fig07_single_buffer,
-                            fig10_aggregation, fig11_switch_bw,
-                            fig13_sparse_model, fig14_sparse_sim,
-                            fig15_network, roofline)
-    if "--quick" in argv:
-        # --quick is the tier-1 smoke gate: a raising benchmark must exit
-        # nonzero, never degrade into a shorter CSV (the full run below
-        # keeps its per-module ERROR-row-and-continue behavior — it is a
-        # report, --quick is a check).
-        print("name,value,derived")
-        try:
-            rows = collectives_bench.run_quick()
-        except Exception as e:
-            print(f"benchmarks.run.quick.ERROR,0,{e!r}", file=sys.stderr)
-            raise SystemExit(1)
-        for name, val, derived in rows:
-            print(f"{name},{val},{derived}")
-        return
-    if "--check-regressions" in argv:
-        # perf-regression sentinel: fresh wall-clock run vs the tracked
-        # BENCH_collectives.json — any *_x ratio row degraded by >20%
-        # exits nonzero (the baseline is NOT rewritten; refresh it with
-        # --json once a regression is understood and accepted)
-        print("name,value,derived")
-        rows = collectives_bench.run(write_json=False)
-        for name, val, derived in rows:
-            print(f"{name},{val},{derived}")
-        failures = collectives_bench.check_regressions(rows)
-        if failures:
-            for f in failures:
-                print(f"REGRESSION: {f}", file=sys.stderr)
-            raise SystemExit(1)
-        print("no regressions past 20% against "
-              f"{collectives_bench.BENCH_JSON}", file=sys.stderr)
-        return
-    if "--json" in argv:
-        print("name,value,derived")
-        for name, val, derived in collectives_bench.run(write_json=True):
-            print(f"{name},{val},{derived}")
-        print(f"wrote {collectives_bench.BENCH_JSON}", file=sys.stderr)
-        return
+def main() -> None:
+    from benchmarks import (fig07_single_buffer, fig10_aggregation,
+                            fig11_switch_bw, fig13_sparse_model,
+                            fig14_sparse_sim, fig15_network, roofline)
     modules = [fig07_single_buffer, fig10_aggregation, fig11_switch_bw,
                fig13_sparse_model, fig14_sparse_sim, fig15_network,
-               collectives_bench, roofline]
+               roofline]
     print("name,value,derived")
     for mod in modules:
         t0 = time.time()

@@ -13,11 +13,11 @@ Public API:
     gradient-reduction engine used by the training loop.
   - ``topology``: reduction trees + the control-plane network manager.
 """
-from repro.core import (bucketing, collectives, compression, fsdp,
-                        reproducible, sparse, topology, transports)
+from repro.core import (collectives, compression, fsdp, reproducible,
+                        sparse, topology, transports)
 from repro.core.engine import FlareConfig, GradReducer
 
 __all__ = [
-    "bucketing", "collectives", "compression", "fsdp", "reproducible",
-    "sparse", "topology", "transports", "FlareConfig", "GradReducer",
+    "collectives", "compression", "fsdp", "reproducible", "sparse",
+    "topology", "transports", "FlareConfig", "GradReducer",
 ]

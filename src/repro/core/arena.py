@@ -13,12 +13,13 @@ module replaces that with a **plan computed once per pytree structure**:
     offsets — since bucket boundaries are a pure reshape view, leaves may
     straddle them freely (the reduction is elementwise across ranks);
   * padding is folded into the plan (``bucket_elems`` is rounded up to
-    ``pad_multiple``) so the collectives never re-pad at runtime;
+    each group's pad multiple, which its transport decides) so the
+    collectives never re-pad at runtime;
   * equal-size buckets become the leading axis of one array, which is
-    what lets ``GradReducer`` reduce all B blocks with a single
-    ``lax.scan`` / pipelined wave schedule instead of B traced calls.
+    what lets ``GradReducer`` reduce all B blocks with one batched
+    schedule instead of B traced calls.
 
-Plans are cached by (leaf shapes/dtypes, bucket_bytes, pad_multiple) —
+Plans are cached by (leaf shapes/dtypes, bucket_bytes, pad multiples) —
 building one is pure Python bookkeeping, no tracing.
 """
 from __future__ import annotations
@@ -71,8 +72,8 @@ class DtypeArena:
         ``min(S, used - b*S)`` elements.  Size-derived transport knobs
         (the sparse top-k, quantization block counts) are computed from
         these extents, never from the padded ``bucket_elems`` — the
-        padded size would inflate k relative to the legacy per-bucket
-        path (see ``sparse.sparse_k``).
+        padded size would inflate k with the padding (see
+        ``sparse.sparse_k``).
         """
         used = self.used_elems
         return tuple(
@@ -179,10 +180,9 @@ def build_plan(leaves: Sequence[jax.Array | jax.ShapeDtypeStruct],
     """Compute (or fetch) the arena plan for a sequence of leaves.
 
     ``pad_multiple`` folds the collectives' divisibility requirement into
-    the plan: with ``pad_multiple = 2 * world`` every bucket length
-    satisfies ring (P), pipelined ring (2P), rhd (P) and two-level
-    (P_in * P_out) chunking with zero runtime padding.  ``group_pads``
-    gives some dtype groups, by dtype name, a multiple of their own.
+    the plan (``Transport.pad_multiple`` says what a schedule needs);
+    the default 1 leaves every bucket unpadded.  ``group_pads`` gives
+    some dtype groups, by dtype name, a multiple of their own.
     """
     return _build_cached(tuple(_leaf_key(l) for l in leaves),
                          int(bucket_bytes), int(pad_multiple),

@@ -278,9 +278,10 @@ def ring_allreduce_bucketed(arena: jax.Array, axis: str, *, op: Op = jnp.add,
     ``stagger`` phase (§5).  Where ``ring_splits`` holds, each chunk's
     lower half takes the ring r → r+1 and its upper half the mirrored
     ring r → r−1 in the same rounds, so every rank drives both of its
-    ring links; both halves end on the same rank.  Per bucket the combine
-    chain is exactly ``allreduce_ring``'s, so results are bitwise-equal
-    to the per-bucket loop.
+    ring links; both halves end on the same rank: 2(P−1) ppermutes in
+    all, 4(P−1) where the chunks split, whatever B.  Per bucket the
+    combine chain is exactly ``allreduce_ring``'s, so results are
+    bitwise-equal to the per-bucket loop.
 
     With static staggers (the arena plan's) each round picks and writes
     chunks per stagger class and direction, on a ``(rows, width, P,
@@ -468,7 +469,8 @@ def rhd_all_gather(seg: jax.Array, axis: str) -> jax.Array:
 
 
 def allreduce_rhd(x: jax.Array, axis: str, *, op: Op = jnp.add) -> jax.Array:
-    """Recursive halving-doubling allreduce (multi-buffer design analogue)."""
+    """Recursive halving-doubling allreduce (multi-buffer design analogue):
+    2 log2 P ppermutes."""
     p = lax.axis_size(axis)
     xp, n = pad_to_multiple(x, p)
     seg = rhd_reduce_scatter(xp, axis, op=op)
@@ -489,7 +491,7 @@ def allreduce_fixed_tree(x: jax.Array, axis: str, *, op: Op = jnp.add,
     ``((0,1),(2,3)),((4,5),(6,7)) ...`` — a pure function of rank ids,
     never of arrival order.  With ``accum_dtype=float32`` this is the
     paper's reproducible mode (F3): bitwise-identical across runs and
-    allocations.  Wire bytes: Z log2(P) per rank (latency-optimal; the
+    allocations.  log2 P ppermutes; wire bytes: Z log2(P) per rank (latency-optimal; the
     paper pays the same structural price — tree aggregation keeps
     (P-1)/log(P) buffers alive instead of 1).
     """
@@ -534,6 +536,8 @@ def allreduce_two_level(x: jax.Array, inner_axis: str, outer_axis: str, *,
     Wire traffic per rank: ~Z on the inner axis (vs ~2Z for a flat ring
     over all P ranks — the paper's 2x in-network traffic reduction shows up
     exactly here) plus Z/P_in * f(P_out) on the scarce inter-pod links.
+    With the default inner ring and outer rhd: 2(P_in − 1) + 2 log2 P_out
+    ppermutes.
     """
     p_in = lax.axis_size(inner_axis)
     xp, n = pad_to_multiple(x, p_in)
@@ -594,6 +598,7 @@ def hierarchical_allreduce(x: jax.Array, axes: tuple[str, ...], *,
 
     Per-level wire algorithms otherwise come from the level fan-in:
     power-of-two fan-ins take the log-depth rhd path, others the ring.
+    With power-of-two fan-ins that is 2 log2 P ppermutes per level.
     """
     sizes = tuple(lax.axis_size(a) for a in axes)
     levels = topology.mesh_levels(axes, sizes)
@@ -664,7 +669,8 @@ def hierarchical_allreduce_bucketed(arena: jax.Array, axes: tuple[str, ...],
 
 @jax.named_scope("flare.psum")
 def allreduce_psum(x: jax.Array, axes: str | tuple[str, ...]) -> jax.Array:
-    """XLA's native psum — the SHARP/fixed-function analogue."""
+    """XLA's native psum — the SHARP/fixed-function analogue: one
+    all-reduce."""
     return lax.psum(x, axes)
 
 

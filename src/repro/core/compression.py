@@ -193,11 +193,12 @@ def quantized_allreduce_batched(x: jax.Array, axis: str, *, block: int = 256,
 
     The batched form of :func:`quantized_allreduce`: ONE ``all_to_all``
     moves every bucket's int8 chunks (plus one for the scales) and ONE
-    ``all_gather`` pair brings the requantized sums back — O(1)
-    collectives per dtype group instead of the O(B) a per-bucket
-    ``lax.scan`` pays.  Per bucket the quantize → exchange → fp32
-    accumulate → requantize chain is exactly the flat form's, so results
-    are bitwise-equal to the scan.
+    ``all_gather`` pair brings the requantized sums back — two
+    ``all_to_all``s and two ``all_gather``s whatever B, O(1)
+    collectives per dtype group instead of the O(B) a per-bucket loop
+    pays.  Per bucket the quantize → exchange → fp32 accumulate →
+    requantize chain is exactly the flat form's, so results are
+    bitwise-equal to that loop.
     """
     red, n = quantized_reduce_scatter_batched(x, axis, block=block)
     if mean:

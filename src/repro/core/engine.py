@@ -1,36 +1,35 @@
 """The Flare gradient-reduction engine (the paper's technique, first-class).
 
 ``GradReducer`` is the composable entry point that training loops call on
-an *unreduced* gradient pytree inside a manual ``shard_map`` region.  It:
+an *unreduced* gradient pytree inside a manual ``shard_map`` region.  It
+has one way to reduce:
 
-  1. packs leaves into reduction blocks — by default through the
-     **flat-arena plan** (``core/arena.py``): one padded buffer per
-     dtype, equal-size buckets as a leading axis, per-leaf offsets
-     computed once per pytree structure,
-  2. per dtype group, selects a **transport** (``core/transports.py``):
+  1. it packs the leaves through the **flat-arena plan**
+     (``core/arena.py``): one padded buffer per dtype, equal-size
+     buckets as a leading axis, per-leaf offsets computed once per
+     pytree structure.  Each dtype group's buckets are padded to the
+     multiple its transport's schedule needs
+     (``Transport.pad_multiple``), so no collective re-pads at runtime;
+  2. per dtype group it selects a **transport** (``core/transports.py``):
      dense lossless (with the paper's §6.4 size switchover — tree <
-     128 KiB ≤ rhd < 512 KiB ≤ ring/two-level), int8 quantized (F1), or
-     §7 top-k sparse — the three-way dispatch lives in exactly one place,
-  3. reduces **all B buckets of a group in one batched schedule**: the
-     dense ring picks each round's chunks per stagger class (the other
-     dense algorithms vmap their rounds), the sparse path issues one
-     ppermute per recursive-doubling step carrying every bucket's
-     coordinate list, the int8 path moves the whole arena's payload in a
-     single all_to_all/all_gather pair — the paper's multi-buffer
-     aggregation (§6.2) applied to every transport, not just dense,
-  4. folds top-k + error feedback into the same trace, with the EF
+     128 KiB ≤ rhd < 512 KiB ≤ ring/two-level), int8 quantized (F1), §7
+     top-k sparse, or the emulated in-network switch — the dispatch lives
+     in exactly one place;
+  3. the transport reduces **all B buckets of a group in one batched
+     schedule**: the dense ring picks each round's chunks per stagger
+     class (the other dense algorithms vmap their rounds), the sparse
+     path issues one ppermute per recursive-doubling step carrying every
+     bucket's coordinate list, the int8 path moves the whole arena's
+     payload in a single all_to_all/all_gather pair — the paper's
+     multi-buffer aggregation (§6.2) applied to every transport;
+  4. top-k + error feedback fold into the same trace, with the EF
      residual computed by ``compression.error_feedback_step`` and ``k``
-     derived from each bucket's unpadded extent (``sparse.sparse_k``),
-  5. staggers concurrent blocks' ring phases (staggered sending, §5) via
-     a static per-bucket phase,
-  6. guarantees bitwise reproducibility when asked (F3: fixed-tree only,
-     fp32 accumulation) — the arena and legacy paths are bitwise-equal
-     there because the fixed tree combines elementwise.
-
-``FlareConfig(arena=False)`` keeps the per-bucket loop alive as the
-benchmark baseline (``benchmarks/collectives_bench.py`` measures both);
-it routes through the same transport objects as a loop over B=1 groups,
-so the wire math is shared and only the batching differs.
+     derived from each bucket's unpadded extent (``sparse.sparse_k``);
+  5. concurrent blocks' ring phases are staggered (staggered sending,
+     §5) by a static per-bucket phase;
+  6. the reduction is bitwise reproducible when asked (F3: fixed-tree
+     only, fp32 accumulation) — the fixed tree combines elementwise, so
+     its bits do not depend on how the plan lays the leaves out.
 
 Error-feedback state is functional: ``reduce(grads, state) -> (out,
 state)``; the trainer threads it through its optimizer state.
@@ -38,7 +37,6 @@ state)``; the trainer threads it through its optimizer state.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
@@ -47,8 +45,7 @@ from jax import lax
 
 from repro import compat
 from repro.core import arena as arena_mod
-from repro.core import bucketing, transports
-from repro.core import collectives as coll
+from repro.core import transports
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,7 +62,6 @@ class FlareConfig:
     bucket_bytes: int = 4 << 20
     stagger: bool = True                # §5 staggered sending
     mean: bool = False                  # divide by world size after reduce
-    arena: bool = True                  # flat-arena pipelined hot path
     #: flat vs hierarchical (tree-driven) wire schedule on multi-axis
     #: meshes.  None → the reduction tree decides from the mesh shape
     #: (``topology.transport_schedule``); True/False force it.
@@ -189,69 +185,28 @@ class GradReducer:
         return jax.tree.map(lambda g: jnp.zeros(g.shape, g.dtype), grads)
 
     # -- the reduction -------------------------------------------------------
-    def __call__(self, grads: Any, state: Any = None) -> tuple[Any, Any]:
-        if self.config.arena:
-            return self._reduce_arena(grads, state)
-        return self._reduce_legacy(grads, state)
-
-    def _world(self) -> int:
-        return lax.axis_size(tuple(self.config.axes))
-
-    def _transport(self, dtype, *, batched: bool):
+    def _transport(self, dtype) -> transports.Transport:
         """Group transport; dtype-suffixed tenant names under a manager
         (each dtype arena is its own wire image, hence its own session)."""
         tenant = self.tenant
         if self.manager is not None and tenant is not None:
             tenant = f"{tenant}/{jnp.dtype(dtype).name}"
-        return transports.from_config(self.config, dtype, batched=batched,
+        return transports.from_config(self.config, dtype,
                                       manager=self.manager, tenant=tenant)
 
-    def _pad_multiple(self, world: int) -> int:
-        """Chunk-divisibility folded into the arena plan.
-
-        ``2 · world`` covers ring (P), pipelined ring waves (2P), rhd (P)
-        and the two-level inner/outer split; with int8 transport the
-        quantization block rides along too, so every bucket chunk is a
-        whole number of quant blocks — no runtime pad anywhere.
-        """
-        pad = 2 * world
-        if self.config.compression == "int8":
-            pad = math.lcm(pad, world * transports.QUANT_BLOCK)
-        return pad
-
     def _plan(self, leaves) -> arena_mod.FlatArena:
-        """The arena plan, buckets a multiple of ``_pad_multiple``.
-
-        A dtype group that the dense transport reduces with the
-        stagger-class ring (``DenseTransport.class_ring`` at that
-        bucket size) pads its chunks to whole ``coll.CHUNK_ALIGN``
-        tiles, so the ring's (bucket, chunk) view of the arena needs no
-        relayout on a TPU — where that grows its buckets by at most
-        1/64; at many ranks a tile per rank may outweigh the bucket.
-        """
+        """The arena plan, each dtype group's buckets a multiple of what
+        its transport's schedule needs (``Transport.pad_multiple``, asked
+        at the group's unpadded bucket size)."""
         c = self.config
-        world = self._world()
-        pad = self._pad_multiple(world)
-        plan = arena_mod.build_plan(leaves, c.bucket_bytes,
-                                    pad_multiple=pad)
-        if c.transport != "auto":
-            return plan
-        tile = math.lcm(pad, world * coll.CHUNK_ALIGN)
-        aligned = []
-        for g in plan.groups:
-            t = self._transport(g.dtype, batched=True)
-            s = g.bucket_elems
-            if (isinstance(t, transports.DenseTransport)
-                    and t.class_ring(s * g.dtype.itemsize)
-                    and -s % tile <= s // 64):
-                aligned.append((g.dtype.name, tile))
-        if not aligned:
-            return plan
-        return arena_mod.build_plan(leaves, c.bucket_bytes, pad_multiple=pad,
-                                    group_pads=aligned)
+        world = lax.axis_size(tuple(c.axes))
+        unpadded = arena_mod.build_plan(leaves, c.bucket_bytes)
+        pads = [(g.dtype.name, self._transport(g.dtype).pad_multiple(
+                    world, g.bucket_elems, g.dtype.itemsize))
+                for g in unpadded.groups]
+        return arena_mod.build_plan(leaves, c.bucket_bytes, group_pads=pads)
 
-    # -- flat-arena pipelined path (the hot path) ----------------------------
-    def _reduce_arena(self, grads: Any, state: Any) -> tuple[Any, Any]:
+    def __call__(self, grads: Any, state: Any = None) -> tuple[Any, Any]:
         c = self.config
         leaves, treedef = jax.tree.flatten(grads)
         ef_leaves = (jax.tree.flatten(state)[0] if state is not None
@@ -264,7 +219,7 @@ class GradReducer:
             with jax.named_scope("flare.pack"):
                 buf = g.pack(leaves)
                 ef_buf = g.pack(ef_leaves) if ef_leaves is not None else None
-            transport = self._transport(g.dtype, batched=True)
+            transport = self._transport(g.dtype)
             red, ef_red = transport(buf, ef_buf, g.staggers(c.stagger),
                                     g.valid_extents)
             red_groups.append(red)
@@ -280,38 +235,3 @@ class GradReducer:
                 [e if e is not None else jnp.zeros_like(r)
                  for e, r in zip(ef_out_groups, red_groups)])
         return out, jax.tree.unflatten(treedef, ef_flat)
-
-    # -- per-bucket loop (benchmark baseline) --------------------------------
-    def _reduce_legacy(self, grads: Any, state: Any) -> tuple[Any, Any]:
-        """The seed dispatch loop, now a loop over B=1 transport groups."""
-        c = self.config
-        leaves, treedef = jax.tree.flatten(grads)
-        ef_leaves = (jax.tree.flatten(state)[0] if state is not None
-                     else [None] * len(leaves))
-        buckets = bucketing.build_buckets(leaves, c.bucket_bytes, c.stagger)
-
-        out_leaves: list[jax.Array | None] = [None] * len(leaves)
-        new_ef: list[jax.Array | None] = [None] * len(leaves)
-
-        for b in buckets:
-            with jax.named_scope("flare.pack"):
-                flat = bucketing.pack_bucket(leaves, b)
-                ef_flat = (bucketing.pack_bucket(ef_leaves, b)
-                           if self.needs_state else None)
-            transport = self._transport(flat.dtype, batched=False)
-            stagger = b.stagger if c.stagger else 0
-            red, ef_out = transport(
-                flat[None], ef_flat[None] if ef_flat is not None else None,
-                jnp.full((1,), stagger, jnp.int32), (b.num_elements,))
-            with jax.named_scope("flare.unpack"):
-                for i, piece in bucketing.unpack_bucket(red[0], leaves, b):
-                    out_leaves[i] = piece
-                if ef_out is not None:
-                    for i, piece in bucketing.unpack_bucket(ef_out[0], leaves,
-                                                            b):
-                        new_ef[i] = piece
-
-        out = jax.tree.unflatten(treedef, out_leaves)
-        state_out = (jax.tree.unflatten(treedef, new_ef)
-                     if self.needs_state else None)
-        return out, state_out

@@ -40,11 +40,9 @@ SENTINEL = jnp.iinfo(jnp.int32).max
 def sparse_k(frac: float, extent: int) -> int:
     """The single source of truth for top-k sizing.
 
-    ``k`` derives from the **unpadded** extent of a reduction block and is
-    clamped to ``[1, extent]`` — the legacy engine used to skip the upper
-    clamp (crashing in ``topk_sparsify`` for ``frac >= 1``) while the
-    arena engine computed it from the padded arena size (inflating it);
-    both now call this.
+    ``k`` derives from the **unpadded** extent of a reduction block (the
+    padded size would inflate it) and is clamped to ``[1, extent]``, so
+    ``frac >= 1`` keeps every element.
     """
     return max(1, min(int(extent), int(frac * extent)))
 
@@ -242,11 +240,12 @@ def sparse_allreduce_batched(x: jax.Array, axis: str,
 
     The batched form of :func:`sparse_allreduce`: every recursive-doubling
     step issues **one** ppermute carrying all B buckets' coordinate lists
-    (the sort + cumsum-scatter merge vmaps cleanly over the bucket axis),
-    so a dtype group costs O(log P) collectives instead of the
-    O(B log P) a per-bucket ``lax.scan`` pays.  Per bucket the combine
-    chain — topk, merge order, densify crossover — is exactly the
-    single-bucket schedule's, so results are bitwise-equal to the scan.
+    (the sort + cumsum-scatter merge vmaps cleanly over the bucket axis;
+    two for sub-32-bit values), so a dtype group costs log2 P
+    collectives instead of the
+    O(B log P) a per-bucket loop pays.  Per bucket the combine chain —
+    topk, merge order, densify crossover — is exactly the single-bucket
+    schedule's, so results are bitwise-equal to that loop.
 
     ``ks`` gives each bucket its own k (derived from its unpadded
     extent); the static list capacity is ``max(ks)`` and smaller buckets
@@ -322,7 +321,8 @@ def sparse_allreduce_two_level_batched(x: jax.Array, inner_axis: str,
 
     Sparse batched schedule within the pod, then a vmapped dense rhd
     across pods — each outer exchange round carries all B buckets' dense
-    vectors in one batched ppermute.
+    vectors in one batched ppermute: log2 P_in + 2 log2 P_out ppermutes
+    for power-of-two axes.
     """
     reduced, mine = sparse_allreduce_batched(
         x, inner_axis, ks, density_threshold=density_threshold)

@@ -1,13 +1,11 @@
 """The unified transport layer: one batched wire schedule per dtype arena.
 
-``GradReducer`` used to re-implement the sparse / int8 / dense three-way
-dispatch twice (once for the flat-arena path, once for the legacy
-per-bucket loop), and the sparse and int8 branches serialized the arena's
-B buckets under a ``lax.scan`` — exactly the workloads the paper argues
-benefit most from flexible aggregation (§7 sparse, F1 custom dtypes).
-This module is the single home of that dispatch: a ``Transport`` reduces
-a whole ``(B, S)`` dtype arena in one traced computation, with top-k +
-error-feedback folded into the same trace.
+This module is the single home of the dense / int8 / sparse / in-network
+dispatch: a ``Transport`` reduces a whole ``(B, S)`` dtype arena in one
+traced computation, with top-k + error-feedback folded into the same
+trace, and tells the arena plan how to pad that arena
+(``Transport.pad_multiple``): the pad is a property of the wire
+schedule, so no caller needs to know a schedule's chunking.
 
 =============  ============================================================
 transport       batched wire schedule (per dtype group)
@@ -24,18 +22,17 @@ transport       batched wire schedule (per dtype group)
                 coordinate lists — O(log P) collectives per group.
 =============  ============================================================
 
-Every transport also keeps its per-bucket ``lax.scan`` ancestor alive
-behind ``batched=False`` — the bitwise-equality oracle for tests and the
-scan-vs-batched baseline for ``benchmarks/run.py --quick``; per bucket
-the combine chains are identical, so ``batched`` never changes results,
-only how many collectives carry them.
+Each wire transport has exactly one schedule.  Per bucket its combine
+chain is that of the single-vector schedule in ``core/collectives.py``,
+``core/compression.py`` or ``core/sparse.py``, which the tests run bucket
+by bucket as the bitwise reference; batching changes only how many
+collectives carry the buckets.
 
 Error feedback lives in exactly one place: every lossy transport routes
 through ``compression.error_feedback_step`` with its own ``transmit``
 closure (sparse returns the decoded top-k contribution, int8 the
 quantize round-trip), and ``k`` for the sparse transport derives from
-each bucket's **unpadded** extent via ``sparse.sparse_k`` — shared with
-the legacy path, which is now just a B=1 loop over these same objects.
+each bucket's **unpadded** extent via ``sparse.sparse_k``.
 
 On multi-axis meshes every transport additionally picks a **flat vs
 hierarchical** wire schedule (DESIGN.md §11): the mesh's reduction tree
@@ -50,6 +47,7 @@ lists, not dense vectors.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Sequence
 
 import jax
@@ -58,9 +56,9 @@ from jax import lax
 
 from repro.core import collectives as coll, compression, sparse, topology
 
-#: Quantization block of the int8 transport; ``GradReducer`` folds
-#: ``world * QUANT_BLOCK`` into the arena plan's pad multiple so every
-#: bucket chunk is a whole number of quantization blocks (no runtime pad).
+#: Quantization block of the int8 transport; its ``pad_multiple`` folds
+#: ``world * QUANT_BLOCK`` into the arena plan so every bucket chunk is a
+#: whole number of quantization blocks (no runtime pad).
 QUANT_BLOCK = 256
 
 
@@ -84,7 +82,6 @@ class Transport:
 
     axes: tuple[str, ...]
     mean: bool = False
-    batched: bool = True    # False → the per-bucket lax.scan ancestor
     #: flat vs hierarchical wire schedule.  ``None`` → the reduction
     #: tree decides (``topology.transport_schedule`` on the trace-time
     #: mesh tree); True/False force it (``FlareConfig.hierarchical``).
@@ -104,6 +101,16 @@ class Transport:
 
     def _world(self) -> int:
         return lax.axis_size(tuple(self.axes))
+
+    def pad_multiple(self, world: int, bucket_elems: int,
+                     itemsize: int) -> int:
+        """The multiple the arena plan pads this transport's buckets to,
+        for buckets of ``bucket_elems`` unpadded ``itemsize``-byte
+        elements over ``world`` ranks.
+
+        ``2 · world`` covers ring (P), rhd (P) and the two-level
+        inner/outer split, so no schedule re-pads at runtime."""
+        return 2 * world
 
     def _use_hierarchy(self) -> bool:
         """Resolve flat vs hierarchical at trace time, tree as arbiter."""
@@ -146,27 +153,37 @@ class DenseTransport(Transport):
                                         multi_level=len(self.axes) > 1)
         return alg
 
-    def class_ring(self, nbytes: int) -> bool:
-        """Whether a ``(B, S)`` arena of ``nbytes`` per bucket takes the
-        batched stagger-class ring: the ring, asked for or what ``auto``
-        picks at that size, on one axis of more than one rank.
+    def class_ring(self, nbytes: int, world: int) -> bool:
+        """Whether a ``(B, S)`` arena of ``nbytes`` per bucket over
+        ``world`` ranks takes the batched stagger-class ring: the ring,
+        asked for or what ``auto`` picks at that size, on one axis of
+        more than one rank.
 
         The one place that decides it: the call routes on it, and
-        ``GradReducer`` pads the chunks of such arenas to whole tiles.
+        ``pad_multiple`` pads the chunks of such arenas to whole tiles.
         Padding only grows S, and the ring's size range has no upper
         end, so the padded arena still takes the ring."""
-        return (self.batched and not self.reproducible
-                and len(self.axes) == 1 and lax.axis_size(self.axes[0]) > 1
-                and self._resolve(nbytes) == "ring")
+        return (not self.reproducible and len(self.axes) == 1
+                and world > 1 and self._resolve(nbytes) == "ring")
+
+    def pad_multiple(self, world, bucket_elems, itemsize):
+        """``2 · world``; where the stagger-class ring runs, chunks of
+        whole ``coll.CHUNK_ALIGN`` tiles, so the ring's (bucket, chunk)
+        view of the arena needs no relayout on a TPU — where that grows
+        the bucket by at most 1/64; at many ranks a tile per rank may
+        outweigh the bucket."""
+        pad = super().pad_multiple(world, bucket_elems, itemsize)
+        s = -(-bucket_elems // pad) * pad
+        tile = math.lcm(pad, world * coll.CHUNK_ALIGN)
+        if self.class_ring(s * itemsize, world) and -s % tile <= s // 64:
+            return tile
+        return pad
 
     def __call__(self, buf, ef, staggers, extents):
         nbytes = buf.shape[1] * jnp.dtype(buf.dtype).itemsize
         alg = self._resolve(nbytes)
-        one = lambda v, s: coll.allreduce(
-            v, self.axes, algorithm=alg, reproducible=self.reproducible,
-            stagger=s)
-        sig = (coll.static_staggers(staggers) if self.class_ring(nbytes)
-               else None)
+        sig = (coll.static_staggers(staggers)
+               if self.class_ring(nbytes, self._world()) else None)
         if sig is not None:
             # one ppermute per round and ring direction for all B
             # buckets, chunks picked and written once per stagger class
@@ -182,16 +199,14 @@ class DenseTransport(Transport):
                     len(sig) if both else 0)
                 reg.counter("wire.ring.stagger_classes").inc(
                     len({s % p for s in sig}))
-        elif self.batched:
+        else:
             # all B buckets in one vmapped schedule: every collective
             # round carries the whole arena's worth of payload in one
             # batched ppermute/exchange (§6.2 multi-buffer parallelism).
-            # Per bucket the combine chain is unchanged, so this is
-            # bitwise-equal to the scan for every algorithm.
-            red = jax.vmap(one)(buf, staggers)
-        else:
-            _, red = lax.scan(lambda _, xs: (None, one(*xs)), None,
-                              (buf, staggers))
+            # Per bucket the combine chain is the single-vector one.
+            red = jax.vmap(lambda v, s: coll.allreduce(
+                v, self.axes, algorithm=alg, reproducible=self.reproducible,
+                stagger=s))(buf, staggers)
         if self.mean:
             red = red / self._world()
         return red, (jnp.zeros_like(ef) if ef is not None else None)
@@ -199,13 +214,19 @@ class DenseTransport(Transport):
 
 @dataclasses.dataclass(frozen=True)
 class Int8Transport(Transport):
-    """F1 int8 transport: quantized exchange with error feedback."""
+    """F1 int8 transport: quantized exchange with error feedback — per
+    axis leg two ``all_to_all``s and two ``all_gather``s (payload and
+    scales) for all B buckets."""
 
     block: int = QUANT_BLOCK
 
     @property
     def needs_state(self) -> bool:
         return True
+
+    def pad_multiple(self, world, bucket_elems, itemsize):
+        """Every bucket chunk a whole number of quantization blocks."""
+        return math.lcm(2 * world, world * self.block)
 
     @jax.named_scope("flare.int8")
     def __call__(self, buf, ef, staggers, extents):
@@ -217,39 +238,19 @@ class Int8Transport(Transport):
         # outer axes go innermost-first (mesh order is outermost-first)
         up_axes = tuple(reversed(outer_axes))
 
-        if self.batched:
-            def transmit(v):            # v: (B, S)
-                if hier:
-                    red = compression.quantized_allreduce_hier_batched(
-                        v, inner, up_axes, block=self.block)
-                else:
+        def transmit(v):                # v: (B, S)
+            if hier:
+                red = compression.quantized_allreduce_hier_batched(
+                    v, inner, up_axes, block=self.block)
+            else:
+                red = compression.quantized_allreduce_batched(
+                    v, inner, block=self.block)
+                for ax in outer_axes:
                     red = compression.quantized_allreduce_batched(
-                        v, inner, block=self.block)
-                    for ax in outer_axes:
-                        red = compression.quantized_allreduce_batched(
-                            red, ax, block=self.block)
-                return red, compression.quantize_roundtrip(v, self.block)
+                        red, ax, block=self.block)
+            return red, compression.quantize_roundtrip(v, self.block)
 
-            red, ef_out = compression.error_feedback_step(buf, ef, transmit)
-        else:
-            def body(_, xs):
-                v, e, _s = xs
-
-                def transmit(w):        # w: (S,)
-                    if hier:
-                        red = compression.quantized_allreduce_hier(
-                            w, inner, up_axes, block=self.block)
-                    else:
-                        red = compression.quantized_allreduce(
-                            w, inner, block=self.block)
-                        for ax in outer_axes:
-                            red = compression.quantized_allreduce(
-                                red, ax, block=self.block)
-                    return red, compression.quantize_roundtrip(w, self.block)
-
-                return None, compression.error_feedback_step(v, e, transmit)
-
-            _, (red, ef_out) = lax.scan(body, None, (buf, ef, staggers))
+        red, ef_out = compression.error_feedback_step(buf, ef, transmit)
         if self.mean:
             red = red / self._world()
         return red, ef_out
@@ -298,47 +299,20 @@ class SparseTransport(Transport):
                     f"outer axes; mesh axes {bad!r} are not")
             hier = not bad
 
-        if self.batched:
-            def transmit(v):            # v: (B, S)
-                if hier:
-                    # lists stay sparse across the inter-pod hop
-                    return sparse.sparse_allreduce_hier_batched(
-                        v, inner, up_axes, ks,
-                        density_threshold=self.density_threshold)
-                if outer_axes:
-                    return sparse.sparse_allreduce_two_level_batched(
-                        v, inner, outer_axes[-1], ks,
-                        density_threshold=self.density_threshold)
-                return sparse.sparse_allreduce_batched(
-                    v, inner, ks, density_threshold=self.density_threshold)
+        def transmit(v):                # v: (B, S)
+            if hier:
+                # lists stay sparse across the inter-pod hop
+                return sparse.sparse_allreduce_hier_batched(
+                    v, inner, up_axes, ks,
+                    density_threshold=self.density_threshold)
+            if outer_axes:
+                return sparse.sparse_allreduce_two_level_batched(
+                    v, inner, outer_axes[-1], ks,
+                    density_threshold=self.density_threshold)
+            return sparse.sparse_allreduce_batched(
+                v, inner, ks, density_threshold=self.density_threshold)
 
-            red, ef_out = compression.error_feedback_step(buf, ef, transmit)
-        else:
-            k_max = max(ks)
-            ks_arr = jnp.asarray(ks, jnp.int32)
-
-            def body(_, xs):
-                v, e, _s, ke = xs
-
-                def transmit(w):        # w: (S,)
-                    if hier:
-                        return sparse.sparse_allreduce_hier(
-                            w, inner, up_axes, k_max,
-                            density_threshold=self.density_threshold,
-                            k_eff=ke)
-                    if outer_axes:
-                        return sparse.sparse_allreduce_two_level(
-                            w, inner, outer_axes[-1], k_max,
-                            density_threshold=self.density_threshold,
-                            k_eff=ke)
-                    return sparse.sparse_allreduce(
-                        w, inner, k_max,
-                        density_threshold=self.density_threshold, k_eff=ke)
-
-                return None, compression.error_feedback_step(v, e, transmit)
-
-            _, (red, ef_out) = lax.scan(body, None, (buf, ef, staggers,
-                                                     ks_arr))
+        red, ef_out = compression.error_feedback_step(buf, ef, transmit)
         if self.mean:
             red = red / self._world()
         return red, ef_out
@@ -362,14 +336,15 @@ class SwitchTransport(Transport):
 
     The schedule is inherently tree-driven — the ``hierarchical`` knob
     of the wire transports doesn't apply (packets carry their block id,
-    so B buckets always share the wire).  ``batched`` (inherited from
-    :class:`Transport`, default True) picks the data-plane schedule:
-    the batched plane runs each tree level as a few collectives +
-    slot-axis kernels over the packed packet tensor, ``batched=False``
-    keeps the per-slot/per-hop loop as the bitwise oracle — the two are
-    cross-checked bit for bit in the multidevice ``switch`` group.
+    so B buckets always share the wire).  ``batched`` (default True)
+    picks the data-plane schedule: the batched plane runs each tree
+    level as a few collectives + slot-axis kernels over the packed
+    packet tensor, ``batched=False`` keeps the per-slot/per-hop loop as
+    the bitwise oracle — the two are cross-checked bit for bit in the
+    multidevice ``switch`` group.
     """
 
+    batched: bool = True
     mode: str = "dense"             # dense | int8 | sparse
     reproducible: bool = False
     design: str = "auto"            # §6.1-§6.3 buffer design, auto = §6.4
@@ -398,6 +373,13 @@ class SwitchTransport(Transport):
     @property
     def needs_state(self) -> bool:
         return self.mode in ("int8", "sparse")
+
+    def pad_multiple(self, world, bucket_elems, itemsize):
+        """The int8 handler's chunks a whole number of quantization
+        blocks, as ``Int8Transport``'s."""
+        if self.mode == "int8":
+            return math.lcm(2 * world, world * self.block)
+        return super().pad_multiple(world, bucket_elems, itemsize)
 
     def _session_perms(self, buf, k: int | None = None):
         """Attach to the shared switch; returns this tenant's per-level
@@ -464,13 +446,12 @@ class SwitchTransport(Transport):
         if self.manager is not None:
             ft.recover_session_failure(self.manager, self.tenant)
         if self.mode == "sparse":
-            return SparseTransport(self.axes, mean=self.mean, batched=True,
+            return SparseTransport(self.axes, mean=self.mean,
                                    k_frac=self.k_frac,
                                    density_threshold=self.density_threshold)
         if self.mode == "int8":
-            return Int8Transport(self.axes, mean=self.mean, batched=True,
-                                 block=self.block)
-        return DenseTransport(self.axes, mean=self.mean, batched=True,
+            return Int8Transport(self.axes, mean=self.mean, block=self.block)
+        return DenseTransport(self.axes, mean=self.mean,
                               reproducible=self.reproducible)
 
     def __call__(self, buf, ef, staggers, extents):
@@ -524,13 +505,12 @@ class SwitchTransport(Transport):
 
 
 def _switch_from_config(config, dtype, is_float: bool, *,
-                        batched: bool = True,
                         manager=None, tenant=None,
                         telemetry=None) -> SwitchTransport:
     axes = tuple(config.axes)
     fault_plan = getattr(config, "fault_plan", None)
     if config.sparse_k_frac > 0 and is_float:
-        return SwitchTransport(axes, mean=config.mean, batched=batched,
+        return SwitchTransport(axes, mean=config.mean,
                                telemetry=telemetry,
                                mode="sparse",
                                k_frac=config.sparse_k_frac,
@@ -538,12 +518,12 @@ def _switch_from_config(config, dtype, is_float: bool, *,
                                manager=manager, tenant=tenant,
                                fault_plan=fault_plan)
     if config.compression == "int8" and is_float:
-        return SwitchTransport(axes, mean=config.mean, batched=batched,
+        return SwitchTransport(axes, mean=config.mean,
                                telemetry=telemetry,
                                mode="int8",
                                manager=manager, tenant=tenant,
                                fault_plan=fault_plan)
-    return SwitchTransport(axes, mean=config.mean, batched=batched,
+    return SwitchTransport(axes, mean=config.mean,
                            telemetry=telemetry,
                            mode="dense",
                            reproducible=config.reproducible,
@@ -551,8 +531,8 @@ def _switch_from_config(config, dtype, is_float: bool, *,
                            fault_plan=fault_plan)
 
 
-def from_config(config, dtype, *, batched: bool = True,
-                manager=None, tenant: str | None = None) -> Transport:
+def from_config(config, dtype, *, manager=None,
+                tenant: str | None = None) -> Transport:
     """The transport dispatch, in one place.
 
     ``config`` is any object with the ``FlareConfig`` transport fields
@@ -574,7 +554,7 @@ def from_config(config, dtype, *, batched: bool = True,
     telemetry = getattr(config, "telemetry", None)
     is_float = jnp.issubdtype(jnp.dtype(dtype), jnp.floating)
     if getattr(config, "transport", "auto") == "innetwork":
-        return _switch_from_config(config, dtype, is_float, batched=batched,
+        return _switch_from_config(config, dtype, is_float,
                                    manager=manager, tenant=tenant,
                                    telemetry=telemetry)
     if manager is not None:
@@ -583,15 +563,15 @@ def from_config(config, dtype, *, batched: bool = True,
             f"only; config has "
             f"transport={getattr(config, 'transport', 'auto')!r}")
     if config.sparse_k_frac > 0 and is_float:
-        return SparseTransport(axes, mean=config.mean, batched=batched,
+        return SparseTransport(axes, mean=config.mean,
                                hierarchical=hierarchical,
                                telemetry=telemetry,
                                k_frac=config.sparse_k_frac,
                                density_threshold=config.density_threshold)
     if config.compression == "int8" and is_float:
-        return Int8Transport(axes, mean=config.mean, batched=batched,
+        return Int8Transport(axes, mean=config.mean,
                              hierarchical=hierarchical, telemetry=telemetry)
-    return DenseTransport(axes, mean=config.mean, batched=batched,
+    return DenseTransport(axes, mean=config.mean,
                           hierarchical=hierarchical, telemetry=telemetry,
                           algorithm=config.algorithm,
                           reproducible=config.reproducible)

@@ -22,13 +22,12 @@ FAKE_2D = (2, 4)
 
 
 def make_fake_mesh(shape=FAKE_2D, axes: tuple[str, ...] | None = None):
-    """A (pod, data) mesh over fake CPU devices for tests/benchmarks.
+    """A (pod, data) mesh over fake CPU devices for the tests.
 
     ``shape`` is ``(pod, data)`` (append a trailing model axis by
     passing 3 entries + explicit ``axes``).  The caller's process must
     run under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    with ``N >= prod(shape)`` — the multidevice checks and the
-    collective benchmarks both do.
+    with ``N >= prod(shape)``, as the multidevice checks do.
     """
     if axes is None:
         axes = ("pod", "data") if len(shape) == 2 else \

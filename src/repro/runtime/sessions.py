@@ -72,7 +72,7 @@ class ReplanResult:
     ``predicted_after`` are per-tenant predicted throughputs
     (pkts/cycle, analytic shared mode) under the observed congestion
     map on the old and candidate trees — what the hysteresis decision
-    was made from, and what benchmarks gate on.
+    was made from, and what the tests hold a replan to (never worse).
     """
 
     replanned: bool

@@ -6,9 +6,9 @@ Invoked by tests/test_collectives.py as::
         python tests/multidevice_checks.py <group>
 
 Groups: collectives | arena_pipeline | sparse_quant | fsdp_engine |
-        trainer | repro | transports | hierarchy | switch | runtime |
-        sparse_densify | chaos | canary | obs | health | scopes |
-        ring_classes (4 devices)
+        trainer | repro | transports | transport_counts | hierarchy |
+        switch | runtime | sparse_densify | chaos | canary | obs |
+        health | scopes | ring_classes (4 devices)
 Exits non-zero on any failure (assertion output on stderr).
 
 The ``hierarchy``, ``switch``, ``runtime``, ``sparse_densify``,
@@ -30,11 +30,12 @@ if __name__ == "__main__":
 import jax                                                     # noqa: E402
 import jax.numpy as jnp                                        # noqa: E402
 import numpy as np                                             # noqa: E402
+from jax import lax                                            # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P     # noqa: E402
 
 from repro.core import collectives as coll                     # noqa: E402
 from repro.core import compression, fsdp, reproducible, sparse  # noqa: E402
-from repro.core import transports                              # noqa: E402
+from repro.core import topology, transports                    # noqa: E402
 from repro.core.engine import FlareConfig, GradReducer         # noqa: E402
 from repro.launch import mesh as launch_mesh                   # noqa: E402
 
@@ -62,6 +63,63 @@ def _run(fn, xs, mesh, out_spec=P(None)):
     with jax.set_mesh(mesh):
         x = jax.device_put(xs, NamedSharding(mesh, P(("pod", "data"), None)))
         return np.asarray(g(x))
+
+
+def per_bucket_reduce(cfg, buf, ef, staggers, extents):
+    """``cfg``'s wire transport as a ``lax.scan`` over the ``(B, S)``
+    arena's buckets, each through the single-vector schedule and
+    ``compression.error_feedback_step``: the bitwise reference of the
+    batched schedules, whose per-bucket combine chains are these.
+
+    Returns ``(reduced, ef_out)``; the dense schedules' ``ef_out`` is
+    zeros."""
+    *outer, inner = cfg.axes
+    up = tuple(reversed(outer))         # upper tree levels, leaf-first
+    hier = bool(outer) and (
+        cfg.hierarchical if cfg.hierarchical is not None
+        else topology.transport_schedule(topology.build_mesh_tree(
+            tuple(lax.axis_size(a) for a in cfg.axes))) == "hierarchical")
+    thr = cfg.density_threshold
+    if cfg.sparse_k_frac > 0:
+        ks = [sparse.sparse_k(cfg.sparse_k_frac, e) for e in extents]
+        k = max(ks)
+
+        def body(_, xs):
+            v, e, ke = xs
+
+            def transmit(w):
+                if hier:
+                    return sparse.sparse_allreduce_hier(
+                        w, inner, up, k, density_threshold=thr, k_eff=ke)
+                if outer:
+                    return sparse.sparse_allreduce_two_level(
+                        w, inner, outer[-1], k, density_threshold=thr,
+                        k_eff=ke)
+                return sparse.sparse_allreduce(
+                    w, inner, k, density_threshold=thr, k_eff=ke)
+            return None, compression.error_feedback_step(v, e, transmit)
+        return lax.scan(body, None,
+                        (buf, ef, jnp.asarray(ks, jnp.int32)))[1]
+    if cfg.compression == "int8":
+        def transmit(w):
+            if hier:
+                red = compression.quantized_allreduce_hier(w, inner, up)
+            else:
+                red = compression.quantized_allreduce(w, inner)
+                for ax in outer:
+                    red = compression.quantized_allreduce(red, ax)
+            return red, compression.quantize_roundtrip(w)
+        return lax.scan(lambda _, xs: (None, compression.error_feedback_step(
+            *xs, transmit)), None, (buf, ef))[1]
+    alg = cfg.algorithm
+    if alg == "auto":
+        alg = "hierarchical" if hier else coll.select_algorithm(
+            buf.shape[1] * buf.dtype.itemsize,
+            reproducible=cfg.reproducible, multi_level=bool(outer))
+    _, red = lax.scan(lambda _, xs: (None, coll.allreduce(
+        xs[0], cfg.axes, algorithm=alg, reproducible=cfg.reproducible,
+        stagger=xs[1])), None, (buf, jnp.asarray(staggers, jnp.int32)))
+    return red, jnp.zeros_like(ef)
 
 
 def check_collectives():
@@ -117,8 +175,9 @@ def check_arena_pipeline():
     Bitwise claims verified here:
       * ``ring_allreduce_bucketed``  ≡ per-bucket ``allreduce_ring`` with
         the same staggers (the §6.2 fused waves reorder rounds only);
-      * arena ``GradReducer`` ≡ legacy per-bucket loop in reproducible
-        fixed-tree mode (F3 — elementwise combine, layout-independent).
+      * ``GradReducer`` in reproducible fixed-tree mode ≡ the
+        single-vector fixed tree over the whole flat gradient (F3 —
+        elementwise combine, layout-independent).
 
     (``allreduce_ring_pipelined`` was retired in PR 6 — it measured
     slower than the plain ring it claimed to pipeline; the bucketed
@@ -144,7 +203,7 @@ def check_arena_pipeline():
     b = _run(loop, xs, mesh)
     assert a.tobytes() == b.tobytes(), "bucketed waves vs per-bucket loop"
 
-    # GradReducer: arena path vs legacy loop
+    # GradReducer on three ragged leaves
     def reduce_with(x, **kw):
         g = {"a": x[0][:192].reshape(2, 96), "b": x[0][192:250],
              "c": x[0][250:]}
@@ -153,19 +212,18 @@ def check_arena_pipeline():
         red, _ = r(g, r.init_state(g))
         return jnp.concatenate([red["a"].reshape(-1), red["b"], red["c"]])
 
-    # reproducible fixed-tree: bitwise-identical across the two packings
+    # reproducible fixed-tree: bitwise-identical to the unbucketed vector
     a = _run(lambda x: reduce_with(x, reproducible=True,
-                                   algorithm="fixed_tree", arena=True),
-             xs, mesh)
-    b = _run(lambda x: reduce_with(x, reproducible=True,
-                                   algorithm="fixed_tree", arena=False),
-             xs, mesh)
-    assert a.tobytes() == b.tobytes(), "arena vs legacy fixed_tree bitwise"
+                                   algorithm="fixed_tree"), xs, mesh)
+    b = _run(lambda x: coll.allreduce(x[0], ("pod", "data"),
+                                      algorithm="fixed_tree",
+                                      reproducible=True), xs, mesh)
+    assert a.tobytes() == b.tobytes(), "arena vs flat fixed_tree bitwise"
 
     # every dense algorithm: arena path matches the fp64 oracle
     for alg in ("ring", "rhd", "fixed_tree",
                 "two_level", "auto"):
-        got = _run(lambda x, a=alg: reduce_with(x, algorithm=a, arena=True),
+        got = _run(lambda x, a=alg: reduce_with(x, algorithm=a),
                    xs, mesh)
         assert np.allclose(got, expect, rtol=1e-5,
                            atol=1e-2), f"arena engine {alg}"
@@ -311,8 +369,9 @@ def check_ring_classes():
     multiple of P and not, a nonzero stagger base, staggers off, and a
     max operator, and refuses staggers of no period;
     ``GradReducer`` pads chunks to whole tiles only where that ring
-    runs; ``DenseTransport(batched=True)`` ≡ ``batched=False`` for the
-    ring; the batched ring's jaxpr holds no gather or scatter, exactly
+    runs; ``DenseTransport``'s ring ≡ the per-bucket ring under a scan
+    (``per_bucket_reduce``); the batched ring's jaxpr holds no gather or
+    scatter, exactly
     2(P-1) ppermutes, while the telemetry counters read the buckets and
     classes it took; and a six-bucket ``GradReducer`` on one axis is
     bitwise-equal to the per-bucket ring.  Four devices."""
@@ -403,8 +462,9 @@ def check_ring_classes():
                 name, g.num_buckets, g.bucket_elems)
         print(f"ring_classes {name} OK")
 
-    # DenseTransport: batched (stagger classes) ≡ the per-bucket scan,
-    # with the plan's own static staggers; the counters show which ran
+    # DenseTransport: batched (stagger classes) ≡ the per-bucket ring
+    # under a scan, with the plan's own static staggers; the counters
+    # show which ran
     p = 4
     leaves = [jax.ShapeDtypeStruct((7, 300), jnp.float32),
               jax.ShapeDtypeStruct((900,), jnp.float32)]
@@ -416,13 +476,13 @@ def check_ring_classes():
                      .astype(np.float32))
     tel = Telemetry.create()
 
-    def dense(batched, telemetry=None):
-        t = transports.DenseTransport(("data",), batched=batched,
-                                      algorithm="ring", telemetry=telemetry)
-        return lambda a: t(a, None, g.staggers(True), g.valid_extents)[0]
-
-    got = _run_flat(dense(True, tel), xs, p)
-    want = _run_flat(dense(False), xs, p)
+    t = transports.DenseTransport(("data",), algorithm="ring",
+                                  telemetry=tel)
+    got = _run_flat(lambda a: t(a, None, g.staggers(True),
+                                g.valid_extents)[0], xs, p)
+    want = _run_flat(lambda a: per_bucket_reduce(
+        FlareConfig(algorithm="ring"), a, jnp.zeros_like(a),
+        g.staggers(True), g.valid_extents)[0], xs, p)
     assert got.tobytes() == want.tobytes(), "dense ring batched != scan"
     assert np.allclose(got[0], np.asarray(xs).sum(0), rtol=1e-5, atol=1e-4)
     reg = tel.registry
@@ -545,19 +605,15 @@ def check_transports():
 
     Verified here:
       * the batched sparse and int8 schedules are **bitwise-equal** to
-        their per-bucket ``lax.scan`` ancestors (``batched=False``) —
-        the per-bucket combine chains are identical, batching only
-        changes how many collectives carry them;
-      * HLO op counts: the batched sparse transport issues O(log P)
-        ``collective-permute``s and the batched int8 transport O(1)
-        ``all-to-all``/``all-gather``s per dtype group, *independent of
-        B* (doubling B leaves the collective count unchanged);
+        the single-vector schedules run bucket by bucket under a scan
+        (``per_bucket_reduce``) — the per-bucket combine chains are
+        identical, batching only changes how many collectives carry
+        them (their collective counts: group ``transport_counts``);
       * ``GradReducer`` arena sparse/int8 end-to-end vs a numpy oracle
         with a ragged tail bucket (k from unpadded extents);
       * sparse preconditions raise at ``GradReducer`` construction on a
         non-power-of-two inner axis.
     """
-    import re
     from jax.sharding import Mesh
 
     mesh = _mesh()
@@ -565,54 +621,36 @@ def check_transports():
     B, S = 4, 64
     xs = jnp.asarray(rng.normal(size=(4, B * S)).astype(np.float32))
     extents = (S, S, S, 40)              # ragged tail bucket
+    staggers = np.arange(B, dtype=np.int32)
 
-    def transport_fn(cfg, batched, b=B, s=S, ext=extents):
+    def transport_fn(cfg):
         def fn(x):
-            t = transports.from_config(cfg, jnp.float32, batched=batched)
-            arena = x[0][:b * s].reshape(b, s)
-            red, ef = t(arena, jnp.zeros_like(arena),
-                        jnp.arange(b, dtype=jnp.int32), ext)
-            return jnp.stack([red, ef if ef is not None
-                              else jnp.zeros_like(red)])
+            t = transports.from_config(cfg, jnp.float32)
+            arena = x[0].reshape(B, S)
+            red, ef = t(arena, jnp.zeros_like(arena), staggers, extents)
+            return jnp.stack([red, ef])
         return fn
 
-    # batched schedule ≡ per-bucket scan ancestor, bitwise (reduced AND
-    # EF residual), across axis layouts and the densify crossover
+    def reference_fn(cfg):
+        def fn(x):
+            arena = x[0].reshape(B, S)
+            return jnp.stack(per_bucket_reduce(
+                cfg, arena, jnp.zeros_like(arena), staggers, extents))
+        return fn
+
+    # batched schedule ≡ per-bucket scan of the single-vector schedule,
+    # bitwise (reduced AND EF residual), across axis layouts and the
+    # densify crossover
     for axes in [("data",), ("pod", "data")]:
         for kw, name in [(dict(sparse_k_frac=0.1), "sparse"),
                          (dict(sparse_k_frac=0.45,
                                density_threshold=0.5), "sparse_densify"),
                          (dict(compression="int8"), "int8")]:
             cfg = FlareConfig(axes=axes, **kw)
-            got = _run(transport_fn(cfg, True), xs, mesh)
-            want = _run(transport_fn(cfg, False), xs, mesh)
+            got = _run(transport_fn(cfg), xs, mesh)
+            want = _run(reference_fn(cfg), xs, mesh)
             assert got.tobytes() == want.tobytes(), \
                 f"batched != scan: {name} axes={axes}"
-
-    # HLO collective counts: independent of B for the batched transports
-    def count_collectives(cfg, batched, b):
-        fn = jax.jit(jax.shard_map(
-            transport_fn(cfg, batched, b=b, ext=(S,) * b),
-            in_specs=(P(("pod", "data"), None),), out_specs=P(None),
-            axis_names={"pod", "data"}, check_vma=False))
-        x = jax.ShapeDtypeStruct((4, b * S), jnp.float32)
-        with jax.set_mesh(mesh):
-            txt = fn.lower(x).compile().as_text()
-        return {op: len(re.findall(op + r"(?:-start)?\(", txt))
-                for op in ("collective-permute", "all-to-all", "all-gather")}
-
-    sp = FlareConfig(axes=("pod", "data"), sparse_k_frac=0.1)
-    c4, c8 = count_collectives(sp, True, 4), count_collectives(sp, True, 8)
-    assert c4 == c8, f"sparse collective count grew with B: {c4} vs {c8}"
-    # inner data axis (P=2): 1 RD step, one packed ppermute; outer pod
-    # rhd: 1 RS + 1 AG ppermute — O(log P), not O(B log P)
-    assert c4["collective-permute"] == 3, c4
-    q8 = FlareConfig(axes=("pod", "data"), compression="int8")
-    q4, q8c = count_collectives(q8, True, 4), count_collectives(q8, True, 8)
-    assert q4 == q8c, f"int8 collective count grew with B: {q4} vs {q8c}"
-    # per axis leg: one all_to_all + one all_gather for payload, one each
-    # for scales — O(1) per dtype group regardless of B
-    assert q4["all-to-all"] == 4 and q4["all-gather"] == 4, q4
 
     # GradReducer end-to-end: arena sparse/int8 vs oracle, ragged leaves
     Z = 192
@@ -644,6 +682,125 @@ def check_transports():
                                  "construction")
         GradReducer(FlareConfig(axes=("data",)))   # dense: fine on 6 ranks
     print("transports OK")
+
+
+#: Collective counts of each wire schedule in the compiled program: name
+#: → (axes, config fields, bucket elements, counts by kind in
+#: ``_COUNT_KINDS`` order).  One axis is ``data`` = 4, two are
+#: ``(pod, data)`` = (2, 2); each count is the one the schedule's
+#: docstring states, and none depends on the bucket count B.
+_COUNT_KINDS = ("collective-permute", "all-to-all", "all-gather",
+                "all-reduce")
+TRANSPORT_COUNT_CASES = {
+    # class ring: 2(P−1) ppermutes; 4(P−1) once chunks split (2 tiles)
+    "dense-ring": (("data",), {"algorithm": "ring"}, 2048, (6, 0, 0, 0)),
+    "dense-ring-split": (("data",), {"algorithm": "ring"}, 8192,
+                         (12, 0, 0, 0)),
+    # rhd: 2 log2 P; fixed tree: log2 P; psum: one all-reduce
+    "dense-rhd": (("data",), {"algorithm": "rhd"}, 2048, (4, 0, 0, 0)),
+    "dense-fixed-tree": (("data",), {"algorithm": "fixed_tree"}, 2048,
+                         (2, 0, 0, 0)),
+    "dense-psum": (("data",), {"algorithm": "psum"}, 2048, (0, 0, 0, 1)),
+    # two-level: 2(P_in−1) on the inner ring + 2 log2 P_out (rhd);
+    # hierarchical: 2 log2 P_in + 2 log2 P_out
+    "dense-two-level": (("pod", "data"), {"algorithm": "two_level"}, 2048,
+                        (4, 0, 0, 0)),
+    "dense-hierarchical": (("pod", "data"), {"algorithm": "hierarchical"},
+                           2048, (4, 0, 0, 0)),
+    # int8: per axis leg two all_to_alls and two all_gathers (payload
+    # and scales)
+    "int8-data": (("data",), {"compression": "int8"}, 2048, (0, 2, 2, 0)),
+    "int8-pod-data": (("pod", "data"), {"compression": "int8"}, 2048,
+                      (0, 4, 4, 0)),
+    # sparse: one ppermute per recursive-doubling step, log2 P; on
+    # (2, 2) the tree keeps it two-level: log2 P_in + 2 log2 P_out
+    "sparse-data": (("data",), {"sparse_k_frac": 0.1}, 2048, (2, 0, 0, 0)),
+    "sparse-pod-data": (("pod", "data"), {"sparse_k_frac": 0.1}, 2048,
+                        (3, 0, 0, 0)),
+}
+#: Reproducible ``GradReducer`` layouts: name → (mesh shape, config).
+REPRO_LAYOUT_CASES = {
+    "repro-layout-fixed-tree": ((8,), {"algorithm": "fixed_tree"}),
+    "repro-layout-hierarchical": ((2, 4), {"algorithm": "hierarchical"}),
+}
+TRANSPORT_COUNT_CHECKS = (tuple(TRANSPORT_COUNT_CASES)
+                          + tuple(REPRO_LAYOUT_CASES))
+
+
+def _axes_mesh(shape):
+    names = ("data",) if len(shape) == 1 else ("pod", "data")
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=jax.devices()[:math.prod(shape)])
+
+
+def collective_counts(text: str) -> tuple[int, ...]:
+    """Collective instructions of compiled HLO by ``_COUNT_KINDS`` kind
+    (an async pair counts once, at its ``-start``)."""
+    import re
+    return tuple(len(re.findall(r"= [^=]* " + k + r"(?:-start)?\(", text))
+                 for k in _COUNT_KINDS)
+
+
+def check_transport_counts():
+    """Each wire schedule's collective counts in the compiled program
+    are the same at B = 4 and B = 8 and equal what its docstring states
+    (``TRANSPORT_COUNT_CASES``), with no loop in the program to repeat
+    them per bucket; and a reproducible ``GradReducer`` gives
+    the same bits at three ``bucket_bytes`` (F3: the fixed-tree combine
+    is elementwise, so the arena layout cannot change it).  Eight
+    devices."""
+    for name, (axes, kw, s, want) in TRANSPORT_COUNT_CASES.items():
+        mesh = _axes_mesh((4,) if len(axes) == 1 else (2, 2))
+        got = []
+        for b in (4, 8):
+            t = transports.from_config(FlareConfig(axes=axes, **kw),
+                                       jnp.float32)
+            fn = jax.jit(jax.shard_map(
+                lambda x, t=t, b=b: t(x[0], jnp.zeros_like(x[0]),
+                                      np.arange(b, dtype=np.int32),
+                                      (s,) * b)[0][None],
+                mesh=mesh, in_specs=P(axes), out_specs=P(axes),
+                check_vma=False))
+            text = fn.lower(jax.ShapeDtypeStruct(
+                (4, b, s), jnp.float32)).compile().as_text()
+            assert " while(" not in text, (name, b)
+            got.append(collective_counts(text))
+        assert got == [want, want], (name, got, want)
+        print(f"transport_counts {name} OK")
+
+    rng = np.random.default_rng(17)
+    sizes = ((100,), (8, 30), (77,))
+    n = sum(math.prod(z) for z in sizes)
+    for name, (shape, kw) in REPRO_LAYOUT_CASES.items():
+        mesh = _axes_mesh(shape)
+        axes = mesh.axis_names
+        world = math.prod(shape)
+        xs = (rng.normal(size=(world, n)) * 1e3).astype(np.float32)
+        outs, buckets = [], []
+        for bucket_bytes in (256, 1024, 1 << 20):
+            cfg = FlareConfig(axes=axes, reproducible=True,
+                              bucket_bytes=bucket_bytes, **kw)
+
+            def body(x, cfg=cfg):
+                leaves, off = [], 0
+                for z in sizes:
+                    leaves.append(x[0, off:off + math.prod(z)].reshape(z))
+                    off += math.prod(z)
+                r = GradReducer(cfg)
+                buckets.append(r._plan(leaves).num_buckets)
+                red, _ = r(leaves)
+                return jnp.concatenate([v.reshape(-1) for v in red])[None]
+            outs.append(np.asarray(jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=P(axes), out_specs=P(axes),
+                check_vma=False))(xs)))
+        assert len(set(buckets)) == 3, (name, buckets)
+        for o in outs[1:]:
+            assert o.tobytes() == outs[0].tobytes(), name
+        assert np.allclose(outs[0], xs.astype(np.float64).sum(0),
+                           rtol=1e-5, atol=1e-2), name
+        print(f"transport_counts {name} OK")
+    print("transport_counts OK")
 
 
 def check_fsdp_engine():
@@ -746,12 +903,14 @@ def check_hierarchy():
     ``(1, 8)`` and the two-level ``(2, 4)`` topology in one tier-1
     invocation (conftest ``--mesh-shape``).  Verified here, for
     dense/int8/sparse:
-      * arena hierarchical == arena flat == legacy loop == fp oracle
-        within dtype tolerance — the schedules move different bytes but
-        reduce the same gradients;
-      * the batched hierarchical schedule is **bitwise-equal** to its
-        per-bucket scan ancestor (same per-bucket combine chains);
-      * reproducible hierarchical fixed-tree: arena ≡ legacy bitwise
+      * arena hierarchical == arena flat == auto == fp oracle within
+        dtype tolerance — the schedules move different bytes but reduce
+        the same gradients;
+      * the batched hierarchical schedule is **bitwise-equal** to the
+        single-vector schedule run bucket by bucket under a scan
+        (``per_bucket_reduce``: same per-bucket combine chains);
+      * reproducible hierarchical fixed-tree: the reducer ≡ the
+        single-vector schedule over the whole flat gradient, bitwise
         (elementwise rank-pure combine — packing-independent, F3).
     """
     pod, data = _mesh_shape()
@@ -785,35 +944,38 @@ def check_hierarchy():
         outs = {}
         for label, extra in [("hier", dict(hierarchical=True)),
                              ("flat", dict(hierarchical=False)),
-                             ("auto", dict()),
-                             ("legacy", dict(hierarchical=True,
-                                             arena=False))]:
+                             ("auto", dict())]:
             outs[label] = run(lambda x, kw={**kw, **extra}: eng(x, kw))
         for label, got in outs.items():
             assert np.allclose(got, expect, atol=tol), \
                 f"{name}/{label}: {np.abs(got - expect).max()}"
 
-    # reproducible hierarchical fixed tree: arena ≡ legacy, bitwise (F3)
+    # reproducible hierarchical fixed tree: the reducer ≡ the flat
+    # vector's single-vector schedule, bitwise (F3)
     a = run(lambda x: eng(x, dict(reproducible=True,
-                                  algorithm="hierarchical", arena=True)))
-    b = run(lambda x: eng(x, dict(reproducible=True,
-                                  algorithm="hierarchical", arena=False)))
-    assert a.tobytes() == b.tobytes(), "hier fixed_tree arena vs legacy"
+                                  algorithm="hierarchical")))
+    b = run(lambda x: coll.allreduce(x[0], ("pod", "data"),
+                                     algorithm="hierarchical",
+                                     reproducible=True))
+    assert a.tobytes() == b.tobytes(), "hier fixed_tree arena vs flat"
     assert np.allclose(a, expect, atol=1e-4), "hier fixed_tree accuracy"
 
     # transport level: hierarchical batched ≡ per-bucket scan, bitwise
     B, S = 4, 64
     xs_t = jnp.asarray(rng.normal(size=(world, B * S)).astype(np.float32))
     extents = (S, S, S, 40)              # ragged tail bucket
+    staggers = np.arange(B, dtype=np.int32)
 
     def transport_fn(cfg, batched):
         def fn(x):
-            t = transports.from_config(cfg, jnp.float32, batched=batched)
             arena = x[0].reshape(B, S)
-            red, ef = t(arena, jnp.zeros_like(arena),
-                        jnp.arange(B, dtype=jnp.int32), extents)
-            return jnp.stack([red, ef if ef is not None
-                              else jnp.zeros_like(red)])
+            ef = jnp.zeros_like(arena)
+            if batched:
+                out = transports.from_config(cfg, jnp.float32)(
+                    arena, ef, staggers, extents)
+            else:
+                out = per_bucket_reduce(cfg, arena, ef, staggers, extents)
+            return jnp.stack(out)
         return fn
 
     for kw, name in [(dict(), "dense"),
@@ -857,7 +1019,8 @@ def check_switch():
         ``fixed_tree`` collective (same aligned combine tree, executed
         in-switch) and **bitwise-invariant** under adversarial per-slot
         packet arrival permutations (§6.3 / F3);
-      * reproducible innetwork: arena ≡ legacy packing bitwise;
+      * reproducible innetwork: the reducer ≡ the wire fixed tree over
+        the whole flat gradient, bitwise (packing-independent, F3);
       * sparse data plane emits collision/spill counters consistent
         with the §7 hash-spill model (the perfmodel cross-check's
         multidevice half).
@@ -897,20 +1060,19 @@ def check_switch():
         outs = {}
         for label, extra in [("innetwork", dict(transport="innetwork")),
                              ("flat", dict(hierarchical=False)),
-                             ("hier", dict(hierarchical=True)),
-                             ("legacy_innet", dict(transport="innetwork",
-                                                   arena=False))]:
+                             ("hier", dict(hierarchical=True))]:
             outs[label] = run(lambda x, kw={**kw, **extra}: eng(x, kw))
         for label, got in outs.items():
             assert np.allclose(got, expect, atol=tol), \
                 f"{name}/{label}: {np.abs(got - expect).max()}"
 
-    # reproducible innetwork: arena ≡ legacy packing, bitwise (F3)
-    a = run(lambda x: eng(x, dict(transport="innetwork", reproducible=True,
-                                  arena=True)))
-    b = run(lambda x: eng(x, dict(transport="innetwork", reproducible=True,
-                                  arena=False)))
-    assert a.tobytes() == b.tobytes(), "innetwork repro arena vs legacy"
+    # reproducible innetwork: the reducer ≡ the wire fixed tree over
+    # the unbucketed vector, bitwise (F3)
+    a = run(lambda x: eng(x, dict(transport="innetwork", reproducible=True)))
+    b = run(lambda x: coll.allreduce(x[0], ("pod", "data"),
+                                     algorithm="fixed_tree",
+                                     reproducible=True))
+    assert a.tobytes() == b.tobytes(), "innetwork repro arena vs flat"
     assert np.allclose(a, expect, atol=1e-4), "innetwork repro accuracy"
 
     # transport level: switch fixed tree ≡ wire fixed tree, bitwise, and
@@ -2022,7 +2184,8 @@ def check_scopes():
         (flat, dict(algorithm="ring"), {"flare.pack", "flare.unpack",
                                         "flare.ring.reduce_scatter",
                                         "flare.ring.all_gather"}),
-        (flat, dict(algorithm="ring", arena=False),
+        # the vmapped single-vector ring, on two axes
+        (two, dict(algorithm="ring", hierarchical=False),
          {"flare.pack", "flare.unpack", "flare.ring.reduce_scatter",
           "flare.ring.all_gather"}),
         (flat, dict(algorithm="rhd"), {"flare.rhd"}),
@@ -2067,6 +2230,7 @@ GROUPS = {
     "arena_pipeline": check_arena_pipeline,
     "sparse_quant": check_sparse_quant,
     "transports": check_transports,
+    "transport_counts": check_transport_counts,
     "fsdp_engine": check_fsdp_engine,
     "trainer": check_trainer,
     "repro": check_repro,

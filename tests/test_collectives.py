@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from multidevice_checks import RING_CLASS_CHECKS
+from multidevice_checks import RING_CLASS_CHECKS, TRANSPORT_COUNT_CHECKS
 
 _SCRIPT = os.path.join(os.path.dirname(__file__), "multidevice_checks.py")
 
@@ -46,9 +46,23 @@ def ring_classes_out():
 @pytest.mark.parametrize("case", RING_CLASS_CHECKS)
 def test_ring_stagger_classes(ring_classes_out, case):
     """The batched ring that picks chunks by stagger class: bitwise-equal
-    to the per-bucket ring (and the dense transport's scan), with one
-    ppermute per round and no gather or scatter."""
+    to the per-bucket ring (looped and scanned), with one ppermute per
+    round and no gather or scatter."""
     assert f"ring_classes {case} OK" in ring_classes_out
+
+
+@pytest.fixture(scope="module")
+def transport_counts_out():
+    """One eight-device run of every transport-count and layout check."""
+    return _run_group("transport_counts")
+
+
+@pytest.mark.parametrize("case", TRANSPORT_COUNT_CHECKS)
+def test_transport_collective_counts(transport_counts_out, case):
+    """Each wire schedule's compiled collective counts, by kind, are the
+    same at B = 4 and B = 8 and what its docstring states; a
+    reproducible reducer's bits do not depend on ``bucket_bytes``."""
+    assert f"transport_counts {case} OK" in transport_counts_out
 
 
 def test_multidevice_hierarchy(mesh_shape):

@@ -1,59 +1,15 @@
-"""Single-device engine machinery: bucketing, config validation, policy."""
-import jax
+"""Single-device engine machinery: config validation, policy, sparse merge."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import bucketing, collectives as coll
+from repro.core import collectives as coll
 from repro.core.engine import FlareConfig
 from repro.core.sparse import (densify_step, expected_sparse_wire_bytes,
                                merge_coordinate_lists, topk_sparsify,
                                SENTINEL)
 from repro.core.reproducible import combine_order
-
-
-@given(st.lists(st.integers(1, 5000), min_size=1, max_size=40),
-       st.integers(10, 22))
-@settings(max_examples=30, deadline=None)
-def test_bucketing_partition(sizes, logbytes):
-    leaves = [jax.ShapeDtypeStruct((s,), jnp.float32) for s in sizes]
-    buckets = bucketing.build_buckets(leaves, 1 << logbytes)
-    ids = [i for b in buckets for i in b.leaf_ids]
-    assert sorted(ids) == list(range(len(sizes)))       # exact partition
-    for b in buckets:
-        # single-leaf buckets may exceed the target; multi-leaf must fit
-        if len(b.leaf_ids) > 1:
-            assert b.nbytes <= (1 << logbytes)
-
-
-def test_bucket_pack_unpack_roundtrip():
-    rng = np.random.default_rng(0)
-    leaves = [jnp.asarray(rng.normal(size=s).astype(np.float32))
-              for s in [(3, 4), (7,), (2, 2, 2)]]
-    buckets = bucketing.build_buckets(leaves, 1 << 20)
-    assert len(buckets) == 1
-    flat = bucketing.pack_bucket(leaves, buckets[0])
-    out = dict(bucketing.unpack_bucket(flat, leaves, buckets[0]))
-    for i, leaf in enumerate(leaves):
-        assert np.array_equal(np.asarray(out[i]), np.asarray(leaf))
-
-
-def test_bucket_dtype_separation():
-    leaves = [jax.ShapeDtypeStruct((10,), jnp.float32),
-              jax.ShapeDtypeStruct((10,), jnp.bfloat16),
-              jax.ShapeDtypeStruct((10,), jnp.float32)]
-    buckets = bucketing.build_buckets(leaves, 1 << 20)
-    for b in buckets:
-        assert len({leaves[i].dtype for i in b.leaf_ids}) == 1
-
-
-def test_stagger_offsets_distinct():
-    leaves = [jax.ShapeDtypeStruct((1 << 18,), jnp.float32)
-              for _ in range(4)]
-    buckets = bucketing.build_buckets(leaves, 1 << 20, stagger=True)
-    offs = [b.stagger for b in buckets]
-    assert len(set(offs)) == len(offs)
 
 
 def test_flare_config_validation():

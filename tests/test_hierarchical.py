@@ -216,7 +216,7 @@ def check_sparse_nonpow2_outer_fallback():
 
     def tfn(cfg):
         def fn(x):
-            t = transports.from_config(cfg, jnp.float32, batched=True)
+            t = transports.from_config(cfg, jnp.float32)
             arena = x[0].reshape(b, s)
             return t(arena, jnp.zeros_like(arena),
                      jnp.zeros((b,), jnp.int32), (s,) * b)[0]

@@ -368,6 +368,26 @@ def test_export_artifacts_are_valid_json(tmp_path):
                for e in trace["traceEvents"])
 
 
+def test_exported_trace_has_a_track_per_tenant(tmp_path):
+    """The trace export round trip: a two-tenant manager's modeled
+    timeline, written as Chrome JSON and read back, gives every tenant
+    at least one track and carries the metrics snapshot."""
+    from repro.obs import timeline
+    tm = Telemetry.create(clock=counting_clock())
+    mgr = _mgr(telemetry=tm)
+    _open_two(mgr)
+    timeline.manager_tracks(tm.tracer, mgr)
+    path = str(tmp_path / "t.json")
+    tm.export_trace(path)
+    with open(path) as f:
+        doc = json.load(f)
+    tracks = {e["args"]["name"] for e in doc["traceEvents"]
+              if e.get("ph") == "M" and e["name"] == "thread_name"}
+    for tenant in ("a", "b"):
+        assert any(tenant in t.split("/") for t in tracks), (tenant, tracks)
+    assert doc["metrics"]
+
+
 def test_report_cli_renders_tables(tmp_path, capsys):
     mpath, tpath = _exported(tmp_path)
     assert obs_report.main([mpath, tpath]) == 0

@@ -505,6 +505,26 @@ def test_replan_routes_around_hot_slot():
     assert fanins == [6, 2]
 
 
+@pytest.mark.parametrize("slot,hotness", [((1, 0), 2.0), ((1, 1), 2.0),
+                                          ((1, 0), 0.6), ((1, 0), 0.3),
+                                          ((2, 0), 2.0)])
+def test_replan_never_predicted_worse(slot, hotness):
+    """Wherever the hot slot is, a congestion replan of a reproducible
+    dense canary beside a sparse tenant never predicts less aggregate
+    throughput than the tree it leaves."""
+    from repro.runtime import CongestionMonitor
+    mgr = _mgr()
+    mgr.open("canary", mode="dense", num_buckets=8, bucket_elems=1 << 15,
+             dtype=jnp.float32, reproducible=True)
+    mgr.open("bg", mode="sparse", num_buckets=8, bucket_elems=1 << 15,
+             dtype=jnp.float32, k=2048)
+    mon = CongestionMonitor(mgr)
+    mon.inject(slot, hotness)
+    res = mgr.replan(mon, threshold=0.5, hysteresis=0.05)
+    assert (sum(res.predicted_after.values())
+            >= sum(res.predicted_before.values())), res
+
+
 def test_replan_hysteresis_blocks_marginal_move():
     """A cheaper tree that doesn't clear the hysteresis margin must not
     move anything (no ping-pong on noise)."""

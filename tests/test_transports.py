@@ -94,7 +94,7 @@ def test_sparse_k_single_source_of_truth():
     unpadded extent — frac >= 1 must not crash and padded sizes must not
     inflate k."""
     assert sparse_k(1.0, 100) == 100
-    assert sparse_k(1.5, 100) == 100       # legacy crashed here (k > size)
+    assert sparse_k(1.5, 100) == 100       # k > size would fail top-k
     assert sparse_k(1e-6, 100) == 1
     assert sparse_k(0.25, 100) == 25
     assert sparse_k(0.5, 1) == 1
@@ -193,11 +193,19 @@ def test_hierarchical_config_threading():
 
 def test_engine_pad_multiple_covers_quant_blocks():
     """With int8 transport the plan pad multiple makes every bucket chunk
-    a whole number of quantization blocks (no runtime pad on the wire)."""
-    r = GradReducer(FlareConfig(compression="int8"))
+    a whole number of quantization blocks (no runtime pad on the wire);
+    a group the int8 config leaves on the dense wire (an integer dtype)
+    pads as the dense schedule does."""
+    q8 = FlareConfig(compression="int8")
     for world in (1, 2, 8):
-        pad = r._pad_multiple(world)
-        assert pad % (world * transports.QUANT_BLOCK) == 0
-        assert pad % (2 * world) == 0
-    r2 = GradReducer(FlareConfig())
-    assert r2._pad_multiple(8) == 16
+        for t in (transports.from_config(q8, jnp.float32),
+                  transports.from_config(
+                      FlareConfig(compression="int8", transport="innetwork"),
+                      jnp.float32)):
+            pad = t.pad_multiple(world, 1000, 4)
+            assert pad % (world * transports.QUANT_BLOCK) == 0
+            assert pad % (2 * world) == 0
+    assert transports.from_config(q8, jnp.int32).pad_multiple(8, 1000, 4) \
+        == 16
+    dense = transports.from_config(FlareConfig(), jnp.float32)
+    assert dense.pad_multiple(8, 1000, 4) == 16
