@@ -27,10 +27,29 @@ from repro.models.base import ModelConfig
 Gather = Callable | None
 
 
-def segsum(x: jax.Array) -> jax.Array:
-    """(..., Q) → (..., Q, Q): out[i,j] = Σ_{k=j+1..i} x[k] (−inf above diag)."""
+def _chunk_cumsum(x: jax.Array) -> jax.Array:
+    """(..., Q) → (..., Q): out[..., q] = Σ_{k≤q} x[..., k] in float32.
+
+    A product with an upper-triangular matrix of ones, on the MXU.
+    ``HIGHEST`` keeps the float32 arithmetic of a cumsum (one bf16 pass
+    would round each log-decay before ``exp``); the backward is the
+    product with the transpose, at the same precision.
+    """
     q = x.shape[-1]
-    cs = jnp.cumsum(x, -1)
+    tri = jnp.triu(jnp.ones((q, q), jnp.float32))
+    return jnp.einsum("...k,kq->...q", x, tri,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def segsum(cs: jax.Array) -> jax.Array:
+    """(..., Q) → (..., Q, Q): out[i,j] = Σ_{k=j+1..i} x[k] (−inf above diag).
+
+    ``cs`` is x's prefix sum, ``_chunk_cumsum(x)``: a product, since a TPU
+    runs ``jnp.cumsum`` as a ``reduce-window`` over lanes; the caller's
+    one prefix sum serves both this and the decays.
+    """
+    q = cs.shape[-1]
     d = cs[..., :, None] - cs[..., None, :]
     mask = jnp.tril(jnp.ones((q, q), bool))
     return jnp.where(mask, d, -jnp.inf)
@@ -39,6 +58,10 @@ def segsum(x: jax.Array) -> jax.Array:
 def ssd_chunked(xdt: jax.Array, a_bar: jax.Array, bb: jax.Array,
                 cc: jax.Array, chunk: int, h0: jax.Array):
     """Chunked SSD scan.
+
+    The within-chunk prefix sum of the log-decay is one product with a
+    triangular matrix (``_chunk_cumsum``): a TPU runs ``jnp.cumsum`` as a
+    ``reduce-window`` over lanes.
 
     xdt: (B,S,H,P) inputs pre-multiplied by dt;  a_bar: (B,S,H) log-decay;
     bb/cc: (B,S,N);  h0: (B,H,P,N) initial state.
@@ -54,9 +77,9 @@ def ssd_chunked(xdt: jax.Array, a_bar: jax.Array, bb: jax.Array,
     bbc = bb.reshape(b, c, chunk, n)
     ccc = cc.reshape(b, c, chunk, n)
 
-    acum = jnp.cumsum(ab, -1)                                   # (B,H,C,Q)
+    acum = _chunk_cumsum(ab)                                    # (B,H,C,Q)
     # 1) intra-chunk (the "attention-like" quadratic-in-chunk term)
-    ll = jnp.exp(segsum(ab))                                    # (B,H,C,Q,Q)
+    ll = jnp.exp(segsum(acum))                                  # (B,H,C,Q,Q)
     scores = jnp.einsum("bcqn,bckn->bcqk", ccc, bbc)
     w = scores[:, None] * ll                                    # (B,H,C,Q,Q)
     y_diag = jnp.einsum("bhcqk,bckhp->bcqhp", w, x)

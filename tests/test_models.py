@@ -141,6 +141,53 @@ def test_mamba_chunked_equals_recurrent():
     assert rel < 1e-3, f"SSD chunked vs recurrent: {rel:.2e}"
 
 
+def _log_decay(q, seed=0):
+    """(2, 3, 4, q) log-decays drawn as the SSD makes them: softplus(dt)
+    times −exp(A_log), A_log up to log 16, so about −11 a position."""
+    kd, ka = jax.random.split(jax.random.PRNGKey(seed))
+    dt = jax.random.normal(kd, (2, 3, 4, q))
+    a_log = jax.random.uniform(ka, (3, 1, 1), maxval=np.log(16.0))
+    return jax.nn.softplus(dt) * -jnp.exp(a_log)
+
+
+@pytest.mark.parametrize("q", [256, 64, 8])
+def test_chunk_cumsum_equals_cumsum(q):
+    from repro.models.mamba2 import _chunk_cumsum
+    x = _log_decay(q)
+    got, want = np.asarray(_chunk_cumsum(x)), np.asarray(jnp.cumsum(x, -1))
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("q", [256, 64, 8])
+def test_chunk_cumsum_grad_equals_cumsum_grad(q):
+    """The product's transpose is a reverse prefix sum, as cumsum's is."""
+    from repro.models.mamba2 import _chunk_cumsum
+    x = _log_decay(q)
+    w = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    got = np.asarray(jax.grad(lambda x: jnp.sum(w * _chunk_cumsum(x)))(x))
+    want = np.asarray(jax.grad(lambda x: jnp.sum(w * jnp.cumsum(x, -1)))(x))
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("q", [256, 64, 8])
+def test_segsum_contract(q):
+    """out[i, j] = Σ_{k=j+1..i} x[k] on and below the diagonal, −inf
+    above it."""
+    from repro.models.mamba2 import _chunk_cumsum, segsum
+    x = _log_decay(q)
+    got = np.asarray(segsum(_chunk_cumsum(x)))
+    assert got.shape == x.shape + (q,)
+    cs = np.cumsum(np.asarray(x, np.float64), -1)
+    want = cs[..., :, None] - cs[..., None, :]
+    below = np.tril(np.ones((q, q), bool))
+    assert np.all(got[..., ~below] == -np.inf)
+    np.testing.assert_allclose(got[..., below], want[..., below], rtol=0,
+                               atol=1e-6 * np.abs(cs).max())
+
+
 def test_moe_router_balance_mechanism():
     """Capacity dropping must engage for adversarially unbalanced routing
     without corrupting kept tokens (positions are collision-free)."""

@@ -156,3 +156,26 @@ def test_dropless_moe_layer_compiles_with_grouped_products(one_chip, on_tpu):
             base.moe_dropless(cfg, p, x)[0].astype(jnp.float32)))(p)
     text = _compiled_text(grads, ffn, x)
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_ssd_prefix_sums_compile_to_highest_products(one_chip):
+    """The Mamba-2 SSD's forward and backward at train-1chip's widths
+    (S 2048, H 32, P 64, N 128, chunk 256): its within-chunk prefix sums
+    are float32 products on the MXU, with no ``reduce-window`` (what a
+    TPU makes of ``jnp.cumsum``) and no single bf16 pass."""
+    from repro.models import mamba2
+
+    b, s, h, p, n, q = 1, 2048, 32, 64, 128, 256
+    xdt, a_bar, bb, cc, h0 = (
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        for shape in [(b, s, h, p), (b, s, h), (b, s, n), (b, s, n),
+                      (b, h, p, n)])
+
+    def grads(xdt, a_bar, bb, cc, h0):
+        def loss(*args):
+            y, h_final = mamba2.ssd_chunked(*args, q, h0)
+            return jnp.sum(y) + jnp.sum(h_final)
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(xdt, a_bar, bb, cc)
+    text = _compiled_text(grads, xdt, a_bar, bb, cc, h0)
+    assert "reduce-window" not in text
+    assert "operand_precision={highest,highest}" in text
